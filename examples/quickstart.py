@@ -17,4 +17,4 @@ sys.argv = [sys.argv[0]] + (sys.argv[1:] or
 from repro.launch import train  # noqa: E402
 
 if __name__ == "__main__":
-    train.main()
+    raise SystemExit(train.main())

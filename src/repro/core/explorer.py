@@ -25,6 +25,7 @@ import numpy as np
 from repro.approx.knobs import ApproxKnobs, PRECISE
 from repro.configs.base import ModelConfig
 from repro.core.variants import ResourcePressure, Variant, VariantTable
+from repro.roofline import DEFAULT_TARGET
 
 
 def knob_grid(cfg: ModelConfig, *, serving: bool = False) -> List[ApproxKnobs]:
@@ -120,15 +121,16 @@ def analytic_quality_loss(cfg: ModelConfig, k: ApproxKnobs) -> float:
 def decode_kv_share(cfg: ModelConfig, batch: int, max_len: int, *,
                     dtype=None, quantized: bool = False) -> float:
     """KV-cache share of one dense decode step's HBM bytes, derived from the
-    COMPILED decode cell's ``cost_analysis()`` (the dry-run's roofline input)
-    rather than the old hard-coded 0.5 heuristic.
+    COMPILED decode cell rather than the old hard-coded 0.5 heuristic.
 
     The ring bytes are exact (every attention layer streams its full
     ``(B, W, G, hd)`` K+V rings once per token); the denominator is the
-    executable's total bytes accessed. This is what makes paged decode
-    pricing honest: the fused paged kernel streams LIVE pages instead of the
-    rings, so the memory term scales by ``kv_share * occupancy`` — and
-    ``kv_share`` must come from the real executable, not a guess.
+    executable's argument bytes (``memory_analysis``): params, caches and
+    inputs, each streamed at least once per step. This is what makes paged
+    decode pricing honest: the fused paged kernel streams LIVE pages instead
+    of the rings, so the memory term scales by ``kv_share * occupancy``.
+    (``cost_analysis`` bytes are no denominator: they count the layer-group
+    scan's body once, so a 32-layer model's share came out above 1.)
     """
     import jax
     import jax.numpy as jnp
@@ -145,10 +147,7 @@ def decode_kv_share(cfg: ModelConfig, batch: int, max_len: int, *,
     toks = jax.ShapeDtypeStruct((batch, 1), jnp.int32)
     pos = jax.ShapeDtypeStruct((batch,), jnp.int32)
     compiled = jax.jit(step).lower(params, toks, pos, caches).compile()
-    cost = compiled.cost_analysis()
-    if isinstance(cost, (list, tuple)):     # jax<=0.4.x drift (see dryrun)
-        cost = cost[0] if cost else {}
-    total = float(cost.get("bytes accessed", 0.0))
+    total = float(compiled.memory_analysis().argument_size_in_bytes)
     from repro.configs.base import LOCAL_ATTN, MAMBA
     itemsize = 1 if quantized else jnp.dtype(dtype).itemsize
     hd, G = cfg.resolved_head_dim, cfg.n_kv_heads
@@ -167,12 +166,15 @@ def decode_kv_share(cfg: ModelConfig, batch: int, max_len: int, *,
 def analytic_cost(cfg: ModelConfig, shape, k: ApproxKnobs,
                   baseline_art: Optional[dict] = None, *,
                   page_occupancy: Optional[float] = None,
-                  kv_share: Optional[float] = None
+                  kv_share: Optional[float] = None,
+                  target: str = DEFAULT_TARGET
                   ) -> Tuple[float, ResourcePressure]:
     """(rel_time, pressure) from the roofline model.
 
     If a dry-run artifact for the precise variant is given, its three terms
-    anchor the baseline; knob deltas scale each term analytically.
+    anchor the baseline; knob deltas scale each term analytically. Without
+    one, the baseline is priced at the peaks of the ``target`` device kind
+    (``roofline.PEAKS``).
 
     ``page_occupancy`` (paged serving engines): fraction of the dense cache
     footprint that is live pages. Dense decode streams the full ``max_len``
@@ -180,7 +182,7 @@ def analytic_cost(cfg: ModelConfig, shape, k: ApproxKnobs,
     so the KV share of the decode memory term scales by occupancy — the
     frontier then sees paged memory savings exactly like any other
     memory-side knob. ``kv_share`` is that KV share of decode HBM bytes,
-    ideally from ``decode_kv_share`` (compiled-cell ``cost_analysis``);
+    ideally from ``decode_kv_share`` (compiled-cell argument bytes);
     None falls back to the coarse 0.5 heuristic.
     """
     from repro import roofline
@@ -190,7 +192,7 @@ def analytic_cost(cfg: ModelConfig, shape, k: ApproxKnobs,
         coll = baseline_art["collective_s"]
     else:
         mf = roofline.model_flops(cfg, shape, PRECISE)
-        comp = mf / 256 / roofline.PEAK_FLOPS
+        comp = mf / 256 / roofline.peaks(target).bf16_flops
         # decode streams every weight + the KV rings per emitted token at
         # trivial arithmetic intensity: firmly HBM-bound, so memory-side knobs
         # (int8 weights, kv_quant) keep paying off after compute knobs bind
@@ -226,7 +228,7 @@ def analytic_cost(cfg: ModelConfig, shape, k: ApproxKnobs,
         # decode HBM traffic priced by LIVE pages (the fused paged kernel
         # streams mapped pages, not slots x max_len rings): scale the KV
         # share of the memory term by occupancy. kv_share comes from the
-        # compiled cell's cost_analysis (decode_kv_share) when the caller
+        # compiled cell's bytes (decode_kv_share) when the caller
         # provides it; 0.5 is the coarse long-context fallback.
         share = 0.5 if kv_share is None else min(max(kv_share, 0.0), 0.95)
         occ = min(max(page_occupancy, 0.0), 1.0)
@@ -297,14 +299,16 @@ def explore(cfg: ModelConfig, shape, *, serving: bool = False,
             evaluate: Optional[Callable] = None,
             max_variants: int = 8,
             page_occupancy: Optional[float] = None,
-            kv_share: Optional[float] = None) -> VariantTable:
+            kv_share: Optional[float] = None,
+            target: str = DEFAULT_TARGET) -> VariantTable:
     """Build the ordered VariantTable for one (arch, shape) colocation.
 
     ``evaluate(knobs) -> (rel_time, quality_loss, pressure)`` overrides the
     analytic backend (the measured path used by benchmarks).
     ``page_occupancy`` prices decode HBM by live pages (paged engines);
     ``kv_share`` anchors that pricing on the compiled decode cell's
-    cost_analysis bytes (``decode_kv_share``).
+    bytes (``decode_kv_share``). ``target`` is the device
+    kind the analytic backend prices for; the table records it.
     """
     cands = knob_grid(cfg, serving=serving)
     evaluated = []
@@ -314,7 +318,7 @@ def explore(cfg: ModelConfig, shape, *, serving: bool = False,
         else:
             rel_t, pressure = analytic_cost(cfg, shape, k, baseline_art,
                                             page_occupancy=page_occupancy,
-                                            kv_share=kv_share)
+                                            kv_share=kv_share, target=target)
             qloss = analytic_quality_loss(cfg, k)
         evaluated.append(Variant(k, rel_t, qloss, pressure))
     # threshold first (paper: discard variants with inaccuracy > 5%)
@@ -329,4 +333,4 @@ def explore(cfg: ModelConfig, shape, *, serving: bool = False,
     if len(front) > max_variants:
         idx = np.linspace(0, len(front) - 1, max_variants).round().astype(int)
         front = [front[int(i)] for i in sorted(set(idx))]
-    return VariantTable(front)
+    return VariantTable(front, target=target)

@@ -48,6 +48,7 @@ class Variant:
 class VariantTable:
     """Ordered: index 0 = precise, last = most approximate."""
     variants: List[Variant]
+    target: str = ""             # device kind the rel_times were priced for
     executables: Dict[int, Any] = field(default_factory=dict)
     compile_times: Dict[int, float] = field(default_factory=dict)
 

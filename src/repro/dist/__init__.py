@@ -1,8 +1,5 @@
 """First-class distribution layer.
 
-* ``compat``      — shims for jax API drift (``jax.set_mesh``/``jax.shard_map``
-                    on the pinned 0.4.x CPU jax). Imported for its side effect:
-                    importing ``repro.dist`` installs the shims.
 * ``sharding``    — logical-axis -> mesh-axis policies: ``param_shardings``,
                     ``cache_shardings``, ``input_shardings``, ``batch_pspec``.
 * ``annotate``    — activation-sharding constraints (``constrain_batch``,
@@ -11,6 +8,3 @@
                     ``grad_compress`` knob) and ``pod_sync_params`` (the
                     ``sync_period`` knob's periodic pod-level sync).
 """
-from repro.dist import compat as _compat
-
-_compat.install()

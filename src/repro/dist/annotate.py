@@ -13,8 +13,6 @@ from typing import Optional, Tuple, Union
 import jax
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-from repro.dist import compat
-
 Axes = Union[None, str, Tuple[str, ...]]
 
 BATCH_AXES: Axes = None       # mesh axes sharding the batch dim
@@ -47,14 +45,21 @@ def _usable(mesh, axes: Axes, dim: int) -> bool:
     return dim % n == 0
 
 
+def active_mesh():
+    """The (abstract) mesh of the enclosing ``jax.set_mesh`` context, or
+    None outside one — the single-device reference paths."""
+    m = jax.sharding.get_abstract_mesh()
+    return m if m.axis_names else None
+
+
 def _constrain(x, spec: P):
     return jax.lax.with_sharding_constraint(
-        x, NamedSharding(compat.active_mesh(), spec))
+        x, NamedSharding(active_mesh(), spec))
 
 
 def constrain_batch(x):
     """Keep dim 0 (batch) sharded over the declared batch axes."""
-    mesh = compat.active_mesh()
+    mesh = active_mesh()
     if mesh is None or not _usable(mesh, BATCH_AXES, x.shape[0]):
         return x
     return _constrain(x, P(BATCH_AXES, *([None] * (x.ndim - 1))))
@@ -68,7 +73,7 @@ def constrain_replicated(x):
     wrong values; gathering first sidesteps it (serving admission path, where
     the gathered chunk K/V are a few tokens wide). No-op off-mesh.
     """
-    mesh = compat.active_mesh()
+    mesh = active_mesh()
     if mesh is None:
         return x
     return _constrain(x, P(*([None] * x.ndim)))
@@ -77,7 +82,7 @@ def constrain_replicated(x):
 def constrain_vocab(x):
     """Keep the trailing (vocab) dim TP-sharded — the chunked cross-entropy
     relies on this so GSPMD never replicates the (B, C, V) logit tile."""
-    mesh = compat.active_mesh()
+    mesh = active_mesh()
     if mesh is None or not _usable(mesh, VOCAB_AXIS, x.shape[-1]):
         return x
     lead = BATCH_AXES if _usable(mesh, BATCH_AXES, x.shape[0]) else None

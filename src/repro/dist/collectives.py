@@ -20,8 +20,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-from repro.dist import compat
-
 
 def _quantize_int8(x):
     """Symmetric per-tensor int8: (payload int8, scale f32 scalar)."""
@@ -109,8 +107,8 @@ def grad_sync(grads, mesh, *, pod_wire: bool = True, compress: bool = False,
                 g = jax.tree.map(lambda x: jax.lax.pmean(x, pod_axis), g)
         return g
 
-    return compat.shard_map(body, mesh=mesh, in_specs=(specs,),
-                            out_specs=specs, check_vma=False)(grads)
+    return jax.shard_map(body, mesh=mesh, in_specs=(specs,),
+                         out_specs=specs, check_vma=False)(grads)
 
 
 def pod_sync_params(params, mesh, *, compress: bool = False, pspecs=None,
@@ -137,5 +135,5 @@ def pod_sync_params(params, mesh, *, compress: bool = False, pspecs=None,
             return compressed_pmean(p, axis)
         return jax.tree.map(lambda x: jax.lax.pmean(x, axis), p)
 
-    return compat.shard_map(body, mesh=mesh, in_specs=(specs,),
-                            out_specs=specs, check_vma=False)(params)
+    return jax.shard_map(body, mesh=mesh, in_specs=(specs,),
+                         out_specs=specs, check_vma=False)(params)

@@ -14,8 +14,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.pallas_compat import CompilerParams
-
 
 def _kernel(x_ref, xs_ref, w_ref, ws_ref, o_ref, acc_ref, *, n_k: int):
     k = pl.program_id(2)
@@ -39,14 +37,21 @@ def _kernel(x_ref, xs_ref, w_ref, ws_ref, o_ref, acc_ref, *, n_k: int):
                                              "interpret"))
 def int8_matmul(x_q, x_scale, w_q, w_scale, *, bm: int = 128, bn: int = 128,
                 bk: int = 512, out_dtype=jnp.bfloat16, interpret: bool = False):
-    """x_q: (M,K) int8; x_scale: (M,1) f32; w_q: (K,N) int8; w_scale: (1,N)."""
+    """x_q: (M,K) int8; x_scale: (M,1) f32; w_q: (K,N) int8; w_scale: (1,N).
+
+    A row count above ``bm`` that is not a multiple of it (a ragged prefill
+    chunk) is padded with zero rows, which are sliced off the result."""
     M, K = x_q.shape
     _, N = w_q.shape
     bm, bn, bk = min(bm, M), min(bn, N), min(bk, K)
-    assert M % bm == 0 and N % bn == 0 and K % bk == 0, (M, N, K, bm, bn, bk)
+    assert N % bn == 0 and K % bk == 0, (N, K, bn, bk)
+    pad = -M % bm
+    if pad:
+        x_q = jnp.pad(x_q, ((0, pad), (0, 0)))
+        x_scale = jnp.pad(x_scale, ((0, pad), (0, 0)))
     n_k = K // bk
-    grid = (M // bm, N // bn, n_k)
-    return pl.pallas_call(
+    grid = ((M + pad) // bm, N // bn, n_k)
+    out = pl.pallas_call(
         functools.partial(_kernel, n_k=n_k),
         grid=grid,
         in_specs=[
@@ -56,9 +61,10 @@ def int8_matmul(x_q, x_scale, w_q, w_scale, *, bm: int = 128, bn: int = 128,
             pl.BlockSpec((1, bn), lambda i, j, k: (0, j)),
         ],
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, k: (i, j)),
-        out_shape=jax.ShapeDtypeStruct((M, N), out_dtype),
+        out_shape=jax.ShapeDtypeStruct((M + pad, N), out_dtype),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(x_q, x_scale, w_q, w_scale)
+    return out[:M] if pad else out

@@ -1,13 +1,14 @@
 """Pallas TPU kernel: fused paged-attention decode (vLLM-style).
 
 One query token per batch slot attends over its block table's K/V pages
-**in place**: the grid runs (slot, kv_head, logical_page) with the page dim
-innermost (sequential), the block table and per-slot positions are scalar-
-prefetched so each page's BlockSpec index map streams the *physical* page
-HBM -> VMEM directly, and an online-softmax accumulator in VMEM scratch
-folds pages as they arrive. The dense ``(B, S_max, G, hd)`` gather buffer of
-the reference path never exists, so per-step decode HBM traffic scales with
-LIVE pages instead of slots x max_len.
+**in place**: the grid runs (slot, logical_page) with the page dim
+innermost (sequential) and every KV head of a page in one step, the block
+table and per-slot positions are scalar-prefetched so each page's BlockSpec
+index map streams the *physical* page HBM -> VMEM directly, and an
+online-softmax accumulator in VMEM scratch folds pages as they arrive. The
+dense ``(B, S_max, G, hd)`` gather buffer of the reference path never
+exists, so per-step decode HBM traffic scales with LIVE pages instead of
+slots x max_len.
 
 Dead traffic is skipped at two levels:
 
@@ -35,16 +36,14 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.pallas_compat import CompilerParams
-
 NEG_INF = -1e30
 
 
 def _kernel(block_ref, pos_ref, q_ref, k_ref, v_ref, ppos_ref, o_ref,
-            m_ref, l_ref, acc_ref, *, page: int, n_m: int, window: int,
-            kv_scale: float, cap: float, scale: float):
+            m_ref, l_ref, acc_ref, *, page: int, n_m: int, n_kv: int,
+            window: int, kv_scale: float, cap: float, scale: float):
     b = pl.program_id(0)
-    m = pl.program_id(2)          # logical page (sequential)
+    m = pl.program_id(1)          # logical page (sequential)
 
     @pl.when(m == 0)
     def _init():
@@ -61,38 +60,39 @@ def _kernel(block_ref, pos_ref, q_ref, k_ref, v_ref, ppos_ref, o_ref,
 
     @pl.when(run)
     def _body():
-        q = q_ref[0, 0].astype(jnp.float32)          # (R, hd)
-        k = k_ref[0, :, 0, :].astype(jnp.float32)    # (P, hd)
-        v = v_ref[0, :, 0, :].astype(jnp.float32)
-        if kv_scale:                                 # fused int8 dequant
-            k = k * kv_scale
-            v = v * kv_scale
-        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32) * scale
-        if cap:
-            s = cap * jnp.tanh(s / cap)
-        kv_pos = ppos_ref[...]                       # (1, P)
+        kv_pos = ppos_ref[0]                         # (1, P)
         valid = (kv_pos >= 0) & (kv_pos <= pos)
         if window:
             valid &= kv_pos > pos - window
-        s = jnp.where(valid, s, NEG_INF)             # (R, P)
-        m_prev, l_prev = m_ref[...], l_ref[...]
-        m_cur = jnp.max(s, axis=-1, keepdims=True)
-        m_new = jnp.maximum(m_prev, m_cur)
-        p = jnp.exp(s - m_new)
-        alpha = jnp.exp(m_prev - m_new)
-        l_ref[...] = l_prev * alpha + jnp.sum(p, -1, keepdims=True)
-        m_ref[...] = m_new
-        acc_ref[...] = (acc_ref[...] * alpha
-                        + jax.lax.dot_general(
-                            p, v, (((1,), (0,)), ((), ())),
-                            preferred_element_type=jnp.float32))
+        for g in range(n_kv):                        # static: one KV head
+            q = q_ref[0, g].astype(jnp.float32)          # (R, hd)
+            k = k_ref[0, :, g, :].astype(jnp.float32)    # (P, hd)
+            v = v_ref[0, :, g, :].astype(jnp.float32)
+            if kv_scale:                                 # fused int8 dequant
+                k = k * kv_scale
+                v = v * kv_scale
+            s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
+                                    preferred_element_type=jnp.float32)
+            s = s * scale
+            if cap:
+                s = cap * jnp.tanh(s / cap)
+            s = jnp.where(valid, s, NEG_INF)             # (R, P)
+            m_prev, l_prev = m_ref[g], l_ref[g]
+            m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+            p = jnp.exp(s - m_new)
+            alpha = jnp.exp(m_prev - m_new)
+            l_ref[g] = l_prev * alpha + jnp.sum(p, -1, keepdims=True)
+            m_ref[g] = m_new
+            acc_ref[g] = (acc_ref[g] * alpha
+                          + jax.lax.dot_general(
+                              p, v, (((1,), (0,)), ((), ())),
+                              preferred_element_type=jnp.float32))
 
     @pl.when(m == n_m - 1)
     def _finish():
         # all-masked slots (inactive decode rows) leave l == 0: emit zeros
         l = jnp.maximum(l_ref[...], 1e-30)
-        o_ref[0, 0] = (acc_ref[...] / l).astype(o_ref.dtype)
+        o_ref[0] = (acc_ref[...] / l).astype(o_ref.dtype)
 
 
 def paged_attention_impl(q, kp, vp, ppos, block, position, *, window: int = 0,
@@ -106,6 +106,11 @@ def paged_attention_impl(q, kp, vp, ppos, block, position, *, window: int = 0,
     physical page ids (0 = unmapped); position: (B,) absolute query position.
     Returns (B, G, R, hd) in q.dtype.
 
+    One grid step streams a whole page, every KV head at once: the TPU
+    compiler tiles the last two block dims, which must be (8, 128)-aligned
+    or span the array, so a page block is (1, P, G, hd) and the ``ppos``
+    row travels as a (1, 1, P) block of a (n_pages, 1, P) view.
+
     Use ``paged_attention`` (the jitted wrapper) from op-level code; this
     raw body exists so ``models.attention`` can call the kernel INSIDE a
     ``shard_map`` region with per-shard (rebased) block tables — a nested
@@ -117,10 +122,10 @@ def paged_attention_impl(q, kp, vp, ppos, block, position, *, window: int = 0,
     block = block.astype(jnp.int32)
     position = position.astype(jnp.int32)
 
-    def _qo_map(b, g, m, block_ref, pos_ref):
-        return (b, g, 0, 0)
+    def _qo_map(b, m, block_ref, pos_ref):
+        return (b, 0, 0, 0)
 
-    def _page_map(b, g, m, block_ref, pos_ref):
+    def _page_map(b, m, block_ref, pos_ref):
         pid = block_ref[b, m]
         # redirect dead pages to the null page: the fetch aliases page 0
         # (elided when consecutive) instead of streaming a page the body
@@ -131,44 +136,41 @@ def paged_attention_impl(q, kp, vp, ppos, block, position, *, window: int = 0,
         dead = m * P > pos_ref[b]
         if window:
             dead |= (m + 1) * P - 1 <= pos_ref[b] - window
-        pid = jnp.where(dead, 0, pid)
-        return (pid, 0, 0, 0)
+        return jnp.where(dead, 0, pid)
 
-    def _kv_map(b, g, m, block_ref, pos_ref):
-        pid = _page_map(b, g, m, block_ref, pos_ref)[0]
-        return (pid, 0, g, 0)
+    def _kv_map(b, m, block_ref, pos_ref):
+        return (_page_map(b, m, block_ref, pos_ref), 0, 0, 0)
 
-    def _ppos_map(b, g, m, block_ref, pos_ref):
-        pid = _page_map(b, g, m, block_ref, pos_ref)[0]
-        return (pid, 0)
+    def _ppos_map(b, m, block_ref, pos_ref):
+        return (_page_map(b, m, block_ref, pos_ref), 0, 0)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
-        grid=(B, G, M),
+        grid=(B, M),
         in_specs=[
-            pl.BlockSpec((1, 1, R, hd), _qo_map),
-            pl.BlockSpec((1, P, 1, hd), _kv_map),
-            pl.BlockSpec((1, P, 1, hd), _kv_map),
-            pl.BlockSpec((1, P), _ppos_map),
+            pl.BlockSpec((1, G, R, hd), _qo_map),
+            pl.BlockSpec((1, P, G, hd), _kv_map),
+            pl.BlockSpec((1, P, G, hd), _kv_map),
+            pl.BlockSpec((1, 1, P), _ppos_map),
         ],
-        out_specs=pl.BlockSpec((1, 1, R, hd), _qo_map),
+        out_specs=pl.BlockSpec((1, G, R, hd), _qo_map),
         scratch_shapes=[
-            pltpu.VMEM((R, 1), jnp.float32),
-            pltpu.VMEM((R, 1), jnp.float32),
-            pltpu.VMEM((R, hd), jnp.float32),
+            pltpu.VMEM((G, R, 1), jnp.float32),
+            pltpu.VMEM((G, R, 1), jnp.float32),
+            pltpu.VMEM((G, R, hd), jnp.float32),
         ],
     )
     kernel = functools.partial(
-        _kernel, page=P, n_m=M, window=window, kv_scale=kv_scale, cap=cap,
-        scale=hd ** -0.5)
+        _kernel, page=P, n_m=M, n_kv=G, window=window, kv_scale=kv_scale,
+        cap=cap, scale=hd ** -0.5)
     return pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
-        compiler_params=CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary")),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
-    )(block, position, q, kp, vp, ppos)
+    )(block, position, q, kp, vp, ppos.reshape(n_pages, 1, P))
 
 
 paged_attention = functools.partial(jax.jit, static_argnames=(

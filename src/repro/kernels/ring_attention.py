@@ -41,8 +41,6 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 from jax.sharding import PartitionSpec as P
 
-from repro.kernels.pallas_compat import CompilerParams
-
 NEG_INF = -1e30
 _BIG = 2 ** 30
 
@@ -63,8 +61,8 @@ def _hop_kernel(q_ref, k_ref, v_ref, qp_ref, kvp_ref, mi_ref, li_ref, ai_ref,
         l_s[...] = li_ref[0, 0]
         a_s[...] = ai_ref[0, 0]
 
-    qpos = qp_ref[...].reshape(bq, 1)
-    kpos = kvp_ref[...].reshape(1, bk)
+    qpos = qp_ref[0].reshape(bq, 1)
+    kpos = kvp_ref[0]                            # (1, bk)
     q_ok, kv_ok = qpos >= 0, kpos >= 0
     # tile-level skip from position bounds (striped-attention block skip)
     q_max = jnp.max(jnp.where(q_ok, qpos, -1))
@@ -116,7 +114,9 @@ def _hop(qf, kf, vf, qp, kvp, m, l, acc, *, window: int, cap: float,
 
     qf: (B, H, Cl, hd); kf/vf: (B, KVH, Ll, hd) at storage dtype; qp: (B,
     Cl); kvp: (B, Ll); m/l: (B, H, Cl, 1) f32; acc: (B, H, Cl, hd) f32.
-    Shapes are pre-padded to block multiples by the caller."""
+    Shapes are pre-padded to block multiples by the caller. Positions
+    travel as (B, 1, len) views so their blocks span the unit dim (the
+    compiler's tiling rule for the last two block dims)."""
     B, H, Cl, hd = qf.shape
     _, KVH, Ll, _ = kf.shape
     rep = H // KVH
@@ -134,8 +134,8 @@ def _hop(qf, kf, vf, qp, kvp, m, l, acc, *, window: int, cap: float,
         kernel,
         grid=grid,
         in_specs=[q_spec, kv_spec, kv_spec,
-                  pl.BlockSpec((1, bq), lambda b, h, i, j: (b, i)),
-                  pl.BlockSpec((1, bk), lambda b, h, i, j: (b, j)),
+                  pl.BlockSpec((1, 1, bq), lambda b, h, i, j: (b, 0, i)),
+                  pl.BlockSpec((1, 1, bk), lambda b, h, i, j: (b, 0, j)),
                   ml_spec, ml_spec, q_spec],
         out_specs=[ml_spec, ml_spec, q_spec],
         out_shape=[jax.ShapeDtypeStruct(m.shape, f32),
@@ -144,11 +144,11 @@ def _hop(qf, kf, vf, qp, kvp, m, l, acc, *, window: int, cap: float,
         scratch_shapes=[pltpu.VMEM((bq, 1), f32),
                         pltpu.VMEM((bq, 1), f32),
                         pltpu.VMEM((bq, hd), f32)],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")),
         interpret=interpret,
-    )(qf, kf, vf, qp, kvp, m, l, acc)
+    )(qf, kf, vf, qp[:, None], kvp[:, None], m, l, acc)
 
 
 def _pad_tail(x, axis: int, to: int, fill):
@@ -244,7 +244,6 @@ def ring_chunk_attention(q, k, v, q_pos, kv_pos, *, mesh, plan, window: int = 0,
         o = o.reshape(B_, G_l, R_, Cl, hd_).transpose(0, 3, 1, 2, 4)
         return o.astype(q_l.dtype)
 
-    from repro.dist import compat
     q_spec = P(None, ax, g_ax, None, None)
     kv_spec = P(None, ax, g_ax, None)
     p_spec = P(None, ax)
@@ -260,7 +259,7 @@ def ring_chunk_attention(q, k, v, q_pos, kv_pos, *, mesh, plan, window: int = 0,
     v = jax.lax.with_sharding_constraint(v, rep)
     q_pos = jax.lax.with_sharding_constraint(q_pos, rep)
     kv_pos = jax.lax.with_sharding_constraint(kv_pos, rep)
-    out = compat.shard_map(
+    out = jax.shard_map(
         region, mesh=mesh,
         in_specs=(q_spec, kv_spec, kv_spec, p_spec, p_spec),
         out_specs=q_spec, check_vma=False)(q, k, v, q_pos, kv_pos)

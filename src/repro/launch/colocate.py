@@ -40,6 +40,7 @@ from repro.core.monitor import LatencyMonitor
 from repro.core.runtime import PliantRuntime
 from repro.core.tenant import ServeTenant, TrainTenant
 from repro.data.pipeline import DataConfig, SyntheticLM
+from repro.launch.compile_cache import use_compile_cache
 from repro.launch.serve import serving_table
 from repro.launch.train import build_variant_steps
 from repro.models import api
@@ -47,7 +48,8 @@ from repro.serve.engine import Request, ServeEngine
 from repro.train import optim
 
 
-def main(argv=None):
+def main(argv=None) -> int:
+    """CLI entry point: 0 when every serving request finished."""
     p = argparse.ArgumentParser()
     p.add_argument("--serve-arch", default="gemma2-27b-smoke")
     p.add_argument("--train-arch", default="phi4-mini-3.8b-smoke")
@@ -74,6 +76,7 @@ def main(argv=None):
     p.add_argument("--json", default="", help="write summary JSON here")
     p.add_argument("--seed", type=int, default=0)
     args = p.parse_args(argv)
+    use_compile_cache()
 
     # ----------------------------------------------------- serve tenant ----
     scfg = get_config(args.serve_arch)
@@ -209,8 +212,8 @@ def main(argv=None):
     if args.json:
         with open(args.json, "w") as f:
             json.dump(summary, f, indent=1)
-    return summary
+    return 0 if summary["requests_done"] == len(reqs) else 1
 
 
 if __name__ == "__main__":
-    main()
+    raise SystemExit(main())

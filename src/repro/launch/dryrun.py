@@ -156,8 +156,6 @@ def _compile_and_measure(cfg, shape, mesh, knobs, *, policy, n_micro, remat):
     compiled = lowered.compile()
     t2 = time.time()
     cost = compiled.cost_analysis()
-    if isinstance(cost, (list, tuple)):   # jax<=0.4.x: one dict per program
-        cost = cost[0] if cost else {}
     mem = compiled.memory_analysis()
     coll = roofline.collective_bytes(compiled.as_text())
     return {
@@ -359,4 +357,4 @@ def main():
 
 
 if __name__ == "__main__":
-    main()
+    raise SystemExit(main())

@@ -4,8 +4,6 @@ from __future__ import annotations
 
 import jax
 
-import repro.dist  # noqa: F401  - installs jax.set_mesh/jax.shard_map shims
-
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)

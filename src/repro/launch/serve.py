@@ -14,7 +14,11 @@ source of truth with the colocation benchmarks, ordered precise-first.
 from __future__ import annotations
 
 import argparse
+import dataclasses
+import sys
 import time
+from dataclasses import dataclass
+from typing import Callable, Dict, List, Optional
 
 import jax
 import jax.numpy as jnp
@@ -27,28 +31,34 @@ from repro.core.explorer import explore
 from repro.core.monitor import LatencyMonitor
 from repro.core.runtime import PliantRuntime
 from repro.core.variants import VariantTable
+from repro.launch.compile_cache import use_compile_cache
 from repro.models import api
+from repro.roofline import DEFAULT_TARGET
 from repro.serve.engine import Request, ServeEngine
 
 
 def serving_table(cfg: ModelConfig, *, slots: int, max_len: int,
                   max_loss: float = 0.05,
                   page_occupancy: float = None,
-                  price_from_compile: bool = False) -> VariantTable:
+                  price_from_compile: bool = False, dtype=None,
+                  target: str = DEFAULT_TARGET) -> VariantTable:
     """The serving VariantTable for one engine shape, from the explorer.
 
     ``page_occupancy``: expected live-page fraction of a paged engine —
     prices decode HBM by live pages so the frontier sees paged savings.
     ``price_from_compile`` anchors that pricing on the compiled decode
-    cell's ``cost_analysis`` bytes (``explorer.decode_kv_share``) instead
-    of the coarse heuristic — one extra compile, so opt-in."""
+    cell's bytes (``explorer.decode_kv_share``) instead
+    of the coarse heuristic — one extra compile, so opt-in — with params
+    and caches in ``dtype`` (the engine's). ``target`` is the device kind
+    the explorer prices for."""
     shape = ShapeConfig("serve", max_len, slots, "decode")
     kv_share = None
     if price_from_compile and page_occupancy is not None:
         from repro.core.explorer import decode_kv_share
-        kv_share = decode_kv_share(cfg, slots, max_len)
+        kv_share = decode_kv_share(cfg, slots, max_len, dtype=dtype)
     return explore(cfg, shape, serving=True, max_loss=max_loss,
-                   page_occupancy=page_occupancy, kv_share=kv_share)
+                   page_occupancy=page_occupancy, kv_share=kv_share,
+                   target=target)
 
 
 def percentiles(lat, ps=(50, 95, 99)):
@@ -58,9 +68,16 @@ def percentiles(lat, ps=(50, 95, 99)):
     return {p: float(np.percentile(a, p)) for p in ps}
 
 
-def main(argv=None):
+def parser() -> argparse.ArgumentParser:
     p = argparse.ArgumentParser()
     p.add_argument("--arch", default="gemma2-27b-smoke")
+    p.add_argument("--layers", type=int, default=0,
+                   help="cut the model to its first N layers, widths kept "
+                        "(0 = the configuration's own depth)")
+    p.add_argument("--dtype", default="bfloat16",
+                   choices=["bfloat16", "float32"],
+                   help="params and cache pool dtype (float32 for "
+                        "bit-parity checks against another layout)")
     p.add_argument("--requests", type=int, default=16)
     p.add_argument("--slots", type=int, default=4)
     p.add_argument("--max-new", type=int, default=12)
@@ -116,15 +133,38 @@ def main(argv=None):
                    help="reject a queued request after waiting this many "
                         "seconds without admission (0 = wait forever)")
     p.add_argument("--seed", type=int, default=0)
-    args = p.parse_args(argv)
+    return p
 
+
+@dataclass
+class Served:
+    """What one ``serve`` run built and what came of it."""
+    engine: ServeEngine
+    requests: List[Request]
+    summary: Dict[str, object]
+
+
+def serve(argv=None, *, on_step: Optional[Callable] = None) -> Served:
+    """Build the engine the CLI describes, drive its requests to the end and
+    print the report. ``on_step(engine, step)`` runs after every engine step
+    (a driver's hook for mid-run actions, such as a forced variant swap).
+
+    Params and the cache pool share ``--dtype`` (bf16 by default, so a
+    published-width model fits one chip)."""
+    args = parser().parse_args(argv)
     cfg = get_config(args.arch)
-    params = api.init(cfg, jax.random.PRNGKey(args.seed), jnp.float32)
+    if args.layers:
+        cfg = dataclasses.replace(cfg, n_layers=args.layers)
+    dtype = jnp.dtype(args.dtype)
+    params = api.init(cfg, jax.random.PRNGKey(args.seed), dtype)
     occupancy = (min(1.0, (args.prompt_len + args.max_new) / args.max_len)
                  if args.paged else None)
+    dev = jax.devices()[0]
+    target = dev.device_kind if dev.platform == "tpu" else DEFAULT_TARGET
     table = serving_table(cfg, slots=args.slots, max_len=args.max_len,
                           page_occupancy=occupancy,
-                          price_from_compile=args.paged)
+                          price_from_compile=args.paged, dtype=dtype,
+                          target=target)
     names = [v.name for v in table.variants]
 
     mesh = None
@@ -147,6 +187,7 @@ def main(argv=None):
                       params=params, table=table, runtime=runtime,
                       temperature=args.temperature, mesh=mesh,
                       prefill_chunk=args.prefill_chunk, seed=args.seed,
+                      cache_dtype=dtype,
                       paged=args.paged, page_size=args.page_size,
                       n_pages=args.pool_pages,
                       max_admission_chunks=args.max_admission_chunks,
@@ -155,6 +196,7 @@ def main(argv=None):
                       megastep_k=args.megastep, eos_id=args.eos_id,
                       sync_timing=args.sync_timing,
                       donate=not args.no_donate)
+    del params                       # the engine holds (or resharded) them
     print(f"dispatch: {eng.explain_dispatch()}")
     print(f"dispatch: {eng.explain_prefill_dispatch()}")
     print(f"dispatch: {eng.explain_megastep()}")
@@ -196,6 +238,8 @@ def main(argv=None):
             break
         eng.step()
         steps += 1
+        if on_step is not None:
+            on_step(eng, steps)
     wall = time.perf_counter() - t0
 
     # per-token latency seen by each request (inter-token gap; first token's
@@ -220,7 +264,8 @@ def main(argv=None):
     pct = percentiles(tok_lat)
     viol = (float(np.mean(np.asarray(tok_lat) > args.qos_target))
             if args.qos_target > 0 and tok_lat else 0.0)
-    print(f"variants: {names} (active={names[eng.active_variant]})")
+    print(f"variants: {names} (active={names[eng.active_variant]}, "
+          f"priced for {table.target})")
     print(f"{done}/{len(reqs)} requests, {toks} tokens in {wall:.2f}s "
           f"({toks / max(wall, 1e-9):.1f} tok/s, rate={args.rate}/s)")
     ttft95 = float(np.percentile(ttft, 95)) if ttft else float("nan")
@@ -269,8 +314,27 @@ def main(argv=None):
             rej = r.rejection
             print(f"  rejected uid={rej.uid} waited={rej.waited_s:.3f}s "
                   f"queue_depth={rej.queue_depth} step={rej.step}")
+    summary = dict(requests=len(reqs), done=done,
+                   rejected=sum(r.rejected for r in reqs),
+                   unfinished=[r.uid for r in reqs
+                               if not (r.done or r.rejected)],
+                   tokens=toks, wall_s=wall, steps=steps,
+                   token_latency_s=pct, ttft_p95_s=ttft95)
+    return Served(eng, reqs, summary)
+
+
+def main(argv=None) -> int:
+    """CLI entry point: 0 when every request finished or was rejected by the
+    admission timeout it was given, 1 otherwise."""
+    use_compile_cache()
+    served = serve(argv)
+    left = served.summary["unfinished"]
+    if left:
+        print(f"error: {len(left)} requests neither done nor rejected: "
+              f"uids {left}", file=sys.stderr)
+        return 1
     return 0
 
 
 if __name__ == "__main__":
-    main()
+    raise SystemExit(main())

@@ -31,6 +31,7 @@ from repro.core.variants import VariantTable
 from repro.ckpt.checkpoint import CheckpointManager
 from repro.configs.base import ShapeConfig
 from repro.data.pipeline import DataConfig, Prefetcher, SyntheticLM
+from repro.launch.compile_cache import use_compile_cache
 from repro.models import api
 from repro.train import optim, step as step_mod
 
@@ -44,7 +45,9 @@ def build_variant_steps(cfg, table: VariantTable, opt_cfg, remat="none",
     table.compile_all(factory)
 
 
-def main(argv=None):
+def train(argv=None) -> float:
+    """Run the training job the CLI describes; returns the mean loss of
+    the last 10 steps."""
     p = argparse.ArgumentParser()
     p.add_argument("--arch", default="phi4-mini-3.8b-smoke")
     p.add_argument("--steps", type=int, default=100)
@@ -232,5 +235,11 @@ def main(argv=None):
     return np.mean(losses[-10:])
 
 
+def main(argv=None) -> int:
+    """CLI entry point: 0 when training ends on a finite loss."""
+    use_compile_cache()
+    return 0 if np.isfinite(train(argv)) else 1
+
+
 if __name__ == "__main__":
-    main()
+    raise SystemExit(main())

@@ -3,8 +3,7 @@ banded sliding-window path, cross-attention, and cached decode.
 
 The full-sequence causal path unrolls over query chunks with *static* growing
 KV slices, so compiled HLO FLOPs match true causal cost (no masked-waste) —
-this is the reference path the dry-run compiles. On real TPUs ``ops.flash``
-dispatches to the Pallas kernel instead.
+this is the reference path the dry-run compiles.
 
 Approximation hook (Pliant "loop perforation" applied to attention): a static
 ``kv_keep_stride`` > 1 drops off-diagonal KV chunks with stride, cutting both
@@ -453,7 +452,6 @@ def _sharded_write_attend(q, k_store, v_store, position, active,
     at cache dtype. Returns (o (B, G, R, hd), new PagedKVCache).
     """
     from jax.sharding import PartitionSpec as P
-    from repro.dist import compat
     from repro.kernels.paged_attention import paged_attention_impl
     b, g = plan.batch_axes, plan.kv_head_axis
     n_pages, Pg = cache.ppos.shape
@@ -476,7 +474,7 @@ def _sharded_write_attend(q, k_store, v_store, position, active,
 
     q_spec = P(b, g, None, None)
     kv_spec = P(b, None, g, None)
-    fn = compat.shard_map(
+    fn = jax.shard_map(
         inner, mesh=mesh,
         in_specs=(q_spec, P(b, g, None), P(b, g, None), P(b), P(b),
                   kv_spec, kv_spec, P(b, None), P(b, None)),

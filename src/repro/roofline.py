@@ -14,10 +14,42 @@ import re
 from dataclasses import dataclass
 from typing import Dict
 
-# TPU v5e-class hardware constants (per chip), per the brief.
-PEAK_FLOPS = 197e12        # bf16
-HBM_BW = 819e9             # bytes/s
-ICI_BW = 50e9              # bytes/s per link
+
+@dataclass(frozen=True)
+class ChipPeaks:
+    """Published per-chip peaks of one accelerator."""
+    bf16_flops: float      # FLOP/s
+    hbm_bw: float          # bytes/s
+    ici_link_bw: float     # bytes/s per link
+    source: str
+
+
+# Keyed by ``jax.Device.device_kind`` (a v5e reports "TPU v5 lite"). A kind
+# missing here is an error (``peaks``), never a silent default.
+PEAKS: Dict[str, ChipPeaks] = {
+    "TPU v5 lite": ChipPeaks(
+        bf16_flops=197e12, hbm_bw=819e9,
+        # 1,600 Gbit/s of interchip interconnect over 4 links
+        ici_link_bw=50e9,
+        source='Google Cloud documentation, "TPU v5e" (system architecture)'),
+}
+
+# the chip the dry-run pods and the analytic explorer price for
+DEFAULT_TARGET = "TPU v5 lite"
+
+
+def peaks(device_kind: str) -> ChipPeaks:
+    try:
+        return PEAKS[device_kind]
+    except KeyError:
+        raise ValueError(
+            f"no published peaks for device kind {device_kind!r}; known: "
+            f"{sorted(PEAKS)} (add the chip to roofline.PEAKS)") from None
+
+
+PEAK_FLOPS = PEAKS[DEFAULT_TARGET].bf16_flops
+HBM_BW = PEAKS[DEFAULT_TARGET].hbm_bw
+ICI_BW = PEAKS[DEFAULT_TARGET].ici_link_bw
 
 _DTYPE_BYTES = {
     "pred": 1, "s8": 1, "u8": 1, "s16": 2, "u16": 2, "bf16": 2, "f16": 2,

@@ -601,8 +601,7 @@ class ServeEngine:
     def _ctx(self):
         if self.mesh is None:
             return contextlib.nullcontext()
-        from repro.dist import compat
-        return compat.set_mesh(self.mesh)
+        return jax.set_mesh(self.mesh)
 
     def _init_caches(self, quantized: bool):
         if self.paged:

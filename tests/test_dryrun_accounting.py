@@ -23,6 +23,12 @@ def test_collective_parser_on_synthetic_hlo():
     assert "add" not in got
 
 
+def test_peaks_by_device_kind():
+    assert roofline.peaks("TPU v5 lite").hbm_bw == roofline.HBM_BW
+    with pytest.raises(ValueError, match="no published peaks"):
+        roofline.peaks("TPU v9 imaginary")
+
+
 def test_probe_calibration_matches_full_unroll(subproc):
     """base + sum(mult_i * delta_i) == fully-unrolled cost (within 2%)."""
     out = subproc("""

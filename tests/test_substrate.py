@@ -134,11 +134,11 @@ def test_checkpoint_atomicity_overwrite(tmp_path):
 def test_train_resume_continues(tmp_path):
     """checkpoint/restart: resumed run continues from the saved step."""
     from repro.launch import train as train_mod
-    loss1 = train_mod.main(["--arch", "mamba2-780m-smoke", "--steps", "16",
+    loss1 = train_mod.train(["--arch", "mamba2-780m-smoke", "--steps", "16",
                             "--batch", "4", "--seq", "32",
                             "--ckpt-dir", str(tmp_path), "--ckpt-period",
                             "8"])
-    loss2 = train_mod.main(["--arch", "mamba2-780m-smoke", "--steps", "24",
+    loss2 = train_mod.train(["--arch", "mamba2-780m-smoke", "--steps", "24",
                             "--batch", "4", "--seq", "32",
                             "--ckpt-dir", str(tmp_path), "--resume"])
     assert np.isfinite(loss1) and np.isfinite(loss2)

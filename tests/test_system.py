@@ -8,7 +8,7 @@ from repro.launch import train as train_mod
 
 
 def test_training_converges():
-    loss = train_mod.main(["--arch", "phi4-mini-3.8b-smoke", "--steps", "40",
+    loss = train_mod.train(["--arch", "phi4-mini-3.8b-smoke", "--steps", "40",
                            "--batch", "8", "--seq", "64", "--lr", "3e-3"])
     assert np.isfinite(loss)
     # random init sits at ~5.64 on this stream; the Markov/copy structure is
@@ -20,7 +20,7 @@ def test_pliant_training_converges_and_acts():
     import io, contextlib
     buf = io.StringIO()
     with contextlib.redirect_stdout(buf):
-        loss = train_mod.main(["--arch", "phi4-mini-3.8b-smoke", "--steps",
+        loss = train_mod.train(["--arch", "phi4-mini-3.8b-smoke", "--steps",
                                "40", "--batch", "8", "--seq", "64", "--lr",
                                "3e-3", "--pliant",
                                "--decision-interval", "0.2"])
