@@ -153,7 +153,6 @@ def main(rows: Rows):
                           **ekw)
         _drive(eng, cfg, np.random.default_rng(5), **cmp_trace)
         eng.step_latencies.clear()
-        eng.admit_latencies.clear()
         eng.step_admission_chunks.clear()
         eng.decode_dispatches = eng.row_dispatches = eng.row_tokens = 0
         eng.drain_block_s = 0.0

@@ -130,5 +130,6 @@ def flash_attention(q, k, v, *, causal: bool = True, window: int = 0,
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")),
         interpret=interpret,
+        name="flash_attention_prefill",
     )(q, k, v)
     return out[:, :, :Sq] if pad_q else out

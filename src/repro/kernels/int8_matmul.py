@@ -66,5 +66,6 @@ def int8_matmul(x_q, x_scale, w_q, w_scale, *, bm: int = 128, bn: int = 128,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
+        name="int8_matmul",
     )(x_q, x_scale, w_q, w_scale)
     return out[:M] if pad else out

@@ -170,6 +170,7 @@ def paged_attention_impl(q, kp, vp, ppos, block, position, *, window: int = 0,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
+        name="paged_attention_decode",
     )(block, position, q, kp, vp, ppos.reshape(n_pages, 1, P))
 
 

@@ -148,6 +148,7 @@ def _hop(qf, kf, vf, qp, kvp, m, l, acc, *, window: int, cap: float,
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")),
         interpret=interpret,
+        name="ring_attention_prefill",
     )(qf, kf, vf, qp[:, None], kvp[:, None], m, l, acc)
 
 

@@ -99,5 +99,6 @@ def ssd_scan(x, dt, a, b, c, *, chunk: int = 128, interpret: bool = False):
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
+        name="ssd_scan",
     )(xh, dth[..., None], dth[:, :, None, :], a.astype(jnp.float32), b, c)
     return out.transpose(0, 2, 1, 3)

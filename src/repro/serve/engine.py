@@ -90,6 +90,7 @@ from repro.models.attention import PagedKVCache
 from repro.models.mamba2 import MambaCache
 from repro.serve import pages as pages_mod
 from repro.serve import slots as slots_mod
+from repro.serve import spans as spans_mod
 from repro.train import step as step_mod
 
 
@@ -206,6 +207,11 @@ class ServeEngine:
                                        # elastic re-home or variant swap
 
     def __post_init__(self):
+        # layer spans, off until ``spans.enable()`` (serve.spans)
+        self.spans = spans_mod.SpanLog()
+        # executables made here and not yet called by the engine, with the
+        # program name and key ``program.obtain`` records at the first call
+        self._fresh: Dict[object, Tuple[str, int]] = {}
         if self.runtime is not None:
             self.table = self.runtime.table
         self._variant_knobs = ([v.knobs for v in self.table.variants]
@@ -294,7 +300,6 @@ class ServeEngine:
                              else 0)
         self.cur_tokens = np.zeros(self.batch_slots, np.int32)
         self.step_latencies: List[float] = []
-        self.admit_latencies: List[float] = []
         self.swaps: List[Tuple[int, int]] = []   # (step index, variant index)
         self.step_admission_chunks: List[Tuple[int, int]] = []  # (used, budget)
         self._token_lat: List[float] = []        # unflushed monitor samples
@@ -319,7 +324,9 @@ class ServeEngine:
         self.rejected: List[Request] = []
         self.stats: Dict[str, int] = dict(
             admission_timeouts=0, backoff_skips=0, collective_retries=0,
-            capacity_events=0, rehomes=0)
+            capacity_events=0, rehomes=0,
+            prefill_exe_misses=0,      # admission executables made anew
+            programs_obtained=0)       # first engine calls of executables
         self._tenant = None
         self._bound = False
         if (self.runtime is not None and self.runtime.auto_tenant
@@ -399,10 +406,13 @@ class ServeEngine:
         mk = self._decode_builder()
         mkey = self._mesh_key(self.mesh)
         prepared = getattr(self, "_prepared", {})   # post-init ordering
-        self._decodes = {
-            i: (prepared.pop((mkey, i), None)
-                or self._lower_decode(mk(self.cfg, self._variant_knobs[i])))
-            for i in self._decodes}
+        for i in self._decodes:
+            exe = prepared.pop((mkey, i), None)
+            if exe is None:
+                exe = self._lower_decode(
+                    mk(self.cfg, self._variant_knobs[i]))
+                self._fresh[exe] = ("decode_step", i)
+            self._decodes[i] = exe
 
     # ----------------------------------------------------------- dispatch --
 
@@ -566,6 +576,7 @@ class ServeEngine:
         if fn is not None:
             self._prefills.move_to_end(key)
             return fn
+        self.stats["prefill_exe_misses"] += 1
         # the caches argument (position 3 in both admission signatures)
         # donates like the decode path: a chunked prefill updates the pool /
         # fresh single-request cache in place instead of copying it per chunk
@@ -592,9 +603,26 @@ class ServeEngine:
                 fn = jax.jit(step, in_shardings=(self._param_sh, None, None,
                                                  None), **kw)
         self._prefills[key] = fn
+        self._fresh[fn] = ("prefill_chunk", chunk_len)
         while len(self._prefills) > self.max_prefill_exes:
-            self._prefills.popitem(last=False)
+            self._fresh.pop(self._prefills.popitem(last=False)[1], None)
         return fn
+
+    def _call(self, exe, args):
+        """Call an executable the engine made. The first call obtains its
+        program (trace, lowering, then a compile or a load from the
+        persistent cache): counted in ``stats['programs_obtained']`` and,
+        spans on, recorded as ``program.obtain``."""
+        first = self._fresh.pop(exe, None)
+        if first is None:
+            return exe(*args)
+        self.stats["programs_obtained"] += 1
+        sid = self.spans.on and self.spans.open(
+            "program.obtain", program=first[0], key=first[1])
+        out = exe(*args)
+        if sid:
+            self.spans.close(sid)
+        return out
 
     # ------------------------------------------------------------- helpers --
 
@@ -935,6 +963,7 @@ class ServeEngine:
         """Mirror the host block tables into the device caches (host-side
         allocation between steps; jitted steps only read the tables) and
         scrub freed pages' stale positions before they can be reused."""
+        sid = self.spans.on and self.spans.open("pool.push_blocks")
         bt = jnp.asarray(self.pool.blocks)
         scrub = self.pool.drain_scrub()
         pids = jnp.asarray(scrub, jnp.int32) if scrub else None
@@ -952,6 +981,8 @@ class ServeEngine:
         if self._cache_sh is not None:
             with self._ctx():
                 self.caches = jax.device_put(self.caches, self._cache_sh)
+        if sid:
+            self.spans.close(sid, scrubbed=len(scrub))
 
     def _mamba_snapshot(self, slot: int):
         """Host copy of the slot's SSM state rows (prefix-boundary snapshot
@@ -997,9 +1028,9 @@ class ServeEngine:
         with self._ctx():
             while start < S:
                 C = min(self.prefill_chunk, S - start)
-                logits, caches = self._prefill_exe(C)(
+                logits, caches = self._call(self._prefill_exe(C), (
                     self.params, jnp.asarray(toks[None, start:start + C]),
-                    jnp.asarray(start, jnp.int32), caches)
+                    jnp.asarray(start, jnp.int32), caches))
                 start += C
         return logits, caches
 
@@ -1048,6 +1079,10 @@ class ServeEngine:
         SSM rows; prefill itself is advanced by ``_advance_admissions``.
         Does NOT stamp ``t_admit_start``: queue-wait ends when the first
         chunk RUNS (``_advance_one``), not when the admission is opened."""
+        if not self.pending:
+            return
+        sid = self.spans.on and self.spans.open("sched.admit")
+        n_open = len(self._admissions)
         started_any = False
         while self.pending:
             slot = next((i for i in range(self.batch_slots)
@@ -1123,6 +1158,9 @@ class ServeEngine:
         if started_any:
             # ONE block-table push covers every admission opened this call
             self._push_blocks()
+        if sid:
+            self.spans.close(sid, uids=[a.req.uid for a in list(
+                self._admissions.values())[n_open:]])
 
     def _chunk_budget(self) -> int:
         """Prefill chunks this step may spend across all in-flight
@@ -1202,12 +1240,14 @@ class ServeEngine:
         end = next(b for b in adm.stops if b > adm.next)
         C = min(self.prefill_chunk, end - adm.next)
         toks = np.asarray(req.prompt[adm.next:adm.next + C], np.int32)
+        sid = self.spans.on and self.spans.open(
+            "prefill.chunk", uid=req.uid, slot=adm.slot, C=C)
         t0 = time.perf_counter()
         with self._ctx():
-            adm.logits, self.caches = self._prefill_exe(C)(
+            adm.logits, self.caches = self._call(self._prefill_exe(C), (
                 self.params, jnp.asarray(toks[None]),
                 jnp.asarray(adm.next, jnp.int32), self.caches,
-                jnp.asarray(adm.slot, jnp.int32))
+                jnp.asarray(adm.slot, jnp.int32)))
         adm.next += C
         # NO per-chunk (or final-chunk) block here: every sync is deferred
         # to the step's single drain point (_drain_admissions), so the final
@@ -1219,6 +1259,8 @@ class ServeEngine:
             self.pool.register_prefix(adm.slot, req.prompt,
                                       self.active_knobs, adm.next,
                                       mamba=self._mamba_snapshot(adm.slot))
+        if sid:
+            self.spans.close(sid)
         if adm.next < S:
             return
         # admission complete: register remaining boundaries (host
@@ -1243,6 +1285,9 @@ class ServeEngine:
         into the device carry via ``_inject_slots``."""
         if not self._await_admit:
             return
+        sid = self.spans.on and self.spans.open(
+            "admit.drain",
+            uids=[a.req.uid for a in self._await_admit.values()])
         freed = False
         for slot, adm in list(self._await_admit.items()):
             req = adm.req
@@ -1254,7 +1299,6 @@ class ServeEngine:
             del self._await_admit[slot]
             tok = int(self._sample_rows(logits, [req])[0])
             now = time.perf_counter()
-            self.admit_latencies.append(adm.compute_s)
             self._token_lat.append(now - req.t_admit_start)  # TTFT (wall)
             req.t_admit = now                  # admission COMPLETION
             req.admit_compute_s = adm.compute_s
@@ -1275,6 +1319,8 @@ class ServeEngine:
                 self._inject_slots.add(slot)
         if freed:
             self._push_blocks()
+        if sid:
+            self.spans.close(sid)
 
     def _admit(self) -> None:
         """Dense path: legacy synchronous admission (full chunked prefill
@@ -1295,7 +1341,6 @@ class ServeEngine:
                 self.pending.popleft()
                 tok = int(self._sample_rows(np.asarray(logits), [req])[0])
                 now = time.perf_counter()
-                self.admit_latencies.append(now - t0)
                 self._token_lat.append(now - t0)   # TTFT sample
                 req.t_admit = now                  # admission COMPLETION
                 req.admit_compute_s = now - t0     # sync: compute == wall
@@ -1322,7 +1367,7 @@ class ServeEngine:
             retry = self._collective_failures > 0
             if retry and self.donate:
                 safe = jax.tree.map(jnp.copy, args[cache_idx])
-            out = exe(*args)
+            out = self._call(exe, args)
             if not retry:
                 return out
             self._collective_failures -= 1
@@ -1354,6 +1399,7 @@ class ServeEngine:
             exe = jax.jit(step, in_shardings=in_sh, out_shardings=out_sh,
                           **kw)
         self._megasteps[key] = exe
+        self._fresh[exe] = ("decode_megastep", k)
         return exe
 
     def _dispatch_megastep(self) -> Optional[dict]:
@@ -1375,6 +1421,7 @@ class ServeEngine:
             # so idle engines hold no donated-cache chain
             self._carry = None
             return None
+        sid = self.spans.on and self.spans.open("decode.dispatch")
         k = self._megastep_budget()
         # never scan past the longest remaining budget: a row with one
         # token left must not pay a K-step full-batch scan
@@ -1436,6 +1483,8 @@ class ServeEngine:
             self._carry = (cur, pos, alive, draws, budget)
         self._inject_slots.clear()
         self.decode_dispatches += 1
+        if sid:
+            self.spans.close(sid, k=k, rows=len(rows))
         return dict(toks=toks, rows=[(i, self.slots[i]) for i in rows],
                     k=k, t0=t0)
 
@@ -1446,6 +1495,7 @@ class ServeEngine:
         same per-megastep -> per-token attribution the QoS monitor applies
         (``LatencyMonitor.record_megastep``). Finished rows free their
         slot/pages here; banded archs release window-dead pages."""
+        sid = self.spans.on and self.spans.open("decode.drain")
         t0 = time.perf_counter()
         toks = np.asarray(flight["toks"])
         now = time.perf_counter()
@@ -1493,6 +1543,8 @@ class ServeEngine:
                     i, int(self.positions[i]) - self._window_free)
         if freed:
             self._push_blocks()
+        if sid:
+            self.spans.close(sid, tokens=sum(emitted))
         if self.runtime is not None and emitted:
             self.runtime.monitor.record_megastep(wall, emitted)
 
@@ -1539,6 +1591,15 @@ class ServeEngine:
         never stalls the decoders for more than the chunk budget. Dense:
         legacy synchronous admission, then decode. All tick the Pliant
         control loop at the step boundary."""
+        sid = self.spans.on and self.spans.open(
+            "engine.step", step=self.step_count + 1)
+        try:
+            self._step()
+        finally:
+            if sid:
+                self.spans.close(sid)
+
+    def _step(self) -> None:
         self.step_count += 1
         self._process_capacity()   # deadline-reached capacity events cut
         self._expire_pending()     # over first, at the step boundary
@@ -1558,6 +1619,8 @@ class ServeEngine:
                 self.pool.replenish()     # keep headroom between steps
             self._control_tick()       # flush TTFT samples of 1-token admits
             return
+        sid = self.spans.on and self.spans.open("decode.dispatch", k=1,
+                                                 rows=len(rows))
         if self.paged:
             # map each live slot's write page before the step scatters to it
             # (live growth bypasses the reclaim limit — see serve.pages).
@@ -1585,6 +1648,8 @@ class ServeEngine:
                 self._decodes[self._active], args, cidx)
             self.caches = new_caches
             self.decode_dispatches += 1
+            if sid:
+                self.spans.close(sid)
             if self.paged:
                 # the step's single drain point: admission chunks were
                 # dispatched BEFORE the decode, so draining them here never
@@ -1593,6 +1658,7 @@ class ServeEngine:
                 self._drain_admissions()
             # fused greedy: ``out`` is (B,) sampled token ids — B*4 bytes
             # off-device per step instead of the (B, V) logits matrix
+            sid = self.spans.on and self.spans.open("decode.drain")
             tb = time.perf_counter()
             out = np.asarray(out)
             self.drain_block_s += time.perf_counter() - tb
@@ -1635,6 +1701,8 @@ class ServeEngine:
                     i, int(self.positions[i]) - self._window_free)
         if freed:
             self._push_blocks()
+        if sid:
+            self.spans.close(sid, tokens=len(rows))
         if self.paged:
             self.pool.replenish()      # watermark top-up, off the admission
         self._token_lat.extend([dt] * len(rows))   # path (between steps)

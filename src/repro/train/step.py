@@ -138,20 +138,20 @@ def make_train_step(cfg: ModelConfig, knobs: ApproxKnobs = PRECISE, *,
 
 def make_serve_step(cfg: ModelConfig, knobs: ApproxKnobs = PRECISE, *,
                     ep_axis: Optional[str] = None, mesh=None):
-    """Returns step(params, tokens, position, caches[, enc_out])
+    """Returns decode_step(params, tokens, position, caches[, enc_out])
     -> (logits, new_caches). One new token against the KV/SSM caches."""
     decode = api.decode_fn(cfg)
 
     if cfg.family == "encdec":
-        def step(params, tokens, position, caches, enc_out):
+        def decode_step(params, tokens, position, caches, enc_out):
             return decode(params, tokens, position, caches, enc_out,
                           knobs=knobs)
-        return step
+        return decode_step
 
-    def step(params, tokens, position, caches):
+    def decode_step(params, tokens, position, caches):
         return decode(params, tokens, position, caches, knobs=knobs,
                       ep_axis=ep_axis, mesh=mesh)
-    return step
+    return decode_step
 
 
 def make_paged_serve_step(cfg: ModelConfig, knobs: ApproxKnobs = PRECISE, *,
@@ -160,7 +160,7 @@ def make_paged_serve_step(cfg: ModelConfig, knobs: ApproxKnobs = PRECISE, *,
                           dynamic_scatter: bool = False,
                           sample_greedy: bool = False,
                           interpret: bool = False):
-    """Returns step(params, tokens, position, active, caches)
+    """Returns decode_step(params, tokens, position, active, caches)
     -> (logits_or_tokens, new_caches) — the paged engine's decode cell.
 
     ``active`` (B,) bool masks per-slot cache writes so decode steps can
@@ -181,7 +181,7 @@ def make_paged_serve_step(cfg: ModelConfig, knobs: ApproxKnobs = PRECISE, *,
     decode = api.decode_fn(cfg)
     assert cfg.family != "encdec", "paged serving: decoder-only path"
 
-    def step(params, tokens, position, active, caches):
+    def decode_step(params, tokens, position, active, caches):
         logits, caches = decode(params, tokens, position, caches, knobs=knobs,
                                 ep_axis=ep_axis, mesh=mesh, active=active,
                                 use_kernel=use_kernel,
@@ -190,7 +190,7 @@ def make_paged_serve_step(cfg: ModelConfig, knobs: ApproxKnobs = PRECISE, *,
         if sample_greedy:
             return jnp.argmax(logits, axis=-1).astype(jnp.int32), caches
         return logits, caches
-    return step
+    return decode_step
 
 
 def make_paged_megastep(cfg: ModelConfig, knobs: ApproxKnobs = PRECISE, *,
@@ -199,8 +199,8 @@ def make_paged_megastep(cfg: ModelConfig, knobs: ApproxKnobs = PRECISE, *,
                         mesh=None, use_kernel: Optional[bool] = None,
                         dynamic_scatter: bool = False,
                         interpret: bool = False):
-    """Returns step(params, cur, pos, alive, uids, draws, budget, caches)
-    -> (toks (B,K), cur, pos, alive, draws, budget, caches) — K fused
+    """Returns decode_megastep(params, cur, pos, alive, uids, draws, budget,
+    caches) -> (toks (B,K), cur, pos, alive, draws, budget, caches) — K fused
     decode steps with on-device sampling and stop masking in ONE
     executable (``lm.decode_megastep``).
 
@@ -212,19 +212,21 @@ def make_paged_megastep(cfg: ModelConfig, knobs: ApproxKnobs = PRECISE, *,
     from repro.models import lm as lm_mod
     assert cfg.family != "encdec", "megastep: decoder-only path"
 
-    def step(params, cur, pos, alive, uids, draws, budget, caches):
+    def decode_megastep(params, cur, pos, alive, uids, draws, budget,
+                        caches):
         return lm_mod.decode_megastep(
             params, cur, pos, alive, uids, draws, budget, caches, cfg, knobs,
             k=k, temperature=temperature, seed=seed, eos_id=eos_id,
             ep_axis=ep_axis, mesh=mesh, use_kernel=use_kernel,
             dyn_scatter=dynamic_scatter, interpret=interpret)
-    return step
+    return decode_megastep
 
 
 def make_admission_step(cfg: ModelConfig, knobs: ApproxKnobs = PRECISE, *,
                         mesh=None, use_kernel: Optional[bool] = None,
                         interpret: bool = False):
-    """Returns step(params, tokens, start, caches) -> (logits, caches).
+    """Returns prefill_chunk(params, tokens, start, caches)
+    -> (logits, caches).
 
     One prompt chunk against existing decode caches — the serving engine's
     chunked-prefill admission cell. ``start`` is traced, so ONE executable
@@ -234,19 +236,20 @@ def make_admission_step(cfg: ModelConfig, knobs: ApproxKnobs = PRECISE, *,
     ``use_kernel``/``interpret`` mirror ``make_paged_serve_step``."""
     from repro.serve import prefill as prefill_mod
 
-    def step(params, tokens, start, caches):
+    def prefill_chunk(params, tokens, start, caches):
         return prefill_mod.prefill_chunk(params, tokens, start, caches, cfg,
                                          knobs=knobs, mesh=mesh,
                                          use_kernel=use_kernel,
                                          interpret=interpret)
-    return step
+    return prefill_chunk
 
 
 def make_paged_admission_step(cfg: ModelConfig, knobs: ApproxKnobs = PRECISE,
                               *, dynamic_scatter: bool = False, mesh=None,
                               use_kernel: Optional[bool] = None,
                               interpret: bool = False):
-    """Returns step(params, tokens, start, caches, slot) -> (logits, caches).
+    """Returns prefill_chunk(params, tokens, start, caches, slot)
+    -> (logits, caches).
 
     The paged engine's admission cell: one prompt chunk written straight
     into the batched page-pool caches at ``slot``'s block-table row. Both
@@ -257,14 +260,14 @@ def make_paged_admission_step(cfg: ModelConfig, knobs: ApproxKnobs = PRECISE,
     applies."""
     from repro.serve import prefill as prefill_mod
 
-    def step(params, tokens, start, caches, slot):
+    def prefill_chunk(params, tokens, start, caches, slot):
         return prefill_mod.paged_prefill_chunk(params, tokens, start, caches,
                                                slot, cfg, knobs=knobs,
                                                dyn_scatter=dynamic_scatter,
                                                mesh=mesh,
                                                use_kernel=use_kernel,
                                                interpret=interpret)
-    return step
+    return prefill_chunk
 
 
 def make_prefill_fn(cfg: ModelConfig, knobs: ApproxKnobs = PRECISE, *,
