@@ -604,18 +604,22 @@ def paged_decode_attention(params, x, position, cache: PagedKVCache,
 
 def paged_chunk_attention(params, x, positions, cache: PagedKVCache,
                           cfg: ModelConfig, slot, *, window: int = 0,
-                          kv_scale: float = 0.0, dyn_scatter: bool = False,
+                          kv_scale: float = 0.0, valid=None,
+                          dyn_scatter: bool = False,
                           mesh=None, use_kernel: Optional[bool] = None,
                           interpret: bool = False):
     """C-token prompt-chunk step for ONE slot of the paged pool (chunked
     admission). x: (1,C,D); positions: (1,C); ``slot`` is a traced scalar —
-    one executable per chunk length serves every slot and every chunk.
+    one executable of chunk length C serves every slot and every chunk.
 
     Scatters the chunk's K/V into the slot's (pre-allocated, private) pages,
     then attends over every mapped page — the chunk's own entries included,
     causally masked by position. Prefix-shared pages are simply already
     present in the block row; chunks the engine skipped on a prefix hit were
-    never run.
+    never run. ``valid`` (C,) bool (None = every row) marks the rows whose
+    K/V and position are written: the others, the pad rows of a chunk
+    shorter than C, write to the never-read null page (``dyn_scatter``) or
+    are dropped from the one-hot mask, as inactive decode rows are.
 
     ``mesh`` + kernel requested: when ``dist.sharding.prefill_plan`` finds a
     sequence layout, the attend runs in ``kernels.ring_attention``. The
@@ -651,16 +655,19 @@ def paged_chunk_attention(params, x, positions, cache: PagedKVCache,
     phys = jnp.take(brow, pos_c // P)                     # (C,)
     if dyn_scatter:
         # dynamic-index write (unsharded pools only — see
-        # paged_decode_attention): chunk positions are distinct, so the
-        # per-token targets never collide
+        # paged_decode_attention): valid rows' positions are distinct, so
+        # their targets never collide; pad rows collide on the null page
+        tgt = phys if valid is None else jnp.where(valid, phys, 0)
         off = pos_c % P
-        nkp = cache.kp.at[phys, off].set(k_store[0])
-        nvp = cache.vp.at[phys, off].set(v_store[0])
-        nppos = cache.ppos.at[phys, off].set(pos_c)
+        nkp = cache.kp.at[tgt, off].set(k_store[0])
+        nvp = cache.vp.at[tgt, off].set(v_store[0])
+        nppos = cache.ppos.at[tgt, off].set(pos_c)
     else:
         sel = ((jnp.arange(n_pages)[None, :, None] == phys[:, None, None])
                & (jnp.arange(P)[None, None, :]
                   == (pos_c % P)[:, None, None]))
+        if valid is not None:
+            sel &= valid[:, None, None]
         write = sel.any(axis=0)
         nkp = _page_scatter(sel, write, cache.kp, k_store[0])
         nvp = _page_scatter(sel, write, cache.vp, v_store[0])

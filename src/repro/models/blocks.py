@@ -105,12 +105,14 @@ def block_prefill(kind: str, params, h, positions, cache, cfg: ModelConfig,
 
 def block_prefill_paged(kind: str, params, h, positions, cache,
                         cfg: ModelConfig, knobs: ApproxKnobs = PRECISE, *,
-                        slot, ep_axis: Optional[str] = None, mesh=None,
-                        dyn_scatter: bool = False,
+                        slot, valid=None, ep_axis: Optional[str] = None,
+                        mesh=None, dyn_scatter: bool = False,
                         use_kernel: Optional[bool] = None,
                         interpret: bool = False):
     """Paged sibling of ``block_prefill``: one slot's prompt chunk against
     the shared page pool / per-slot Mamba rows. h: (1,C,D); ``slot`` traced.
+    ``valid`` (C,) bool marks the rows whose K/V reach the slot's pages
+    (``attention.paged_chunk_attention``); a Mamba layer takes every row.
     """
     aux = jnp.zeros((), jnp.float32)
     prec = knobs.matmul_precision
@@ -133,8 +135,8 @@ def block_prefill_paged(kind: str, params, h, positions, cache,
     y, new_cache = attn_mod.paged_chunk_attention(
         params["attn"], rms_norm(h, params["norm_attn"], cfg.norm_eps),
         positions, cache, cfg, slot, window=window, kv_scale=kv_scale,
-        dyn_scatter=dyn_scatter, mesh=mesh, use_kernel=use_kernel,
-        interpret=interpret)
+        valid=valid, dyn_scatter=dyn_scatter, mesh=mesh,
+        use_kernel=use_kernel, interpret=interpret)
     h = h + y
     hn = rms_norm(h, params["norm_mlp"], cfg.norm_eps)
     if "moe" in params:

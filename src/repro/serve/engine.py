@@ -89,6 +89,7 @@ from repro.models import lm
 from repro.models.attention import PagedKVCache
 from repro.models.mamba2 import MambaCache
 from repro.serve import pages as pages_mod
+from repro.serve import prefill as prefill_mod
 from repro.serve import slots as slots_mod
 from repro.serve import spans as spans_mod
 from repro.train import step as step_mod
@@ -165,6 +166,8 @@ class ServeEngine:
     kernel_interpret: bool = False     # Pallas interpret mode (CPU CI of the
                                        # sharded kernel path)
     max_prefill_exes: int = 16         # LRU bound on admission executables
+                                       # (one per knob set where tails pad,
+                                       # else one per knob set and length)
     pack_window: int = 4               # pending requests scanned per step for
                                        # page-aware packing (bounds host work
                                        # while the pool is blocked)
@@ -223,6 +226,10 @@ class ServeEngine:
         # greedy paged engines fuse argmax into the decode executable: the
         # step returns (B,) token ids, so the host never pulls (B, V) logits
         self._fused_sample = bool(self.paged and self.temperature <= 0.0)
+        # paged admission pads every shorter chunk to ``prefill_chunk``
+        # where pad rows are exact, so one program serves every tail
+        self._pad_chunks = bool(self.paged
+                                and prefill_mod.pads_exactly(self.cfg))
         self._derive_plans()
         if self.paged:
             self._page_spec = pages_mod.spec_for(
@@ -255,7 +262,8 @@ class ServeEngine:
         self._build_decodes()
         # admission executables, keyed by (knobs, chunk len, paged) — NOT by
         # variant index, so table entries with identical admission knobs
-        # share one compiled chunk cell — and LRU-bounded
+        # share one compiled chunk cell — and LRU-bounded; a padding engine
+        # keys every length as the full chunk
         self._prefills: "collections.OrderedDict[Tuple, object]" = \
             collections.OrderedDict()
         self._insert = jax.jit(slots_mod.insert_request)
@@ -326,6 +334,7 @@ class ServeEngine:
             admission_timeouts=0, backoff_skips=0, collective_retries=0,
             capacity_events=0, rehomes=0,
             prefill_exe_misses=0,      # admission executables made anew
+            prefill_pad_tokens=0,      # pad rows run in admission chunks
             programs_obtained=0)       # first engine calls of executables
         self._tenant = None
         self._bound = False
@@ -454,7 +463,8 @@ class ServeEngine:
     @property
     def sharded_prefill(self) -> bool:
         """True when this engine's admission chunks run the ring-attention
-        sequence-parallel cell (full-size chunks; ragged tails re-plan)."""
+        sequence-parallel cell (full-size chunks, and ragged tails where
+        they pad; unpadded tails re-plan)."""
         if self._prefill_plan is None:
             return False
         if self.use_kernel is not None:
@@ -571,6 +581,8 @@ class ServeEngine:
                        out_shardings=(None, self._cache_sh), **kw)
 
     def _prefill_exe(self, chunk_len: int):
+        if self._pad_chunks:
+            chunk_len = self.prefill_chunk
         key = (self.active_knobs, chunk_len, self.paged)
         fn = self._prefills.get(key)
         if fn is not None:
@@ -1239,9 +1251,12 @@ class ServeEngine:
         S = len(req.prompt)
         end = next(b for b in adm.stops if b > adm.next)
         C = min(self.prefill_chunk, end - adm.next)
-        toks = np.asarray(req.prompt[adm.next:adm.next + C], np.int32)
+        pad = self.prefill_chunk - C if self._pad_chunks else 0
+        toks = np.full(C + pad, prefill_mod.PAD_TOKEN, np.int32)
+        toks[:C] = req.prompt[adm.next:adm.next + C]
+        self.stats["prefill_pad_tokens"] += pad
         sid = self.spans.on and self.spans.open(
-            "prefill.chunk", uid=req.uid, slot=adm.slot, C=C)
+            "prefill.chunk", uid=req.uid, slot=adm.slot, C=C, pad=pad)
         t0 = time.perf_counter()
         with self._ctx():
             adm.logits, self.caches = self._call(self._prefill_exe(C), (

@@ -131,6 +131,17 @@ def prefill_chunk(params, tokens, start, caches, cfg: ModelConfig,
     return logits_fn(params, h[:, -1], cfg), new_caches
 
 
+PAD_TOKEN = -1      # pad entry of a paged admission chunk
+
+
+def pads_exactly(cfg: ModelConfig) -> bool:
+    """True when pad rows in a paged admission chunk leave every valid
+    row's result and every mapped page as an exact-length chunk would: no
+    SSM layer (its state would absorb the pad tokens) and no expert layer
+    (pad tokens would take expert capacity from real ones)."""
+    return MAMBA not in cfg.pattern and cfg.moe is None
+
+
 def paged_prefill_chunk(params, tokens, start, caches, slot,
                         cfg: ModelConfig, knobs: ApproxKnobs = PRECISE,
                         dyn_scatter: bool = False, *, mesh=None,
@@ -143,12 +154,23 @@ def paged_prefill_chunk(params, tokens, start, caches, slot,
     single-request cache that is then slot-scattered), this writes straight
     into the batched page pool through the slot's block table — there is no
     insert step, and prefix-shared pages are simply already mapped. Returns
-    (last-token logits (1,V) fp32, advanced caches).
+    (last-valid-token logits (1,V) fp32, advanced caches).
+
+    The chunk may end in pad entries (``PAD_TOKEN``) after n >= 1 valid
+    tokens, so one executable of length C serves every shorter chunk too.
+    Each pad row repeats the last valid token at its position, so it
+    computes a copy of row n-1 over the same keys and stays finite under
+    every causal and window mask; its K/V and position writes go to the
+    never-read null page. An all-valid chunk is the plain computation. The
+    pad rows are exact only where ``pads_exactly(cfg)``.
     """
     from repro.models.blocks import block_prefill_paged
-    h = params["embed"][tokens]
-    B, C, D = h.shape
-    positions = start + jnp.broadcast_to(jnp.arange(C), (B, C))
+    B, C = tokens.shape
+    valid = tokens[0] >= 0                                    # (C,)
+    last = jnp.sum(valid.astype(jnp.int32)) - 1
+    rel = jnp.minimum(jnp.arange(C), last)
+    h = params["embed"][jnp.take(tokens, rel, axis=1)]
+    positions = start + jnp.broadcast_to(rel, (B, C))
     shared = params.get("shared")
 
     def group_body(h, xs):
@@ -158,15 +180,17 @@ def paged_prefill_chunk(params, tokens, start, caches, slot,
             p = shared if kind == SHARED_ATTN else group_params.get(f"pos{j}")
             h, nc, _ = block_prefill_paged(kind, p, h, positions,
                                            group_caches[j], cfg, knobs,
-                                           slot=slot, dyn_scatter=dyn_scatter,
+                                           slot=slot, valid=valid,
+                                           dyn_scatter=dyn_scatter,
                                            mesh=mesh, use_kernel=use_kernel,
                                            interpret=interpret)
             new_caches.append(nc)
         return h, tuple(new_caches)
 
     h, new_caches = jax.lax.scan(group_body, h, (params["groups"], caches))
+    h = jax.lax.dynamic_index_in_dim(h, last, axis=1, keepdims=False)
     h = rms_norm(h, params["final_norm"], cfg.norm_eps)
-    return logits_fn(params, h[:, -1], cfg), new_caches
+    return logits_fn(params, h, cfg), new_caches
 
 
 def prefill_with_cache(params, tokens, cfg: ModelConfig, max_len: int,

@@ -253,11 +253,14 @@ def make_paged_admission_step(cfg: ModelConfig, knobs: ApproxKnobs = PRECISE,
 
     The paged engine's admission cell: one prompt chunk written straight
     into the batched page-pool caches at ``slot``'s block-table row. Both
-    ``start`` and ``slot`` are traced — ONE executable per (variant, chunk
-    length) serves every chunk of every slot. ``dynamic_scatter`` as in
-    ``make_paged_serve_step``; ``mesh``/``use_kernel``/``interpret`` select
-    the ring-sequence-parallel chunk attention when the prefill plan
-    applies."""
+    ``start`` and ``slot`` are traced, and ``tokens`` may end in pad
+    entries (``serve.prefill.PAD_TOKEN``) whose rows write nothing the slot
+    reads: where ``serve.prefill.pads_exactly(cfg)``, ONE executable per
+    variant, of the full chunk length, serves every chunk of every slot,
+    ragged tails included; elsewhere the engine keeps one per (variant,
+    chunk length). ``dynamic_scatter`` as in ``make_paged_serve_step``;
+    ``mesh``/``use_kernel``/``interpret`` select the ring-sequence-parallel
+    chunk attention when the prefill plan applies."""
     from repro.serve import prefill as prefill_mod
 
     def prefill_chunk(params, tokens, start, caches, slot):

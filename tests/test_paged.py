@@ -384,10 +384,15 @@ def test_prefill_exe_cache_knob_keyed_and_bounded():
     assert not any(k[0] == int8 for k in eng._prefills)
     assert 1 not in eng._decodes and 2 not in eng._decodes
 
-    eng.max_prefill_exes = 2
+    # the LRU bound, on a paged SSM hybrid, whose admission programs stay
+    # keyed per chunk length (its state would absorb pad tokens)
+    hcfg, hparams = setup("zamba2-2.7b")
+    hyb = ServeEngine(hcfg, batch_slots=2, max_len=32, params=hparams,
+                      paged=True, page_size=4, max_prefill_exes=2)
     for c in (1, 2, 3, 5):
-        eng._prefill_exe(c)
-    assert len(eng._prefills) <= 2
+        hyb._prefill_exe(c)
+    assert hyb.stats["prefill_exe_misses"] == 4
+    assert len(hyb._prefills) <= 2
 
 
 def test_paged_engine_multi_device(subproc):

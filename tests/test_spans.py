@@ -88,6 +88,8 @@ def test_prefill_chunks_carry_their_request(megastep_k):
     for r in reqs:
         mine = [s.attrs["C"] for s in chunks if s.attrs["uid"] == r.uid]
         assert sum(mine) == len(r.prompt)
+    assert all(s.attrs["C"] + s.attrs["pad"] == eng.prefill_chunk
+               for s in chunks)
     admitted = [u for s in spans if s.name == "sched.admit"
                 for u in s.attrs["uids"]]
     assert sorted(admitted) == [r.uid for r in reqs]
@@ -101,9 +103,10 @@ def test_program_obtain_spans_match_the_counter(megastep_k):
     progs = {s.attrs["program"] for s in got}
     assert "prefill_chunk" in progs
     assert ("decode_megastep" if megastep_k else "decode_step") in progs
-    # prompts of 5, 8 and 11 tokens in chunks of 4: tails 1 and 3 and the
-    # full chunk, each a new admission executable
-    assert eng.stats["prefill_exe_misses"] == 3
+    # prompts of 5, 8 and 11 tokens in chunks of 4: tails 1 and 3 pad to
+    # the full chunk, so one admission executable serves all three
+    assert eng.stats["prefill_exe_misses"] == 1
+    assert eng.stats["prefill_pad_tokens"] == 3 + 1
     by_id = {s.sid: s for s in spans}
     assert all(by_id[s.parent].name in ("prefill.chunk", "decode.dispatch")
                for s in got)
