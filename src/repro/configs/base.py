@@ -21,10 +21,32 @@ SHARED_ATTN = "shared"   # zamba2-style shared-weight attention block
 
 @dataclass(frozen=True)
 class MoEConfig:
-    n_experts: int
+    n_experts: int                   # routed experts the router scores
     top_k: int
-    # capacity factor for dense dispatch (tokens per expert per batch*seq)
+    d_expert: int                    # width of one routed expert's SwiGLU
+    # capacity factor of the expert-parallel dispatch (tokens per expert per
+    # batch*seq); the single-device layer is dropless
     capacity_factor: float = 1.25
+    # routed experts this chip holds: (first, count); count 0 = all of them.
+    # Picks on the others add nothing here (they live on other chips)
+    held: Tuple[int, int] = (0, 0)
+    d_shared: int = 0                # width of the shared experts' SwiGLU
+    scoring: str = "softmax"         # softmax | sigmoid
+    score_bias: bool = False         # selection adds a learned per-expert
+                                     # bias (DeepSeek-V3 noaux_tc)
+    norm_topk: bool = True           # picked weights renormalised to sum 1
+    scale: float = 1.0               # routed_scaling_factor
+
+    @property
+    def held_range(self) -> Tuple[int, int]:
+        """[lo, hi) of the routed experts held here."""
+        lo, n = self.held
+        return (lo, lo + (n or self.n_experts))
+
+    @property
+    def n_held(self) -> int:
+        lo, hi = self.held_range
+        return hi - lo
 
 
 @dataclass(frozen=True)
@@ -53,6 +75,15 @@ class ModelConfig:
     attn_softcap: float = 0.0        # gemma2-style attention logit softcap
     final_softcap: float = 0.0       # gemma2-style final logit softcap
     rope_theta: float = 10_000.0
+    # latent attention (MLA, DeepSeek-V2): > 0 caches one ``kv_lora_rank``
+    # latent plus one shared ``rope_dim`` rotary key per token; heads take
+    # ``head_dim`` nope dims + ``rope_dim`` rope dims for q/k, ``head_dim``
+    # for v (q_lora_rank absent: q comes straight from x)
+    kv_lora_rank: int = 0
+    rope_dim: int = 0
+    # leading dense layers (first_k_dense_replace): SwiGLU of width d_ff,
+    # counted in n_layers, outside the scanned groups
+    n_dense_layers: int = 0
     moe: Optional[MoEConfig] = None
     ssm: Optional[SSMConfig] = None
     # enc-dec (whisper): encoder layers share d_model/heads/d_ff
@@ -80,15 +111,29 @@ class ModelConfig:
 
     @property
     def n_groups(self) -> int:
-        assert self.n_layers % len(self.pattern) == 0, (
-            f"{self.name}: n_layers={self.n_layers} not divisible by "
+        n = self.n_layers - self.n_dense_layers
+        assert n % len(self.pattern) == 0, (
+            f"{self.name}: {n} scanned layers not divisible by "
             f"pattern period {len(self.pattern)}")
-        return self.n_layers // len(self.pattern)
+        return n // len(self.pattern)
+
+    @property
+    def mla(self) -> bool:
+        return self.kv_lora_rank > 0
+
+    @property
+    def cache_width(self) -> int:
+        """Cache entries per token per attention layer: K and V of every KV
+        head, or MLA's one latent plus its shared rotary key."""
+        if self.mla:
+            return self.kv_lora_rank + self.rope_dim
+        return 2 * self.kv_dim
 
     def kinds(self) -> Tuple[str, ...]:
-        """Full per-layer kind sequence (pattern tiled to n_layers)."""
+        """Per-layer kind sequence of the scanned stack (pattern tiled over
+        the layers after the leading dense ones)."""
         return tuple(self.pattern[i % len(self.pattern)]
-                     for i in range(self.n_layers))
+                     for i in range(self.n_layers - self.n_dense_layers))
 
     def param_count(self) -> int:
         """Analytic parameter count (matches init exactly; asserted in tests)."""
@@ -99,9 +144,18 @@ class ModelConfig:
             n += self.vocab_size * d
         per_kind = {}
         attn_p = d * self.q_dim + 2 * d * self.kv_dim + self.q_dim * d
-        mlp_p = 3 * d * self.d_ff                      # SwiGLU: gate/up/down
+        if self.mla:
+            r, hr = self.kv_lora_rank, self.rope_dim
+            attn_p = (d * self.n_heads * (hd + hr) + d * (r + hr) + r
+                      + 2 * r * self.q_dim + self.q_dim * d)
+        dense_p = 3 * d * self.d_ff                    # SwiGLU: gate/up/down
+        mlp_p = dense_p
         if self.moe is not None:
-            mlp_p = self.moe.n_experts * 3 * d * self.d_ff + d * self.moe.n_experts
+            m = self.moe
+            mlp_p = (m.n_held * 3 * d * m.d_expert + d * m.n_experts
+                     + 3 * d * m.d_shared
+                     + (m.n_experts if m.score_bias else 0))
+        n += self.n_dense_layers * (attn_p + dense_p + 2 * d)
         per_kind[ATTN] = attn_p + mlp_p + 2 * d
         per_kind[LOCAL_ATTN] = per_kind[ATTN]
         if self.ssm is not None:
@@ -180,8 +234,14 @@ def smoke_config(cfg: ModelConfig) -> ModelConfig:
         max_position=4096,
     )
     if cfg.moe is not None:
-        kw["moe"] = MoEConfig(n_experts=8, top_k=min(cfg.moe.top_k, 2),
-                              capacity_factor=cfg.moe.capacity_factor)
+        m = cfg.moe
+        held = (0, 0) if not m.held[1] else (0, 4)   # a share: 4 of 8
+        kw["moe"] = replace(m, n_experts=8, top_k=min(m.top_k, 3), d_expert=32,
+                            held=held, d_shared=32 if m.d_shared else 0)
+    if cfg.mla:
+        kw.update(kv_lora_rank=32, rope_dim=16)
+    if cfg.n_dense_layers:
+        kw.update(n_dense_layers=1, n_layers=1 + kw["n_layers"])
     if cfg.ssm is not None:
         kw["ssm"] = SSMConfig(d_state=16, expand=2, head_dim=16, chunk=16)
     if cfg.n_encoder_layers:

@@ -1,6 +1,6 @@
 """olmoe-1b-7b [moe]: 64 experts, top-8.
 
-16L d_model=2048 16H (GQA kv=16 = MHA) d_ff=1024 (per expert) vocab=50304,
+16L d_model=2048 16H (GQA kv=16 = MHA) expert width 1024 vocab=50304,
 MoE 64e top-8 [arXiv:2409.02060; hf].
 """
 from repro.configs.base import ATTN, ModelConfig, MoEConfig
@@ -13,10 +13,10 @@ CONFIG = ModelConfig(
     n_heads=16,
     n_kv_heads=16,
     head_dim=128,
-    d_ff=1024,
+    d_ff=0,                 # every layer is an expert layer
     vocab_size=50304,
     pattern=(ATTN,),
-    moe=MoEConfig(n_experts=64, top_k=8),
+    moe=MoEConfig(n_experts=64, top_k=8, d_expert=1024),
     rope_theta=10_000.0,
     sub_quadratic=False,
 )

@@ -137,6 +137,14 @@ def decode_kv_share(cfg: ModelConfig, batch: int, max_len: int, *,
     from repro.models import api
     from repro.train import step as step_mod
     dtype = dtype or jnp.float32
+    itemsize = 1 if quantized else jnp.dtype(dtype).itemsize
+    if cfg.mla:
+        # latent caches live only in the paged pool: no dense decode cell
+        # to compile, so the share comes from the shapes
+        ring = (batch * max_len * cfg.cache_width * itemsize
+                * (cfg.n_dense_layers + len(cfg.kinds())))
+        return min(ring / (ring + cfg.param_count()
+                           * jnp.dtype(dtype).itemsize), 0.95)
     step = step_mod.make_serve_step(cfg, PRECISE)
     params = api.abstract(cfg, dtype)
     # caches at the SAME dtype as the ring-bytes numerator below — a dtype
@@ -149,7 +157,6 @@ def decode_kv_share(cfg: ModelConfig, batch: int, max_len: int, *,
     compiled = jax.jit(step).lower(params, toks, pos, caches).compile()
     total = float(compiled.memory_analysis().argument_size_in_bytes)
     from repro.configs.base import LOCAL_ATTN, MAMBA
-    itemsize = 1 if quantized else jnp.dtype(dtype).itemsize
     hd, G = cfg.resolved_head_dim, cfg.n_kv_heads
     ring = 0
     for kind in cfg.kinds():

@@ -304,13 +304,13 @@ def megastep_shardings(param_sh, cache_sh):
 
     Signature (``train.step.make_paged_megastep``): ``step(params, cur,
     pos, alive, uids, draws, budget, caches) -> (toks, cur, pos, alive,
-    draws, budget, caches)``. Params and caches keep the engine's derived
-    layouts — the caches spec appearing in BOTH positions is what lets the
-    engine donate argument 7 and have XLA alias the pool in place across
-    the whole K-step scan. The (B,)-shaped per-row carries (and the (B, K)
-    token output) ride replicated: a few hundred bytes, not worth a
+    draws, budget, counts, caches)``. Params and caches keep the engine's
+    derived layouts — the caches spec appearing in BOTH positions is what
+    lets the engine donate argument 7 and have XLA alias the pool in place
+    across the whole K-step scan. The (B,)-shaped per-row carries (and the
+    (B, K) token output and the expert counters) ride replicated: a few hundred bytes, not worth a
     collective, and the host reads them whole at the drain point.
     """
     in_sh = (param_sh, None, None, None, None, None, None, cache_sh)
-    out_sh = (None, None, None, None, None, None, cache_sh)
+    out_sh = (None, None, None, None, None, None, None, cache_sh)
     return in_sh, out_sh

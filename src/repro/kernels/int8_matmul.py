@@ -44,6 +44,8 @@ def int8_matmul(x_q, x_scale, w_q, w_scale, *, bm: int = 128, bn: int = 128,
     M, K = x_q.shape
     _, N = w_q.shape
     bm, bn, bk = min(bm, M), min(bn, N), min(bk, K)
+    while K % bk and bk % 256 == 0:
+        bk //= 2              # e.g. K = 2816 = 11 x 256 takes bk 256
     assert N % bn == 0 and K % bk == 0, (N, K, bn, bk)
     pad = -M % bm
     if pad:

@@ -6,7 +6,8 @@ On TPU the Pallas kernels run natively; on CPU (the tests, and the dry-run's
 ``interpret=True`` in tests.
 
 ``DISPATCH_COUNTS`` records, while tracing, which side each call took
-(``int8_matmul`` / ``int8_matmul_ref``, ``ssd_scan`` / ``ssd_ref``), so a
+(``int8_matmul`` / ``int8_matmul_ref``, ``ssd_scan`` / ``ssd_ref``,
+``moe_experts`` / ``moe_experts_ref``), so a
 chip run can prove it never landed on a reference.
 """
 from __future__ import annotations
@@ -18,6 +19,7 @@ import jax.numpy as jnp
 
 from repro.kernels import ref
 from repro.kernels.int8_matmul import int8_matmul
+from repro.kernels.moe_experts import moe_experts
 from repro.kernels.ssd_scan import ssd_scan
 
 DISPATCH_COUNTS: "collections.Counter[str]" = collections.Counter()
@@ -50,6 +52,21 @@ def matmul(precision: str):
     if precision == "int8":
         return quantized_matmul
     return bf16_matmul
+
+
+def grouped_experts(x, wg, wu, wo, tile_e, tile_n, layer, *, tm: int,
+                    use_kernel=None, interpret: bool = False):
+    """The held experts' SwiGLU over expert-sorted row tiles, weights
+    (L, E, F, D) at ``layer``: the Pallas ``moe_experts`` kernel on TPU (or
+    in interpret mode), jnp elsewhere."""
+    if use_kernel is None:
+        use_kernel = _on_tpu()
+    if use_kernel or interpret:
+        DISPATCH_COUNTS["moe_experts"] += 1
+        return moe_experts(x, wg, wu, wo, tile_e, tile_n, layer, tm=tm,
+                           interpret=interpret)
+    DISPATCH_COUNTS["moe_experts_ref"] += 1
+    return ref.moe_experts_ref(x, wg, wu, wo, tile_e, tile_n, layer, tm=tm)
 
 
 def ssd(x, dt, a, b, c, *, chunk=128, d_skip=None):

@@ -26,6 +26,11 @@ Dead traffic is skipped at two levels:
 ``kv_scale`` > 0 fuses int8 -> fp dequantization into the page load (the
 ``kv_quant`` serving knob): quantized K/V pages stream as int8 and are
 scaled in VMEM, never round-tripping through an fp32 HBM buffer.
+
+A latent mode (``paged_latent_attention``, MLA's absorbed decode) reads a
+pool of one [latent | rotary key] entry per position: every query head
+attends over it as one KV head, and the value is the entry's leading
+latent columns of the same page block.
 """
 from __future__ import annotations
 
@@ -176,6 +181,124 @@ def paged_attention_impl(q, kp, vp, ppos, block, position, *, window: int = 0,
 
 paged_attention = functools.partial(jax.jit, static_argnames=(
     "window", "kv_scale", "cap", "interpret"))(paged_attention_impl)
+
+
+# ---- latent mode (MLA): one KV "head" per token, its latent as the value
+
+def _latent_kernel(layer_ref, block_ref, pos_ref, q_ref, *refs, page: int,
+                   n_m: int, nb: int, dv: int, kv_scale: float,
+                   scale: float):
+    c_refs, ppos_refs = refs[:nb], refs[nb:2 * nb]
+    o_ref, m_ref, l_ref, acc_ref = refs[2 * nb:]
+    i = pl.program_id(0)
+    m = pl.program_id(1)
+
+    @pl.when(m == 0)
+    def _init():
+        m_ref[...] = jnp.full_like(m_ref, NEG_INF)
+        l_ref[...] = jnp.zeros_like(l_ref)
+        acc_ref[...] = jnp.zeros_like(acc_ref)
+
+    for j in range(nb):                 # static: the step's slots
+        b = i * nb + j
+        pos = pos_ref[b]
+
+        @pl.when((block_ref[b, m] != 0) & (m * page <= pos))
+        def _body():
+            kv_pos = ppos_refs[j][0, 0]                  # (1, P)
+            valid = (kv_pos >= 0) & (kv_pos <= pos)
+            q = q_ref[j].astype(jnp.float32)             # (H, W)
+            c = c_refs[j][0, 0].astype(jnp.float32)      # (P, W)
+            if kv_scale:
+                c = c * kv_scale
+            s = jax.lax.dot_general(
+                q, c, (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32) * scale
+            s = jnp.where(valid, s, NEG_INF)             # (H, P)
+            m_prev, l_prev = m_ref[j], l_ref[j]
+            m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+            p = jnp.exp(s - m_new)
+            alpha = jnp.exp(m_prev - m_new)
+            l_ref[j] = l_prev * alpha + jnp.sum(p, -1, keepdims=True)
+            m_ref[j] = m_new
+            acc_ref[j] = acc_ref[j] * alpha + jax.lax.dot_general(
+                p, c[:, :dv], (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)
+
+    @pl.when(m == n_m - 1)
+    def _finish():
+        l = jnp.maximum(l_ref[...], 1e-30)
+        o_ref[...] = (acc_ref[...] / l).astype(o_ref.dtype)
+
+
+def latent_slots_per_step(batch: int) -> int:
+    """Slots one grid step of the latent kernel serves: the most of 8, 4,
+    2, 1 that divides the batch. Each grid step costs a fixed overhead
+    even where its pages are dead, so fewer, wider steps pay it less."""
+    return next(n for n in (8, 4, 2, 1) if batch % n == 0)
+
+
+def paged_latent_attention_impl(q, cp, ppos, block, position, layer, *,
+                                dv: int, scale: float, kv_scale: float = 0.0,
+                                interpret: bool = False):
+    """Fused paged decode over a latent (MLA) pool, absorbed form.
+
+    q: (B, H, W) — each head's query already multiplied into the latent
+    space (``q_nope @ W_UK``) and followed by its rotary part; cp:
+    (L, n_pages, P, W) physical pools of [latent | rotary key] entries,
+    stacked over layers, of which ``layer`` (a traced scalar) is read —
+    the layer scan hands the kernel the whole stack, so no layer's pool is
+    copied for it (int8 when ``kv_scale``); ppos: (L, n_pages, P); block:
+    (B, M) the layer's block table; position: (B,). Every head attends
+    over the same one entry per position, and the value is that entry's
+    first ``dv`` columns, so each page is read once for all heads. The grid
+    is (B / nb, M): a step streams page m of each of its ``nb`` slots
+    (``latent_slots_per_step``), one operand per slot. Returns (B, H, dv)
+    latent outputs in q.dtype (the caller applies ``W_UV``)."""
+    B, H, W = q.shape
+    L, n_pages, P = ppos.shape
+    M = block.shape[1]
+    nb = latent_slots_per_step(B)
+
+    def _map(j):
+        def c_map(i, m, l_ref, block_ref, pos_ref):
+            b = i * nb + j
+            pid = jnp.where(m * P > pos_ref[b], 0, block_ref[b, m])
+            return (l_ref[0], pid, 0, 0)
+        return c_map
+
+    qo_map = lambda i, m, *_: (i, 0, 0)
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=3,
+        grid=(B // nb, M),
+        in_specs=([pl.BlockSpec((nb, H, W), qo_map)]
+                  + [pl.BlockSpec((1, 1, P, W), _map(j)) for j in range(nb)]
+                  + [pl.BlockSpec((1, 1, 1, P), _map(j))
+                     for j in range(nb)]),
+        out_specs=pl.BlockSpec((nb, H, dv), qo_map),
+        scratch_shapes=[
+            pltpu.VMEM((nb, H, 1), jnp.float32),
+            pltpu.VMEM((nb, H, 1), jnp.float32),
+            pltpu.VMEM((nb, H, dv), jnp.float32),
+        ],
+    )
+    kernel = functools.partial(_latent_kernel, page=P, n_m=M, nb=nb, dv=dv,
+                               kv_scale=kv_scale, scale=scale)
+    ppos4 = ppos.reshape(L, n_pages, 1, P)
+    return pl.pallas_call(
+        kernel,
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((B, H, dv), q.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary")),
+        interpret=interpret,
+        name="paged_latent_decode",
+    )(jnp.reshape(layer, (1,)).astype(jnp.int32), block.astype(jnp.int32),
+      position.astype(jnp.int32), q, *([cp] * nb), *([ppos4] * nb))
+
+
+paged_latent_attention = functools.partial(jax.jit, static_argnames=(
+    "dv", "scale", "kv_scale", "interpret"))(paged_latent_attention_impl)
 
 
 def page_hbm_bytes(page_size: int, n_kv_heads: int, head_dim: int, *,

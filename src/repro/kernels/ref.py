@@ -167,3 +167,22 @@ def ssd_chunked_ref(x, dt, a, b, c, *, chunk=64, d_skip=None,
     if return_state:
         return y.astype(x.dtype), s_final
     return y.astype(x.dtype)
+
+
+# ----------------------------------------------------- grouped experts ----
+
+def moe_experts_ref(x, wg, wu, wo, tile_e, tile_n, layer, *, tm: int):
+    """Oracle of ``kernels.moe_experts``: each tile of ``tm`` sorted rows
+    through its expert's SwiGLU (weights (L, E, F, D), layer ``layer``);
+    empty tiles give 0."""
+    wg, wu, wo = (jax.lax.dynamic_index_in_dim(w, layer, keepdims=False)
+                  for w in (wg, wu, wo))
+    n_t = x.shape[0] // tm
+    xt = x.reshape(n_t, tm, -1)
+    f32 = jnp.float32
+    g = jnp.einsum("tmd,tfd->tmf", xt, wg[tile_e], preferred_element_type=f32)
+    u = jnp.einsum("tmd,tfd->tmf", xt, wu[tile_e], preferred_element_type=f32)
+    h = (jax.nn.silu(g) * u).astype(x.dtype)
+    y = jnp.einsum("tmf,tfd->tmd", h, wo[tile_e], preferred_element_type=f32)
+    y = jnp.where((tile_n > 0)[:, None, None], y, 0.0)
+    return y.reshape(x.shape).astype(x.dtype)

@@ -286,7 +286,7 @@ def serve(argv=None, *, on_step: Optional[Callable] = None) -> Served:
               f"tokens_skipped={s['tokens_skipped']} "
               f"reclaim_events={s['reclaim_events']}")
         print(f"admission: grouped_pages={s['grouped_pages']} "
-              f"grouped_fallbacks={s['grouped_fallbacks']} "
+              f"blocked={s['blocked_admissions']} "
               f"replenish_evictions={s['replenish_evictions']} "
               f"chunks/step max={max(chunks, default=0)} "
               f"budget_cap={args.max_admission_chunks}")

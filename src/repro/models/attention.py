@@ -19,7 +19,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.configs.base import ModelConfig
-from repro.models.common import ParamSpec, apply_rope, softcap
+from repro.models.common import ParamSpec, apply_rope, rms_norm, softcap
 
 
 def attn_specs(cfg: ModelConfig):
@@ -787,3 +787,219 @@ def chunk_decode_attention(params, x, positions, cache: KVCache,
     qg = q.reshape(B, C, G, R, hd)
     o = _sdpa(qg, kk, vv, mask=valid[:, None, None], cap=cfg.attn_softcap)
     return _merge(o, B, C, cfg.q_dim) @ params["wo"], new_cache
+
+
+# ------------------------------------------------------------ latent (MLA) --
+# DeepSeek-V2/V3 latent attention without a q latent: q comes straight from
+# x as H heads of (nope + rope) dims; one kv_lora_rank latent per token
+# (RMS-normed) expands into each head's nope key and value, and one shared
+# rotary key joins every head's key. The cache keeps only [latent | rotary
+# key] per token; decode runs absorbed (q_nope W_UK attends over the
+# latents as one KV head, the latent output goes through W_UV).
+
+def mla_specs(cfg: ModelConfig):
+    d, H, hd = cfg.d_model, cfg.n_heads, cfg.resolved_head_dim
+    r, hr = cfg.kv_lora_rank, cfg.rope_dim
+    return {
+        "wq": ParamSpec((d, H * (hd + hr)), ("embed", "q_heads")),
+        "wkv_a": ParamSpec((d, r + hr), ("embed", None)),
+        "kv_norm": ParamSpec((r,), (None,), init="ones"),
+        "wk_b": ParamSpec((r, H * hd), (None, "q_heads")),
+        "wv_b": ParamSpec((r, H * hd), (None, "q_heads")),
+        "wo": ParamSpec((H * hd, d), ("q_heads", "embed")),
+    }
+
+
+def _mla_scale(cfg: ModelConfig) -> float:
+    return (cfg.resolved_head_dim + cfg.rope_dim) ** -0.5
+
+
+def _mla_project(params, x, positions, cfg: ModelConfig):
+    """x: (B,S,D) -> (q_nope (B,S,H,hd), roped q_pe (B,S,H,hr), cache entry
+    (B,S,r+hr) = [normed latent | roped shared key])."""
+    B, S, _ = x.shape
+    H, hd, r = cfg.n_heads, cfg.resolved_head_dim, cfg.kv_lora_rank
+    q = (x @ params["wq"]).reshape(B, S, H, -1)
+    q_pe = apply_rope(q[..., hd:], positions, cfg.rope_theta)
+    kv = x @ params["wkv_a"]
+    c = rms_norm(kv[..., :r], params["kv_norm"], cfg.norm_eps)
+    k_pe = apply_rope(kv[..., None, r:], positions, cfg.rope_theta)[..., 0, :]
+    return q[..., :hd], q_pe, jnp.concatenate([c, k_pe], axis=-1)
+
+
+def mla_attention(params, x, positions, cfg: ModelConfig, *,
+                  q_chunk: int = 0, kv_keep_stride: int = 1):
+    """Full-sequence causal MLA in the expanded form: each head's key is
+    [latent W_UK | shared rotary key], its value latent W_UV."""
+    B, S, _ = x.shape
+    H, hd, r = cfg.n_heads, cfg.resolved_head_dim, cfg.kv_lora_rank
+    q_nope, q_pe, ent = _mla_project(params, x, positions, cfg)
+    c, k_pe = ent[..., :r], ent[..., r:]
+    k_nope = (c @ params["wk_b"]).reshape(B, S, H, hd)
+    v = (c @ params["wv_b"]).reshape(B, S, H, hd)
+    q = jnp.concatenate([q_nope, q_pe], -1)[:, :, :, None]   # (B,S,H,1,.)
+    k = jnp.concatenate([k_nope, jnp.broadcast_to(
+        k_pe[:, :, None], (B, S, H, cfg.rope_dim))], -1)
+    o = _causal_chunked(q, k, v, q_chunk=q_chunk or default_q_chunk(S),
+                        kv_keep_stride=kv_keep_stride, cap=0.0)
+    return o.reshape(B, S, H * hd) @ params["wo"]
+
+
+def _absorb_q(params, q_nope, q_pe, cfg: ModelConfig):
+    """Queries in the latent space: [q_nope W_UK^T | q_pe] per head."""
+    H, hd, r = cfg.n_heads, cfg.resolved_head_dim, cfg.kv_lora_rank
+    q_lat = jnp.einsum("...hd,rhd->...hr", q_nope,
+                       params["wk_b"].reshape(r, H, hd))
+    return jnp.concatenate([q_lat, q_pe.astype(q_lat.dtype)], axis=-1)
+
+
+def _latent_out(params, o_lat, cfg: ModelConfig):
+    """Latent attention outputs (..., H, r) through W_UV and the output
+    projection."""
+    H, hd, r = cfg.n_heads, cfg.resolved_head_dim, cfg.kv_lora_rank
+    o = jnp.einsum("...hr,rhd->...hd", o_lat,
+                   params["wv_b"].reshape(r, H, hd))
+    return o.reshape(o.shape[:-2] + (H * hd,)) @ params["wo"]
+
+
+class PagedLatentCache(NamedTuple):
+    """Paged latent (MLA) cache: one [latent | rotary key] entry per
+    position in a shared physical page pool, block tables as
+    ``PagedKVCache`` (physical page 0 is the null page). The attention
+    functions below take it stacked over layers (leading axis) with the
+    layer's index: the layer scan carries the whole stack, so entries are
+    written in place and the kernel reads its layer without a copy."""
+    cp: jax.Array         # (n_pages, page_size, W) physical page pool,
+                          # W = r + hr rounded up to 128 lanes
+    ppos: jax.Array       # (n_pages, page_size) absolute positions, -1 empty
+    block: jax.Array      # (B, max_pages) int32 physical page ids
+
+
+def _lane_pad(x, cfg: ModelConfig):
+    """Zero-pad the last dim of entries (or of latent-space queries) from
+    ``cache_width`` to a whole number of 128-lane tiles: a pool whose last
+    dim is not one is laid out pages-minor by the TPU's default layout, and
+    every call would then copy the pool to the layout the writes and the
+    kernel use. The pad columns are zero in entries and queries alike."""
+    pad = -cfg.cache_width % 128
+    return jnp.pad(x, [(0, 0)] * (x.ndim - 1) + [(0, pad)]) if pad else x
+
+
+def init_paged_latent_cache(cfg: ModelConfig, batch: int, n_pages: int,
+                            page_size: int, max_pages: int,
+                            dtype=jnp.bfloat16,
+                            quantized: bool = False) -> PagedLatentCache:
+    width = -(-cfg.cache_width // 128) * 128
+    return PagedLatentCache(
+        cp=jnp.zeros((n_pages, page_size, width),
+                     jnp.int8 if quantized else dtype),
+        ppos=jnp.full((n_pages, page_size), -1, jnp.int32),
+        block=jnp.zeros((batch, max_pages), jnp.int32))
+
+
+def _latent_write(cache: PagedLatentCache, layer, phys, positions, ent, *,
+                  keep):
+    """Write entries ent (N, W) of stacked layer ``layer`` at absolute
+    ``positions`` (N,) into pages ``phys`` (N,); rows with ``keep`` False
+    (None = all) go to the never-read null page. An indexed write: the
+    latent pool is single-device (no GSPMD hazard)."""
+    P = cache.ppos.shape[-1]
+    tgt = phys if keep is None else jnp.where(keep, phys, 0)
+    off = positions % P
+    return cache._replace(cp=cache.cp.at[layer, tgt, off].set(ent),
+                          ppos=cache.ppos.at[layer, tgt, off].set(positions))
+
+
+def _latent_attend(q_abs, cache: PagedLatentCache, layer, block,
+                   q_positions, *, dv: int, scale: float, kv_scale: float):
+    """Absorbed attention over a block table's latent pages of stacked
+    layer ``layer`` (the reference of the latent kernel, and the admission
+    chunk's attention). q_abs: (B, C, H, W); block: (B, M); q_positions:
+    (B, C). Returns (B, C, H, dv) latent outputs."""
+    B, M = block.shape
+    P = cache.ppos.shape[-1]
+    ent = cache.cp[layer, block].reshape(B, M * P, -1)
+    ent = dequantize_kv(ent, q_abs.dtype, kv_scale) if kv_scale else \
+        ent.astype(q_abs.dtype)
+    gpos = cache.ppos[layer, block].reshape(B, M * P)
+    valid = jnp.repeat(block != 0, P, axis=1)[:, None, :] \
+        & (gpos[:, None, :] >= 0) & (gpos[:, None, :] <= q_positions[..., None])
+    s = jnp.einsum("bchw,btw->bhct", q_abs, ent,
+                   preferred_element_type=jnp.float32) * scale
+    s = jnp.where(valid[:, None], s, -1e30)
+    p = jax.nn.softmax(s, axis=-1).astype(q_abs.dtype)
+    return jnp.einsum("bhct,btv->bchv", p, ent[..., :dv])
+
+
+def _latent_mesh_check(mesh) -> None:
+    if mesh is not None:
+        raise NotImplementedError(
+            "latent (MLA) attention serves from a single-device page pool")
+
+
+def paged_latent_decode_attention(params, x, position,
+                                  cache: PagedLatentCache, cfg: ModelConfig,
+                                  *, layer, kv_scale: float = 0.0,
+                                  active=None,
+                                  use_kernel: Optional[bool] = None,
+                                  interpret: bool = False, mesh=None):
+    """One-token MLA decode against the stacked latent pool at ``layer``,
+    absorbed: the new entry goes to the slot's page (inactive rows to the
+    null page, as in ``paged_decode_attention``), then every head attends
+    over the latents through the fused ``paged_latent_decode`` kernel
+    (``use_kernel``, default on TPU) or the gather reference."""
+    from repro.kernels import ops as kops
+    from repro.kernels.paged_attention import paged_latent_attention
+    _latent_mesh_check(mesh)
+    B = x.shape[0]
+    P = cache.ppos.shape[-1]
+    block = cache.block[layer]
+    q_nope, q_pe, ent = _mla_project(params, x, position[:, None], cfg)
+    ent = _lane_pad(ent[:, 0], cfg)
+    store = quantize_kv(ent, kv_scale) if kv_scale else \
+        ent.astype(cache.cp.dtype)
+    phys = jnp.take_along_axis(block, (position // P)[:, None], axis=1)[:, 0]
+    new_cache = _latent_write(cache, layer, phys, position, store,
+                              keep=active)
+    q_abs = _lane_pad(_absorb_q(params, q_nope[:, 0], q_pe[:, 0], cfg),
+                      cfg)                                      # (B,H,W)
+    r, scale = cfg.kv_lora_rank, _mla_scale(cfg)
+    if use_kernel is None:
+        use_kernel = kops._on_tpu()
+    DISPATCH_COUNTS["latent_kernel" if use_kernel else "latent_gather"] += 1
+    if use_kernel:
+        o_lat = paged_latent_attention(q_abs, new_cache.cp, new_cache.ppos,
+                                       block, position, layer, dv=r,
+                                       scale=scale, kv_scale=kv_scale,
+                                       interpret=interpret)
+    else:
+        o_lat = _latent_attend(q_abs[:, None], new_cache, layer, block,
+                               position[:, None], dv=r, scale=scale,
+                               kv_scale=kv_scale)[:, 0]
+    return _latent_out(params, o_lat, cfg).reshape(B, 1, -1), new_cache
+
+
+def paged_latent_chunk_attention(params, x, positions,
+                                 cache: PagedLatentCache, cfg: ModelConfig,
+                                 slot, *, layer, kv_scale: float = 0.0,
+                                 valid=None, mesh=None):
+    """C-token MLA prompt chunk for ONE slot of the stacked latent pool at
+    ``layer`` (chunked admission): the chunk's entries go to the slot's
+    pages (``valid`` as in ``paged_chunk_attention``), then the chunk
+    attends, absorbed, over every mapped latent page, its own entries
+    included."""
+    _latent_mesh_check(mesh)
+    P = cache.ppos.shape[-1]
+    q_nope, q_pe, ent = _mla_project(params, x, positions, cfg)
+    ent = _lane_pad(ent[0], cfg)
+    store = quantize_kv(ent, kv_scale) if kv_scale else \
+        ent.astype(cache.cp.dtype)
+    brow = cache.block[layer, slot]                       # (M,)
+    pos_c = positions[0]
+    new_cache = _latent_write(cache, layer, jnp.take(brow, pos_c // P),
+                              pos_c, store, keep=valid)
+    q_abs = _lane_pad(_absorb_q(params, q_nope, q_pe, cfg), cfg)  # (1,C,H,W)
+    o_lat = _latent_attend(q_abs, new_cache, layer, brow[None], positions,
+                           dv=cfg.kv_lora_rank, scale=_mla_scale(cfg),
+                           kv_scale=kv_scale)
+    return _latent_out(params, o_lat, cfg), new_cache

@@ -37,6 +37,9 @@ def lm_specs(cfg: ModelConfig) -> Dict[str, Any]:
     }
     if not cfg.tie_embeddings:
         specs["unembed"] = ParamSpec((d, cfg.vocab_size), ("embed", "vocab"))
+    if cfg.n_dense_layers:
+        specs["lead"] = stack_specs(block_specs(ATTN, cfg, dense=True),
+                                    cfg.n_dense_layers)
     groups: Dict[str, Any] = {}
     for j, kind in enumerate(cfg.pattern):
         if kind == SHARED_ATTN:
@@ -76,6 +79,10 @@ def forward_hidden(params, tokens, cfg: ModelConfig,
     h = constrain_batch(h)
     B, S, D = h.shape
     positions = jnp.broadcast_to(jnp.arange(S), (B, S))
+    for i in range(cfg.n_dense_layers):
+        lp = jax.tree.map(lambda p: p[i], params["lead"])
+        h, _ = block_forward(ATTN, lp, h, positions, cfg, knobs,
+                             ep_axis=ep_axis, mesh=mesh)
     keep = keep_groups(cfg.n_groups, knobs.layer_skip)
     groups = _slice_groups(params["groups"], keep, cfg.n_groups)
     shared = params.get("shared")
@@ -214,20 +221,32 @@ def lm_loss(params, batch, cfg: ModelConfig, knobs: ApproxKnobs = PRECISE, *,
 
 # ----------------------------------------------------------------- decode --
 
+def _stacked_caches(cfg: ModelConfig, one):
+    """One cache entry per pattern position, stacked over the groups, then
+    (where the model has leading dense layers) theirs, stacked over them."""
+    def stack(tree, n):
+        return jax.tree.map(
+            lambda x: jnp.broadcast_to(x[None], (n,) + x.shape), tree)
+    caches = tuple(stack(one(kind), cfg.n_groups) for kind in cfg.pattern)
+    if cfg.n_dense_layers:
+        caches += (stack(one(ATTN), cfg.n_dense_layers),)
+    return caches
+
+
 def init_caches(cfg: ModelConfig, batch: int, max_len: int,
                 dtype=jnp.bfloat16, quantized: bool = False):
     """Stacked (over groups) caches, one entry per pattern position."""
+    if cfg.mla:
+        raise NotImplementedError("latent (MLA) attention is served from "
+                                  "the paged latent pool")
+
     def one(kind):
         if kind == MAMBA:
             return mamba_mod.init_mamba_cache(cfg, batch, dtype)
         length = min(cfg.window, max_len) if kind == LOCAL_ATTN else max_len
         return attn_mod.init_cache(cfg, batch, length, dtype,
                                    quantized=quantized)
-    def stack(tree):
-        return jax.tree.map(
-            lambda x: jnp.broadcast_to(x[None], (cfg.n_groups,) + x.shape),
-            tree)
-    return tuple(stack(one(kind)) for kind in cfg.pattern)
+    return _stacked_caches(cfg, one)
 
 
 def init_paged_caches(cfg: ModelConfig, batch: int, n_pages: int,
@@ -242,19 +261,18 @@ def init_paged_caches(cfg: ModelConfig, batch: int, n_pages: int,
 
     Every leaf is group-stacked (axis 0 = layer groups) like ``init_caches``
     so the decode scan consumes it unchanged; the block table is replicated
-    per group (a few KiB) to keep the pytree scan-uniform.
+    per group (a few KiB) to keep the pytree scan-uniform. Latent (MLA)
+    models get a ``PagedLatentCache``: one [latent | rotary key] entry per
+    token and layer in place of K and V per head.
     """
     def one(kind):
         if kind == MAMBA:
             return mamba_mod.init_mamba_cache(cfg, batch, dtype)
-        return attn_mod.init_paged_cache(cfg, batch, n_pages, page_size,
-                                         max_pages, dtype,
-                                         quantized=quantized)
-    def stack(tree):
-        return jax.tree.map(
-            lambda x: jnp.broadcast_to(x[None], (cfg.n_groups,) + x.shape),
-            tree)
-    return tuple(stack(one(kind)) for kind in cfg.pattern)
+        init = (attn_mod.init_paged_latent_cache if cfg.mla
+                else attn_mod.init_paged_cache)
+        return init(cfg, batch, n_pages, page_size, max_pages, dtype,
+                    quantized=quantized)
+    return _stacked_caches(cfg, one)
 
 
 def decode_step(params, tokens, position, caches, cfg: ModelConfig,
@@ -272,29 +290,101 @@ def decode_step(params, tokens, position, caches, cfg: ModelConfig,
     Mamba state rows) advance inside the ONE ``lax.scan`` body below, so a
     mixed block stack is a single lowered executable per decode step.
     """
-    h = params["embed"][tokens[:, 0]][:, None, :]
-    shared = params.get("shared")
+    logits, new_caches, _ = decode_counted(
+        params, tokens, position, caches, cfg, knobs, ep_axis=ep_axis,
+        mesh=mesh, enc_out=enc_out, active=active, use_kernel=use_kernel,
+        dyn_scatter=dyn_scatter, interpret=interpret)
+    return logits, new_caches
 
-    def group_body(h, xs):
-        group_params, group_caches = xs
-        new_caches = []
-        for j, kind in enumerate(cfg.pattern):
-            p = shared if kind == SHARED_ATTN else group_params.get(f"pos{j}")
-            h, nc, _ = block_decode(kind, p, h, position, group_caches[j],
-                                    cfg, knobs, ep_axis=ep_axis, mesh=mesh,
-                                    enc_out=enc_out, active=active,
-                                    use_kernel=use_kernel,
-                                    dyn_scatter=dyn_scatter,
-                                    interpret=interpret)
-            new_caches.append(nc)
-        return h, tuple(new_caches)
 
+def decode_counted(params, tokens, position, caches, cfg: ModelConfig,
+                   knobs: ApproxKnobs = PRECISE, *,
+                   ep_axis: Optional[str] = None, mesh=None,
+                   enc_out: Optional[jax.Array] = None, active=None,
+                   use_kernel: Optional[bool] = None,
+                   dyn_scatter: bool = False, interpret: bool = False):
+    """``decode_step`` that also returns the step's expert counters:
+    (logits, new_caches, counts (2,) int32 summed over layers)."""
     from repro import flags
-    h, new_caches = jax.lax.scan(group_body, h,
-                                 (params["groups"], caches),
-                                 unroll=flags.unroll("groups"))
+    h = params["embed"][tokens[:, 0]][:, None, :]
+    kw = dict(ep_axis=ep_axis, mesh=mesh, enc_out=enc_out, active=active,
+              use_kernel=use_kernel, dyn_scatter=dyn_scatter,
+              interpret=interpret)
+
+    def layer(kind, p, h, cache, i):
+        return block_decode(kind, p, h, position, cache, cfg, knobs, layer=i,
+                            **kw)
+
+    h, new_caches, counts = scan_layers(params, caches, cfg, h, layer,
+                                        unroll=flags.unroll("groups"))
     h = rms_norm(h, params["final_norm"], cfg.norm_eps)
-    return logits_fn(params, h[:, 0], cfg), new_caches
+    return logits_fn(params, h[:, 0], cfg), new_caches, counts
+
+
+_EXPERT_MATS = ("wi_gate", "wi_up", "wo")
+
+
+def scan_layers(params, caches, cfg: ModelConfig, h, layer_fn, *,
+                unroll=1):
+    """Run the leading dense layers, then the scanned groups, threading the
+    hidden state through ``layer_fn(kind, layer_params, h, cache, i)`` ->
+    (h, new_cache, counts (2,) int32) over ``caches`` (the
+    ``init_caches`` / ``init_paged_caches`` layout). Returns (h,
+    new_caches, counts summed over layers).
+
+    Latent caches and the expert matrices stay stacked over the layers:
+    the scan carries the former (written in place) and closes over the
+    latter, and ``layer_fn`` gets them whole with the layer index ``i``,
+    so a kernel reads its layer without the per-layer copy a scanned
+    slice would cost. Every other cache and parameter is sliced per
+    group."""
+    from repro.models.attention import PagedLatentCache
+    n_pat = len(cfg.pattern)
+    shared = params.get("shared")
+    counts = jnp.zeros((2,), jnp.int32)
+
+    def run(h, counts, stack, stack_caches, kinds, n):
+        lat = [isinstance(c, PagedLatentCache) for c in stack_caches]
+        xs_params, mats = {}, {}
+        for key, p in stack.items():
+            if "moe" in p:
+                moe = dict(p["moe"])
+                mats[key] = {k: moe.pop(k) for k in _EXPERT_MATS}
+                p = dict(p, moe=moe)
+            xs_params[key] = p
+
+        def body(carry, xs):
+            h, counts, carried = carry
+            gp, gc, i = xs
+            carried, ys = list(carried), []
+            for j, kind in enumerate(kinds):
+                key = f"pos{j}"
+                p = shared if kind == SHARED_ATTN else gp.get(key)
+                if key in mats:
+                    p = dict(p, moe=dict(p["moe"], **mats[key]))
+                h, nc, c = layer_fn(kind, p, h,
+                                    carried[j] if lat[j] else gc[j], i)
+                counts = counts + c
+                if lat[j]:
+                    carried[j], nc = nc, None
+                ys.append(nc)
+            return (h, counts, tuple(carried)), tuple(ys)
+
+        carried = tuple(c if l else None for c, l in zip(stack_caches, lat))
+        xs_c = tuple(None if l else c for c, l in zip(stack_caches, lat))
+        (h, counts, carried), ys = jax.lax.scan(
+            body, (h, counts, carried), (xs_params, xs_c, jnp.arange(n)),
+            unroll=unroll)
+        return h, counts, tuple(c if l else y
+                                for c, y, l in zip(carried, ys, lat))
+
+    lead = ()
+    if cfg.n_dense_layers:
+        h, counts, lead = run(h, counts, {"pos0": params["lead"]},
+                              (caches[n_pat],), (ATTN,), cfg.n_dense_layers)
+    h, counts, groups = run(h, counts, params["groups"], caches[:n_pat],
+                            cfg.pattern, cfg.n_groups)
+    return h, groups + lead, counts
 
 
 def sample_token(logits, uids, draws, *, temperature: float = 0.0,
@@ -344,17 +434,17 @@ def decode_megastep(params, cur, pos, alive, uids, draws, budget, caches,
     ``pos + k - 1`` on a fully-live row — the host pre-reserves that page
     range (``PagePool.ensure_decode_range``) before dispatch.
 
-    Returns ``(toks (B, K) int32, cur, pos, alive, draws, budget,
-    new_caches)``.
+    Returns ``(toks (B, K) int32, cur, pos, alive, draws, budget, counts,
+    new_caches)``: ``counts`` (2,) int32 sums the expert counters of the K
+    steps (``blocks._serve_ffn``).
     """
 
     def body(carry, _):
-        cur, pos, alive, draws, budget, caches = carry
-        logits, caches = decode_step(params, cur[:, None], pos, caches, cfg,
-                                     knobs, ep_axis=ep_axis, mesh=mesh,
-                                     active=alive, use_kernel=use_kernel,
-                                     dyn_scatter=dyn_scatter,
-                                     interpret=interpret)
+        cur, pos, alive, draws, budget, counts, caches = carry
+        logits, caches, c = decode_counted(
+            params, cur[:, None], pos, caches, cfg, knobs, ep_axis=ep_axis,
+            mesh=mesh, active=alive, use_kernel=use_kernel,
+            dyn_scatter=dyn_scatter, interpret=interpret)
         tok = sample_token(logits, uids, draws, temperature=temperature,
                            seed=seed)
         emit = alive
@@ -367,9 +457,10 @@ def decode_megastep(params, cur, pos, alive, uids, draws, budget, caches,
         alive = alive & ~hit_eos & (budget > 0)
         pos = pos + step1
         cur = jnp.where(emit, tok, cur)
-        return (cur, pos, alive, draws, budget, caches), out
+        return (cur, pos, alive, draws, budget, counts + c, caches), out
 
-    carry0 = (cur, pos, alive, draws, budget, caches)
-    (cur, pos, alive, draws, budget, caches), toks = jax.lax.scan(
+    carry0 = (cur, pos, alive, draws, budget, jnp.zeros((2,), jnp.int32),
+              caches)
+    (cur, pos, alive, draws, budget, counts, caches), toks = jax.lax.scan(
         body, carry0, None, length=k)
-    return toks.T, cur, pos, alive, draws, budget, caches
+    return toks.T, cur, pos, alive, draws, budget, counts, caches

@@ -1,10 +1,15 @@
-"""Top-k MoE layer with sort-based dispatch and expert parallelism.
+"""Top-k MoE layer: a dropless layer over the experts one chip holds, and
+expert parallelism.
 
-Dispatch is index-based (argsort by expert, capacity-bounded slots) — never a
-one-hot dispatch tensor — so activation inflation is exactly tokens x top_k.
+Routing scores every expert (softmax, or sigmoid with a selection bias and
+a scale, as in DeepSeek-V3), then ``held_experts`` computes the picks on
+the experts held here: sorted by expert into whole row tiles, one grouped
+call (the ``moe_experts`` Pallas kernel on TPU) computes every pick, none
+dropped. Shared experts are one SwiGLU added to every token.
 Distributed mode runs under a FULLY-MANUAL ``shard_map`` (every mesh axis):
 tokens are flat-sharded over (pod, data, model), experts are sharded over
-``model``, and two ``all_to_all``s move capacity slots to/from expert owners.
+``model``, and two ``all_to_all``s move capacity-bounded slots to/from
+expert owners.
 Under the fsdp_tp policy the expert weights' embed dim is FSDP-sharded over
 ``data`` and all-gathered on entry (hand-written — partial-manual shard_map
 transposes of all_to_all crash XLA CPU, see EXPERIMENTS.md §Dry-run).
@@ -21,21 +26,59 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.configs.base import ModelConfig
+from repro.configs.base import ModelConfig, MoEConfig
+from repro.models import mlp as mlp_mod
 from repro.models.common import ParamSpec
 from repro.kernels import ops as kops
+from repro.kernels import ref as kref
 from repro.dist import annotate
 
 
 def moe_specs(cfg: ModelConfig):
-    d, f, e = cfg.d_model, cfg.d_ff, cfg.moe.n_experts
-    return {
-        # gate stays replicated (tiny): routing must see full d
-        "wg": ParamSpec((d, e), (None, None)),
-        "wi_gate": ParamSpec((e, d, f), ("expert", "embed", "mlp")),
-        "wi_up": ParamSpec((e, d, f), ("expert", "embed", "mlp")),
+    m = cfg.moe
+    d, f, e = cfg.d_model, m.d_expert, m.n_held
+    s = {
+        # gate stays replicated (tiny): routing must see full d and every
+        # expert, held here or not
+        "wg": ParamSpec((d, m.n_experts), (None, None)),
+        # (experts, F, D): an f-block of one expert is contiguous in HBM
+        "wi_gate": ParamSpec((e, f, d), ("expert", "mlp", "embed")),
+        "wi_up": ParamSpec((e, f, d), ("expert", "mlp", "embed")),
         "wo": ParamSpec((e, f, d), ("expert", "mlp", "embed")),
     }
+    if m.score_bias:
+        s["score_bias"] = ParamSpec((m.n_experts,), (None,))
+    if m.d_shared:
+        s["shared"] = mlp_mod.mlp_specs(cfg, m.d_shared)
+    return s
+
+
+def _scores(x2, params, m: MoEConfig, top_k: int):
+    """Routing over every expert, in f32. Returns (ids (T,k), weights
+    (T,k) f32, probs (T,E)): experts are picked by score (plus the
+    correction bias where the model has one) and weighted by score alone,
+    renormalised over the picks and scaled."""
+    logits = jnp.dot(x2.astype(jnp.float32),
+                     params["wg"].astype(jnp.float32))          # (T, E)
+    if m.scoring == "sigmoid":
+        probs = jax.nn.sigmoid(logits)
+    else:
+        probs = jax.nn.softmax(logits, axis=-1)
+    choice = probs
+    if m.score_bias:
+        choice = probs + params["score_bias"].astype(jnp.float32)
+    _, ids = jax.lax.top_k(choice, top_k)
+    w = jnp.take_along_axis(probs, ids, axis=-1)
+    if m.norm_topk:
+        w = w / (jnp.sum(w, -1, keepdims=True) + 1e-20)
+    return ids, w * m.scale, probs
+
+
+def _aux(probs, ids, n_experts: int):
+    """Switch-style load-balance statistic over the routed experts."""
+    flat = ids.reshape(-1)
+    ce = jnp.bincount(flat, length=n_experts).astype(jnp.float32) / flat.size
+    return n_experts * jnp.sum(jnp.mean(probs, axis=0) * ce)
 
 
 def _capacity(n_tokens: int, top_k: int, n_experts: int, cf: float,
@@ -44,12 +87,11 @@ def _capacity(n_tokens: int, top_k: int, n_experts: int, cf: float,
     return max(align, -(-c // align) * align)
 
 
-def _route(x2, wg, top_k: int, capacity: int, n_experts: int):
-    """x2: (T, D). Returns (slots (T,k), weights (T,k), keep (T,k), aux)."""
-    logits = (x2 @ wg).astype(jnp.float32)                  # (T, E)
-    probs = jax.nn.softmax(logits, axis=-1)
-    gate, ids = jax.lax.top_k(probs, top_k)                 # (T, k)
-    gate = gate / jnp.maximum(gate.sum(-1, keepdims=True), 1e-9)
+def _route(ids, capacity: int, n_experts: int):
+    """Capacity slots of the expert-parallel dispatch. ids: (T,k).
+    Returns (slots (T,k), keep (T,k)): picks past an expert's capacity
+    are dropped."""
+    top_k = ids.shape[1]
     flat_e = ids.reshape(-1)                                # (T*k,)
     order = jnp.argsort(flat_e, stable=True)
     sorted_e = flat_e[order]
@@ -61,58 +103,144 @@ def _route(x2, wg, top_k: int, capacity: int, n_experts: int):
     slot_sorted = sorted_e * capacity + jnp.minimum(rank, capacity - 1)
     slot = jnp.zeros_like(flat_e).at[order].set(slot_sorted)
     keep = jnp.zeros(flat_e.shape, bool).at[order].set(keep_sorted)
-    me = jnp.mean(probs, axis=0)
-    ce = counts.astype(jnp.float32) / flat_e.shape[0]
-    aux = n_experts * jnp.sum(me * ce)
-    return (slot.reshape(-1, top_k), gate.astype(x2.dtype),
-            keep.reshape(-1, top_k), aux)
+    return slot.reshape(-1, top_k), keep.reshape(-1, top_k)
 
 
 def _expert_ffn(xe, wi_gate, wi_up, wo, precision: str):
-    """xe: (E_loc, C', D); weights (E_loc, D, F) / (E_loc, F, D)."""
+    """xe: (E_loc, C', D); weights (E_loc, F, D)."""
     if precision == "int8":
         def one(x, wg_, wu_, wo_):
-            g = jax.nn.silu(kops.quantized_matmul(x, wg_).astype(jnp.float32))
-            u = kops.quantized_matmul(x, wu_)
+            g = jax.nn.silu(kops.quantized_matmul(x, wg_.T).astype(
+                jnp.float32))
+            u = kops.quantized_matmul(x, wu_.T)
             return kops.quantized_matmul(g.astype(x.dtype) * u, wo_)
         return jax.vmap(one)(xe, wi_gate, wi_up, wo)
-    g = jax.nn.silu(jnp.einsum("ecd,edf->ecf", xe, wi_gate,
+    g = jax.nn.silu(jnp.einsum("ecd,efd->ecf", xe, wi_gate,
                                preferred_element_type=jnp.float32))
-    u = jnp.einsum("ecd,edf->ecf", xe, wi_up)
+    u = jnp.einsum("ecd,efd->ecf", xe, wi_up)
     return jnp.einsum("ecf,efd->ecd", g.astype(xe.dtype) * u, wo)
 
 
-def _moe_local(params, x2, cfg: ModelConfig, top_k: int, precision: str,
-               ep_axis: Optional[str]):
-    """Core MoE on local tokens x2: (T, D). Inside shard_map when ``ep_axis``
-    is set (experts sharded over that axis), else single-device."""
-    E = cfg.moe.n_experts
+def _moe_ep(params, x2, cfg: ModelConfig, top_k: int, precision: str,
+            ep_axis: str):
+    """Expert-parallel MoE on local tokens x2: (T, D), inside shard_map
+    with the experts sharded over ``ep_axis``: capacity slots move to and
+    from the experts' owners by two all_to_alls."""
+    m = cfg.moe
+    E = m.n_experts
     T = x2.shape[0]
-    C = _capacity(T, top_k, E, cfg.moe.capacity_factor)
-    slot, gate, keep, aux = _route(x2, params["wg"], top_k, C, E)
+    C = _capacity(T, top_k, E, m.capacity_factor)
+    ids, gate, probs = _scores(x2, params, m, top_k)
+    slot, keep = _route(ids, C, E)
     flat_slot = slot.reshape(-1)
     flat_keep = keep.reshape(-1)
     tok_idx = jnp.repeat(jnp.arange(T), top_k)
     buf = jnp.zeros((E * C, x2.shape[1]), x2.dtype)
     buf = buf.at[flat_slot].add(
         jnp.where(flat_keep[:, None], x2[tok_idx], 0))
-    if ep_axis is not None:
-        xe = buf.reshape(E, C, -1)
-        xe = jax.lax.all_to_all(xe, ep_axis, split_axis=0, concat_axis=1,
-                                tiled=True)
-        ye = _expert_ffn(xe, params["wi_gate"], params["wi_up"], params["wo"],
-                         precision)
-        ye = jax.lax.all_to_all(ye, ep_axis, split_axis=1, concat_axis=0,
-                                tiled=True)
-        buf_out = ye.reshape(E * C, -1)
-    else:
-        xe = buf.reshape(E, C, -1)
-        ye = _expert_ffn(xe, params["wi_gate"], params["wi_up"], params["wo"],
-                         precision)
-        buf_out = ye.reshape(E * C, -1)
-    y = buf_out[flat_slot].reshape(T, top_k, -1)
-    y = jnp.sum(y * (gate * keep)[..., None], axis=1)
-    return y.astype(x2.dtype), aux
+    xe = buf.reshape(E, C, -1)
+    xe = jax.lax.all_to_all(xe, ep_axis, split_axis=0, concat_axis=1,
+                            tiled=True)
+    ye = _expert_ffn(xe, params["wi_gate"], params["wi_up"], params["wo"],
+                     precision)
+    ye = jax.lax.all_to_all(ye, ep_axis, split_axis=1, concat_axis=0,
+                            tiled=True)
+    y = ye.reshape(E * C, -1)[flat_slot].reshape(T, top_k, -1)
+    y = jnp.sum(y * (gate * keep)[..., None].astype(y.dtype), axis=1)
+    if m.d_shared:
+        y = y + mlp_mod.mlp(params["shared"], x2, precision=precision)
+    return y.astype(x2.dtype), _aux(probs, ids, E)
+
+
+def row_tile(n_tokens: int, top_k: int, n_experts: int) -> int:
+    """Rows per tile of the grouped expert call: about twice an expert's
+    expected rows, a multiple of 16 (bf16 sublanes), 16 to 128."""
+    want = -(-2 * n_tokens * top_k // n_experts)
+    return int(min(128, max(16, -(-want // 16) * 16)))
+
+
+def _w8a8(a, axis: int):
+    """Values of a symmetric int8 quantization with per-channel scales over
+    ``axis`` (the int8 rung's rounding, on the bf16 grouped call)."""
+    q, scale = kref.quantize_rowwise(a, axis=axis)
+    return (q.astype(jnp.float32) * scale).astype(a.dtype)
+
+
+def held_experts(params, x2, cfg: ModelConfig, *, top_k: int = 0,
+                 precision: str = "bf16", valid=None, layer=None,
+                 use_kernel: Optional[bool] = None, interpret: bool = False):
+    """Dropless MoE over the experts held here. x2: (T, D).
+
+    Routes every token over all ``n_experts`` and computes, for each pick
+    on a held expert, that expert's SwiGLU of the token: picks are sorted
+    by expert and padded per expert to whole row tiles, and one grouped
+    call (``kernels.ops.grouped_experts``) runs each tile through its
+    expert. No pick is dropped, and picks on experts held elsewhere add
+    nothing. The shared experts are added for every token. ``valid`` (T,)
+    bool: rows that take no expert rows (admission pads, dead decode
+    rows), so a valid row's result never depends on them. ``layer``: the
+    expert matrices in ``params`` are stacked over layers and this is the
+    layer's index (a layer scan passes the stack whole: the grouped kernel
+    reads it in place); None: they are this layer's own.
+
+    Returns (y (T, D), aux, counts (2,) int32): ``counts`` is [expert rows
+    computed, held experts with at least one row]."""
+    m = cfg.moe
+    top_k = top_k or m.top_k
+    T, D = x2.shape
+    lo, n = m.held_range[0], m.n_held
+    ids, w, probs = _scores(x2, params, m, top_k)
+    local = ids - lo
+    take = (local >= 0) & (local < n)
+    if valid is not None:
+        take &= valid[:, None]
+    grp = jnp.where(take, local, n).reshape(-1)               # n = none
+    N = T * top_k
+    tm = row_tile(T, top_k, m.n_experts)
+    n_tiles = max(1, min(N, (N + n * (tm - 1)) // tm))
+    R = n_tiles * tm
+    counts = jnp.bincount(grp, length=n + 1)[:n]
+    tiles = (counts + tm - 1) // tm
+    t_end = jnp.cumsum(tiles)
+    t_start = t_end - tiles
+    order = jnp.argsort(grp, stable=True)
+    g_sorted = grp[order]
+    seg = jnp.concatenate([jnp.zeros((1,), counts.dtype), jnp.cumsum(counts)])
+    first_tile = jnp.concatenate([t_start, jnp.full((1,), n_tiles,
+                                                    t_start.dtype)])
+    dest_sorted = (first_tile[g_sorted] * tm
+                   + jnp.arange(N) - seg[g_sorted])
+    dest = jnp.zeros((N,), jnp.int32).at[order].set(
+        dest_sorted.astype(jnp.int32))
+    tok = jnp.repeat(jnp.arange(T, dtype=jnp.int32), top_k)
+    row_tok = jnp.zeros((R,), jnp.int32).at[dest].set(tok, mode="drop")
+    j = jnp.arange(n_tiles)
+    total = t_end[-1]
+    jj = jnp.minimum(j, jnp.maximum(total - 1, 0))    # empty: tile before
+    tile_e = jnp.minimum(jnp.searchsorted(t_end, jj, side="right"), n - 1)
+    tile_n = jnp.where(j < total, jnp.clip(
+        counts[tile_e] - (j - t_start[tile_e]) * tm, 0, tm), 0)
+    xs = x2[row_tok]
+    wg, wu, wo = params["wi_gate"], params["wi_up"], params["wo"]
+    if layer is not None and precision == "int8":
+        wg, wu, wo = (w[layer] for w in (wg, wu, wo))   # quantize one layer
+        layer = None
+    if precision == "int8":
+        xs, wg, wu, wo = (_w8a8(xs, -1), _w8a8(wg, -1), _w8a8(wu, -1),
+                          _w8a8(wo, 1))
+    if layer is None:
+        wg, wu, wo, layer = wg[None], wu[None], wo[None], 0
+    ys = kops.grouped_experts(xs, wg, wu, wo, tile_e.astype(jnp.int32),
+                              tile_n.astype(jnp.int32),
+                              jnp.asarray(layer, jnp.int32), tm=tm,
+                              use_kernel=use_kernel, interpret=interpret)
+    yp = ys[jnp.minimum(dest, R - 1)].reshape(T, top_k, D)
+    y = jnp.sum(jnp.where(take[..., None],
+                          yp.astype(jnp.float32) * w[..., None], 0.0), axis=1)
+    if m.d_shared:
+        y = y + mlp_mod.mlp(params["shared"], x2, precision=precision)
+    cnt = jnp.stack([jnp.sum(take), jnp.sum(counts > 0)]).astype(jnp.int32)
+    return y.astype(x2.dtype), _aux(probs, ids, m.n_experts), cnt
 
 
 def moe(params, x, cfg: ModelConfig, *, top_k: int = 0,
@@ -122,9 +250,11 @@ def moe(params, x, cfg: ModelConfig, *, top_k: int = 0,
     B, S, D = x.shape
     top_k = top_k or cfg.moe.top_k
     if ep_axis is None or mesh is None:
-        y, aux = _moe_local(params, x.reshape(-1, D), cfg, top_k, precision,
-                            None)
+        y, aux, _ = held_experts(params, x.reshape(-1, D), cfg, top_k=top_k,
+                                 precision=precision, use_kernel=False)
         return y.reshape(B, S, D), aux
+    assert cfg.moe.n_held == cfg.moe.n_experts, \
+        "expert parallelism divides the whole model's experts"
 
     from jax.sharding import PartitionSpec as P
     T = B * S
@@ -135,8 +265,8 @@ def moe(params, x, cfg: ModelConfig, *, top_k: int = 0,
     elif T % mesh.shape[ep_axis] == 0:
         tok_axes = (ep_axis,)                   # decode-size batches
     else:
-        y, aux = _moe_local(params, x.reshape(-1, D), cfg, top_k, precision,
-                            None)
+        y, aux, _ = held_experts(params, x.reshape(-1, D), cfg, top_k=top_k,
+                                 precision=precision, use_kernel=False)
         return y.reshape(B, S, D), aux          # tiny batch: replicated
     fsdp = annotate.FSDP_AXIS
     fsdp = fsdp if (fsdp in mesh.shape and
@@ -145,22 +275,24 @@ def moe(params, x, cfg: ModelConfig, *, top_k: int = 0,
     def body(params_loc, x_loc):
         p = dict(params_loc)
         if fsdp is not None:                    # hand-written FSDP unshard
-            p["wi_gate"] = jax.lax.all_gather(p["wi_gate"], fsdp, axis=1,
+            p["wi_gate"] = jax.lax.all_gather(p["wi_gate"], fsdp, axis=2,
                                               tiled=True)
-            p["wi_up"] = jax.lax.all_gather(p["wi_up"], fsdp, axis=1,
+            p["wi_up"] = jax.lax.all_gather(p["wi_up"], fsdp, axis=2,
                                             tiled=True)
             p["wo"] = jax.lax.all_gather(p["wo"], fsdp, axis=2, tiled=True)
-        y, aux = _moe_local(p, x_loc, cfg, top_k, precision, ep_axis)
+        y, aux = _moe_ep(p, x_loc, cfg, top_k, precision, ep_axis)
         for ax in tok_axes:
             aux = jax.lax.pmean(aux, ax)
         return y, aux
 
     pspec = {
         "wg": P(),
-        "wi_gate": P(ep_axis, fsdp, None),
-        "wi_up": P(ep_axis, fsdp, None),
+        "wi_gate": P(ep_axis, None, fsdp),
+        "wi_up": P(ep_axis, None, fsdp),
         "wo": P(ep_axis, None, fsdp),
     }
+    pspec.update({k: jax.tree.map(lambda _: P(), params[k])
+                  for k in ("score_bias", "shared") if k in params})
     y2, aux = jax.shard_map(
         body, mesh=mesh,
         in_specs=(pspec, P(tok_axes, None)),

@@ -182,10 +182,12 @@ def model_flops(cfg, shape, knobs=None) -> float:
     # active params: MoE uses top_k of n_experts expert MLPs
     n_active = n_total
     if cfg.moe is not None:
-        k = knobs.topk_override or cfg.moe.top_k
-        expert_p = cfg.moe.n_experts * 3 * cfg.d_model * cfg.d_ff
-        active_expert_p = k * 3 * cfg.d_model * cfg.d_ff
-        n_active = n_total - cfg.n_layers * (expert_p - active_expert_p)
+        m = cfg.moe
+        k = knobs.topk_override or m.top_k
+        # a share computes its held experts' fraction of the picks
+        active = k * m.n_held / m.n_experts
+        expert_p = (m.n_held - active) * 3 * cfg.d_model * m.d_expert
+        n_active = n_total - (cfg.n_layers - cfg.n_dense_layers) * expert_p
     # embedding gather is not a matmul; unembed matmul counted separately
     n_active -= cfg.vocab_size * cfg.d_model * (1 if cfg.tie_embeddings else 2)
     keep = keep_groups(cfg.n_groups, knobs.layer_skip)
@@ -286,11 +288,11 @@ def decode_min_bytes(cfg, shape, n_chips: int, kv_quant: bool = False):
     params_b = cfg.param_count() * 2.0
     kv_bytes = 1 if kv_quant else 2
     cache_b = 0.0
-    for kind in cfg.kinds():
+    for kind in (ATTN,) * cfg.n_dense_layers + cfg.kinds():
         if kind in (ATTN, SHARED_ATTN):
-            cache_b += 2 * cfg.kv_dim * kv_bytes * shape.seq_len
+            cache_b += cfg.cache_width * kv_bytes * shape.seq_len
         elif kind == LOCAL_ATTN:
-            cache_b += 2 * cfg.kv_dim * kv_bytes * min(cfg.window,
+            cache_b += cfg.cache_width * kv_bytes * min(cfg.window,
                                                        shape.seq_len)
         elif kind == MAMBA and cfg.ssm is not None:
             di = cfg.ssm.expand * cfg.d_model

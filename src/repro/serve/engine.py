@@ -86,7 +86,7 @@ from repro.core.runtime import PliantRuntime
 from repro.core.variants import VariantTable
 from repro.dist import elastic
 from repro.models import lm
-from repro.models.attention import PagedKVCache
+from repro.models.attention import PagedKVCache, PagedLatentCache
 from repro.models.mamba2 import MambaCache
 from repro.serve import pages as pages_mod
 from repro.serve import prefill as prefill_mod
@@ -141,6 +141,33 @@ class _Admission:
     compute_s: float = 0.0
     started: bool = False        # first chunk issued (queue-wait ends THEN,
                                  # not when the admission is opened)
+
+
+class _PaddedChunk:
+    """A padding engine's admission executable: a chunk of n <= C tokens
+    runs as the one C-row program, PAD_TOKEN entries after its n valid ones
+    (``prefill.paged_prefill_chunk``). Called or lowered with a shorter
+    chunk, it pads the chunk first, so every length is that one program
+    (and priming a shorter chunk's program primes the one the engine
+    runs)."""
+
+    def __init__(self, fn, chunk: int):
+        self.fn, self.chunk = fn, chunk
+
+    def _args(self, args):
+        toks = args[1]
+        pad = self.chunk - toks.shape[1]
+        if pad > 0:
+            xp = np if isinstance(toks, np.ndarray) else jnp
+            toks = xp.pad(toks, ((0, 0), (0, pad)),
+                          constant_values=prefill_mod.PAD_TOKEN)
+        return (args[0], toks) + tuple(args[2:])
+
+    def __call__(self, *args):
+        return self.fn(*self._args(args))
+
+    def lower(self, *args):
+        return self.fn.lower(*self._args(args))
 
 
 @dataclass
@@ -335,7 +362,16 @@ class ServeEngine:
             capacity_events=0, rehomes=0,
             prefill_exe_misses=0,      # admission executables made anew
             prefill_pad_tokens=0,      # pad rows run in admission chunks
-            programs_obtained=0)       # first engine calls of executables
+            programs_obtained=0,       # first engine calls of executables
+            moe_rows=0,                # held-expert rows the megastep and
+            moe_active_experts=0)      # admission programs computed, and
+                                       # held experts that got one (per
+                                       # layer and call), read at the drains
+        # expert models: the counters' running totals after each drain that
+        # read some, stamped on time.perf_counter — (t, rows, active)
+        self.counter_marks: Deque[Tuple[float, int, int]] = \
+            collections.deque(maxlen=1 << 16)
+        self._chunk_counts: List[object] = []   # undrained admission counts
         self._tenant = None
         self._bound = False
         if (self.runtime is not None and self.runtime.auto_tenant
@@ -604,7 +640,8 @@ class ServeEngine:
                 fn = jax.jit(step,
                              in_shardings=(self._param_sh, None, None,
                                            self._cache_sh, None),
-                             out_shardings=(None, self._cache_sh), **kw)
+                             out_shardings=(None, None, self._cache_sh),
+                             **kw)
         else:
             step = step_mod.make_admission_step(
                 self.cfg, self.active_knobs, mesh=self.mesh,
@@ -614,6 +651,8 @@ class ServeEngine:
             else:
                 fn = jax.jit(step, in_shardings=(self._param_sh, None, None,
                                                  None), **kw)
+        if self._pad_chunks:
+            fn = _PaddedChunk(fn, chunk_len)
         self._prefills[key] = fn
         self._fresh[fn] = ("prefill_chunk", chunk_len)
         while len(self._prefills) > self.max_prefill_exes:
@@ -978,10 +1017,16 @@ class ServeEngine:
         sid = self.spans.on and self.spans.open("pool.push_blocks")
         bt = jnp.asarray(self.pool.blocks)
         scrub = self.pool.drain_scrub()
-        pids = jnp.asarray(scrub, jnp.int32) if scrub else None
+        pids = None
+        if scrub:
+            # padded to a power of two with the null page, whose positions
+            # nothing reads: each distinct length is an eager program of
+            # its own, obtained in the window (300-400 a Moonlight window)
+            n = 1 << (len(scrub) - 1).bit_length()
+            pids = jnp.asarray(scrub + [0] * (n - len(scrub)), jnp.int32)
 
         def one(c):
-            if isinstance(c, PagedKVCache):
+            if isinstance(c, (PagedKVCache, PagedLatentCache)):
                 ppos = c.ppos if pids is None else \
                     c.ppos.at[:, pids].set(-1)
                 return c._replace(
@@ -1252,17 +1297,19 @@ class ServeEngine:
         end = next(b for b in adm.stops if b > adm.next)
         C = min(self.prefill_chunk, end - adm.next)
         pad = self.prefill_chunk - C if self._pad_chunks else 0
-        toks = np.full(C + pad, prefill_mod.PAD_TOKEN, np.int32)
-        toks[:C] = req.prompt[adm.next:adm.next + C]
+        toks = np.asarray(req.prompt[adm.next:adm.next + C], np.int32)
         self.stats["prefill_pad_tokens"] += pad
         sid = self.spans.on and self.spans.open(
             "prefill.chunk", uid=req.uid, slot=adm.slot, C=C, pad=pad)
         t0 = time.perf_counter()
         with self._ctx():
-            adm.logits, self.caches = self._call(self._prefill_exe(C), (
-                self.params, jnp.asarray(toks[None]),
-                jnp.asarray(adm.next, jnp.int32), self.caches,
-                jnp.asarray(adm.slot, jnp.int32)))
+            adm.logits, counts, self.caches = self._call(
+                self._prefill_exe(C), (
+                    self.params, toks[None],
+                    jnp.asarray(adm.next, jnp.int32), self.caches,
+                    jnp.asarray(adm.slot, jnp.int32)))
+        if self.cfg.moe is not None:
+            self._chunk_counts.append(counts)
         adm.next += C
         # NO per-chunk (or final-chunk) block here: every sync is deferred
         # to the step's single drain point (_drain_admissions), so the final
@@ -1303,6 +1350,8 @@ class ServeEngine:
         sid = self.spans.on and self.spans.open(
             "admit.drain",
             uids=[a.req.uid for a in self._await_admit.values()])
+        rows, active = self._read_counts(self._chunk_counts)
+        self._chunk_counts = []
         freed = False
         for slot, adm in list(self._await_admit.items()):
             req = adm.req
@@ -1335,7 +1384,22 @@ class ServeEngine:
         if freed:
             self._push_blocks()
         if sid:
-            self.spans.close(sid)
+            self.spans.close(sid, rows=rows, active=active)
+
+    def _read_counts(self, counts: List[object]) -> Tuple[int, int]:
+        """Add device expert counters (each (2,) int32: rows, active) to
+        ``stats`` and stamp the new totals in ``counter_marks``. Returns
+        what they added."""
+        if not counts:
+            return 0, 0
+        rows, active = (int(v) for v in np.sum(
+            [np.asarray(c) for c in counts], axis=0))
+        self.stats["moe_rows"] += rows
+        self.stats["moe_active_experts"] += active
+        self.counter_marks.append((time.perf_counter(),
+                                   self.stats["moe_rows"],
+                                   self.stats["moe_active_experts"]))
+        return rows, active
 
     def _admit(self) -> None:
         """Dense path: legacy synchronous admission (full chunked prefill
@@ -1492,7 +1556,7 @@ class ServeEngine:
                     budget = jnp.where(mj, jnp.asarray(inj_budget), budget)
             args = (self.params, cur, pos, alive, jnp.asarray(self._uids),
                     draws, budget, self.caches)
-            toks, cur, pos, alive, draws, budget, new_caches = \
+            toks, cur, pos, alive, draws, budget, counts, new_caches = \
                 self._call_decode(self._megastep_exe(k), args, 7)
             self.caches = new_caches
             self._carry = (cur, pos, alive, draws, budget)
@@ -1500,8 +1564,13 @@ class ServeEngine:
         self.decode_dispatches += 1
         if sid:
             self.spans.close(sid, k=k, rows=len(rows))
+        # the admission chunks dispatched before this megastep have run by
+        # its drain: their counters are read there without a wait
+        counts = [counts] + self._chunk_counts if self.cfg.moe is not None \
+            else []
+        self._chunk_counts = []
         return dict(toks=toks, rows=[(i, self.slots[i]) for i in rows],
-                    k=k, t0=t0)
+                    k=k, t0=t0, counts=counts)
 
     def _drain_megastep(self, flight: dict) -> None:
         """THE decode drain point: one transfer surfaces up to K tokens and
@@ -1513,6 +1582,7 @@ class ServeEngine:
         sid = self.spans.on and self.spans.open("decode.drain")
         t0 = time.perf_counter()
         toks = np.asarray(flight["toks"])
+        rows, active = self._read_counts(flight["counts"])
         now = time.perf_counter()
         self.drain_block_s += now - t0
         wall = now - flight["t0"]
@@ -1559,7 +1629,8 @@ class ServeEngine:
         if freed:
             self._push_blocks()
         if sid:
-            self.spans.close(sid, tokens=sum(emitted))
+            self.spans.close(sid, tokens=sum(emitted), rows=rows,
+                             active=active)
         if self.runtime is not None and emitted:
             self.runtime.monitor.record_megastep(wall, emitted)
 

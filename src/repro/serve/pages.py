@@ -182,7 +182,7 @@ class PagePool(CacheStore):
             prefix_registered=0, prefix_evicted=0, tokens_skipped=0,
             blocked_admissions=0, reclaim_events=0, over_limit_allocs=0,
             register_capped=0, peak_used=0, window_freed=0,
-            grouped_admissions=0, grouped_pages=0, grouped_fallbacks=0,
+            grouped_admissions=0, grouped_pages=0,
             replenish_evictions=0, capacity_cut_events=0,
             elastic_migrations=0, elastic_prefix_evicted=0)
 
@@ -389,17 +389,20 @@ class PagePool(CacheStore):
         Reserved pages carry no valid entries yet (their ``ppos`` rows are
         scrubbed to -1, masking them out of attention) and are freed with
         the slot like any other private page. When the full group does not
-        fit, admission falls back to prompt-only rather than blocking."""
+        fit, admission returns None and the request waits: a prompt mapped
+        alone could find no page to decode into once the pool fills."""
         P = self.spec.page_size
         assert not self.slot_pages[slot], f"slot {slot} not freed"
         assert len(prompt) <= self.spec.max_pages * P, (len(prompt), self.spec)
         shard = self.slot_shard(slot)
         prompt_pages = -(-len(prompt) // P)
-        if prompt_pages > self.spec.shard_pages - 1:
+        want_full = min(max(-(-(len(prompt) + reserve_tokens) // P),
+                            prompt_pages), self.spec.max_pages)
+        if want_full > self.spec.shard_pages - 1:
             # structurally impossible — retrying every step would spin the
             # engine through max_steps with the request silently unserved
             raise RuntimeError(
-                f"prompt needs {prompt_pages} pages but the pool has "
+                f"request needs {want_full} pages but the pool has "
                 f"{self.spec.shard_pages - 1} usable on the slot's shard; "
                 "size n_pages up")
         shared, entry = self.lookup_prefix(prompt, tag, shard)
@@ -408,30 +411,28 @@ class PagePool(CacheStore):
         # page-aware packing retries several candidates per step while the
         # pool is blocked — without this gate every failed retry would run
         # _alloc's pressure loop and progressively drain the prefix cache.
-        # ``evictable`` counts index pages only the index pins (ref 1):
-        # evicting those both lowers ``used`` and refills the free list, so
-        # the gate passing guarantees the allocation below succeeds.
+        # ``evictable`` counts index pages only the index pins (every ref
+        # is an entry's: the chained entries of one prompt share their
+        # leading pages): evicting those both lowers ``used`` and refills
+        # the free list, so the gate passing guarantees the allocation
+        # below succeeds.
         hit_pages = set(entry.pages) if entry is not None else set()
+        pins = collections.Counter(p for e in self.index.values()
+                                   for p in e.pages)
         evict_all = evict_shard = 0
-        for e in self.index.values():
-            for p in e.pages:
-                if self.ref[p] == 1 and p not in hit_pages:
-                    evict_all += 1
-                    if self.page_shard(p) == shard:
-                        evict_shard += 1
+        for p, n in pins.items():
+            if self.ref[p] == n and p not in hit_pages:
+                evict_all += 1
+                if self.page_shard(p) == shard:
+                    evict_shard += 1
         # budget headroom can be relieved by evicting ANY shard's entries;
         # supply headroom only by this shard's free list + evictable pages
         head = min(max(self.limit - self.used, 0) + evict_all,
                    len(self._free[shard]) + evict_shard)
-        want_full = min(max(-(-(len(prompt) + reserve_tokens) // P),
-                            prompt_pages), self.spec.max_pages)
-        n_total = next((c for c in dict.fromkeys([want_full, prompt_pages])
-                        if c - shared // P <= head), None)
-        if n_total is None:
+        n_total = want_full
+        if n_total - shared // P > head:
             self.stats["blocked_admissions"] += 1
             return None
-        if n_total < want_full:
-            self.stats["grouped_fallbacks"] += 1
         n_new = n_total - shared // P
         if shared:
             # pin the hit pages BEFORE allocating fresh ones: under pressure

@@ -21,7 +21,7 @@ from repro.models import mlp as mlp_mod
 from repro.models import moe as moe_mod
 from repro.models.attention import KVCache
 from repro.models.common import apply_rope, rms_norm
-from repro.models.lm import logits_fn
+from repro.models.lm import logits_fn, scan_layers
 from repro.kernels import ref as kref
 from repro.kernels import ops as kops
 
@@ -137,9 +137,10 @@ PAD_TOKEN = -1      # pad entry of a paged admission chunk
 def pads_exactly(cfg: ModelConfig) -> bool:
     """True when pad rows in a paged admission chunk leave every valid
     row's result and every mapped page as an exact-length chunk would: no
-    SSM layer (its state would absorb the pad tokens) and no expert layer
-    (pad tokens would take expert capacity from real ones)."""
-    return MAMBA not in cfg.pattern and cfg.moe is None
+    SSM layer (its state would absorb the pad tokens). Expert layers pad
+    exactly: serving runs them dropless, and pad rows take no expert
+    rows."""
+    return MAMBA not in cfg.pattern
 
 
 def paged_prefill_chunk(params, tokens, start, caches, slot,
@@ -161,8 +162,12 @@ def paged_prefill_chunk(params, tokens, start, caches, slot,
     Each pad row repeats the last valid token at its position, so it
     computes a copy of row n-1 over the same keys and stays finite under
     every causal and window mask; its K/V and position writes go to the
-    never-read null page. An all-valid chunk is the plain computation. The
-    pad rows are exact only where ``pads_exactly(cfg)``.
+    never-read null page, and they take no expert rows. An all-valid chunk
+    is the plain computation. The pad rows are exact only where
+    ``pads_exactly(cfg)``.
+
+    Returns (logits, counts, caches): ``counts`` (2,) int32 are the chunk's
+    expert counters summed over layers (``blocks._serve_ffn``).
     """
     from repro.models.blocks import block_prefill_paged
     B, C = tokens.shape
@@ -171,26 +176,17 @@ def paged_prefill_chunk(params, tokens, start, caches, slot,
     rel = jnp.minimum(jnp.arange(C), last)
     h = params["embed"][jnp.take(tokens, rel, axis=1)]
     positions = start + jnp.broadcast_to(rel, (B, C))
-    shared = params.get("shared")
 
-    def group_body(h, xs):
-        group_params, group_caches = xs
-        new_caches = []
-        for j, kind in enumerate(cfg.pattern):
-            p = shared if kind == SHARED_ATTN else group_params.get(f"pos{j}")
-            h, nc, _ = block_prefill_paged(kind, p, h, positions,
-                                           group_caches[j], cfg, knobs,
-                                           slot=slot, valid=valid,
-                                           dyn_scatter=dyn_scatter,
-                                           mesh=mesh, use_kernel=use_kernel,
-                                           interpret=interpret)
-            new_caches.append(nc)
-        return h, tuple(new_caches)
+    def layer(kind, p, h, cache, i):
+        return block_prefill_paged(
+            kind, p, h, positions, cache, cfg, knobs, slot=slot, valid=valid,
+            layer=i, dyn_scatter=dyn_scatter, mesh=mesh,
+            use_kernel=use_kernel, interpret=interpret)
 
-    h, new_caches = jax.lax.scan(group_body, h, (params["groups"], caches))
+    h, new_caches, counts = scan_layers(params, caches, cfg, h, layer)
     h = jax.lax.dynamic_index_in_dim(h, last, axis=1, keepdims=False)
     h = rms_norm(h, params["final_norm"], cfg.norm_eps)
-    return logits_fn(params, h, cfg), new_caches
+    return logits_fn(params, h, cfg), counts, new_caches
 
 
 def prefill_with_cache(params, tokens, cfg: ModelConfig, max_len: int,

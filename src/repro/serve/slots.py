@@ -11,6 +11,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.models.attention import (KVCache, KV_SCALE, PagedKVCache,
+                                    PagedLatentCache,
                                     dequantize_kv, quantize_kv)
 from repro.models.mamba2 import MambaCache
 
@@ -73,6 +74,10 @@ def convert_caches(caches, kv_quant: bool, dtype=jnp.float32):
                 return c._replace(kp=q(c.kp), vp=q(c.vp))
             if not kv_quant and c.kp.dtype == jnp.int8:
                 return c._replace(kp=dq(c.kp), vp=dq(c.vp))
+            return c
+        if isinstance(c, PagedLatentCache):
+            if kv_quant != (c.cp.dtype == jnp.int8):
+                return c._replace(cp=q(c.cp) if kv_quant else dq(c.cp))
             return c
         return c
 

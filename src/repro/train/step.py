@@ -200,9 +200,10 @@ def make_paged_megastep(cfg: ModelConfig, knobs: ApproxKnobs = PRECISE, *,
                         dynamic_scatter: bool = False,
                         interpret: bool = False):
     """Returns decode_megastep(params, cur, pos, alive, uids, draws, budget,
-    caches) -> (toks (B,K), cur, pos, alive, draws, budget, caches) — K fused
-    decode steps with on-device sampling and stop masking in ONE
-    executable (``lm.decode_megastep``).
+    caches) -> (toks (B,K), cur, pos, alive, draws, budget, counts, caches)
+    — K fused decode steps with on-device sampling and stop masking in ONE
+    executable (``lm.decode_megastep``); ``counts`` are their expert
+    counters.
 
     The caches argument sits at position 7 so the engine can jit with
     ``donate_argnums=(7,)`` and update the paged pool + SSM state in
@@ -249,7 +250,7 @@ def make_paged_admission_step(cfg: ModelConfig, knobs: ApproxKnobs = PRECISE,
                               use_kernel: Optional[bool] = None,
                               interpret: bool = False):
     """Returns prefill_chunk(params, tokens, start, caches, slot)
-    -> (logits, caches).
+    -> (logits, counts, caches), ``counts`` the chunk's expert counters.
 
     The paged engine's admission cell: one prompt chunk written straight
     into the batched page-pool caches at ``slot``'s block-table row. Both

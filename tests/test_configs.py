@@ -11,7 +11,7 @@ EXPECTED_BILLIONS = {       # within documented substitutions (DESIGN.md)
     "mistral-large-123b": (115, 130), "phi4-mini-3.8b": (3.4, 4.3),
     "gemma2-27b": (24, 30), "whisper-large-v3": (1.2, 2.4),
     "paligemma-3b": (2.0, 3.2), "mamba2-780m": (0.7, 0.9),
-    "olmoe-1b-7b": (6.0, 7.5), "moonshot-v1-16b-a3b": (15, 30),
+    "olmoe-1b-7b": (6.0, 7.5), "moonlight-16b-a3b": (15.9, 16.0),
 }
 
 ASSIGNED = {
@@ -31,9 +31,12 @@ ASSIGNED = {
                          d_ff=16384, vocab_size=257216),
     "mamba2-780m": dict(n_layers=48, d_model=1536, d_ff=0, vocab_size=50280),
     "olmoe-1b-7b": dict(n_layers=16, d_model=2048, n_heads=16,
-                        n_kv_heads=16, d_ff=1024, vocab_size=50304),
-    "moonshot-v1-16b-a3b": dict(n_layers=48, d_model=2048, n_heads=16,
-                                n_kv_heads=16, d_ff=1408, vocab_size=163840),
+                        n_kv_heads=16, d_ff=0, vocab_size=50304),
+    "moonlight-16b-a3b": dict(n_layers=27, n_dense_layers=1, d_model=2048,
+                              n_heads=16, n_kv_heads=16, head_dim=128,
+                              kv_lora_rank=512, rope_dim=64, d_ff=11264,
+                              vocab_size=163840, rope_theta=50_000.0,
+                              norm_eps=1e-5, tie_embeddings=False),
 }
 
 
@@ -54,7 +57,18 @@ def test_param_count_in_band(name):
 def test_moe_knobs():
     assert ARCHS["olmoe-1b-7b"].moe.n_experts == 64
     assert ARCHS["olmoe-1b-7b"].moe.top_k == 8
-    assert ARCHS["moonshot-v1-16b-a3b"].moe.top_k == 6
+    assert ARCHS["olmoe-1b-7b"].moe.d_expert == 1024
+    m = ARCHS["moonlight-16b-a3b"].moe
+    assert (m.n_experts, m.top_k, m.d_expert, m.d_shared) == (64, 6, 1408,
+                                                              2816)
+    assert (m.scoring, m.score_bias, m.norm_topk, m.scale) == (
+        "sigmoid", True, True, 2.446)
+    assert m.held_range == (0, 64)
+    # one chip's share of the four-chip expert-parallel deployment
+    ep4 = get_config("moonlight-16b-a3b-ep4")
+    assert ep4.moe.held_range == (0, 16) and ep4.moe.n_experts == 64
+    assert round(ARCHS["moonlight-16b-a3b"].param_count() / 1e9, 2) == 15.96
+    assert round(ep4.param_count() / 1e9, 2) == 5.16
 
 
 def test_cell_grid():

@@ -47,15 +47,14 @@ def test_moe_ep_matches_local(subproc):
     out = subproc("""
 import dataclasses, jax, jax.numpy as jnp, numpy as np
 from repro.configs import get_config
-from repro.configs.base import MoEConfig
 from repro.launch.mesh import make_mesh
 from repro.models import moe as moe_mod
 from repro.models.common import init_params
 
 cfg = get_config("olmoe-1b-7b-smoke")
 # high capacity so EP reordering cannot change the capacity-drop set
-cfg = dataclasses.replace(cfg, moe=MoEConfig(n_experts=8, top_k=2,
-                                             capacity_factor=8.0))
+cfg = dataclasses.replace(cfg, moe=dataclasses.replace(
+    cfg.moe, capacity_factor=8.0))
 params = init_params(moe_mod.moe_specs(cfg), jax.random.PRNGKey(0),
                      jnp.float32)
 x = jax.random.normal(jax.random.PRNGKey(1), (2, 16, cfg.d_model),

@@ -1,8 +1,8 @@
 """Padded paged admission: a ragged prompt tail run through the full-chunk
 admission program, its pad rows after the valid ones, gives what the
-exact-length program gives; attention-only paged engines obtain one
-admission program per knob set, while SSM and expert configurations keep
-one per tail length and pad nothing."""
+exact-length program gives; attention-only and (dropless) expert paged
+engines obtain one admission program per knob set, while SSM
+configurations keep one per tail length and pad nothing."""
 import dataclasses
 import functools
 
@@ -56,16 +56,17 @@ def test_padded_tail_equals_exact_tail(name, window, kv_quant, dyn_scatter):
     slot = jnp.asarray(SLOT, jnp.int32)
     caches = mapped_caches(cfg, kv_quant, pages)
     for s in (0, CHUNK):                          # two full chunks first
-        _, caches = fn(params, jnp.asarray(prompt[None, s:s + CHUNK]),
+        _, _, caches = fn(params, jnp.asarray(prompt[None, s:s + CHUNK]),
                        jnp.asarray(s, jnp.int32), caches, slot)
     before = jax.tree.map(np.asarray, caches)
     start = jnp.asarray(2 * CHUNK, jnp.int32)
     tail = prompt[2 * CHUNK:]
-    exact_logits, exact = fn(params, jnp.asarray(tail[None]), start,
+    exact_logits, _, exact = fn(params, jnp.asarray(tail[None]), start,
                              jax.tree.map(jnp.asarray, before), slot)
     padded_toks = np.full(CHUNK, PAD_TOKEN, np.int32)
     padded_toks[:len(tail)] = tail
-    pad_logits, padded = fn(params, jnp.asarray(padded_toks[None]), start,
+    pad_logits, _, padded = fn(params, jnp.asarray(padded_toks[None]),
+                               start,
                             jax.tree.map(jnp.asarray, before), slot)
 
     np.testing.assert_allclose(np.asarray(pad_logits),
@@ -106,7 +107,7 @@ def test_all_valid_block_is_the_plain_computation(length):
     toks = np.random.default_rng(2).integers(
         1, cfg.vocab_size, (1, length)).astype(np.int32)
     caches = mapped_caches(cfg, False, [2, 4, 6])
-    logits, _ = fn(params, jnp.asarray(toks), jnp.asarray(0, jnp.int32),
+    logits, _, _ = fn(params, jnp.asarray(toks), jnp.asarray(0, jnp.int32),
                    caches, jnp.asarray(SLOT, jnp.int32))
     # the reference: the last row of a one-shot prefill of the same tokens
     ref, _ = prefill_with_cache(params, jnp.asarray(toks), cfg, 32)
@@ -158,8 +159,22 @@ def test_attention_only_engine_obtains_one_admission_program():
 @pytest.mark.parametrize("name", ["zamba2-2.7b",      # SSM hybrid
                                   "olmoe-1b-7b"])     # experts
 def test_ssm_and_expert_engines_keep_exact_lengths(name):
-    assert not pads_exactly(model(name)[0])
-    eng, _, asked = serve(name, LENGTHS)
+    """SSM engines keep one admission program per tail length. Expert
+    engines kept one too while their layer dropped picks past a capacity;
+    the dropless layer gives pad rows no expert rows, so they now pad like
+    attention-only engines, obtain one program, and serve what the
+    exact-length path serves."""
+    cfg = model(name)[0]
+    eng, outs, asked = serve(name, LENGTHS)
     assert len(set(asked)) > 1
-    assert eng.stats["prefill_exe_misses"] == len(set(asked))
-    assert eng.stats["prefill_pad_tokens"] == 0
+    if cfg.moe is None:
+        assert not pads_exactly(cfg)
+        assert eng.stats["prefill_exe_misses"] == len(set(asked))
+        assert eng.stats["prefill_pad_tokens"] == 0
+        return
+    assert pads_exactly(cfg)
+    assert eng.stats["prefill_exe_misses"] == 1
+    assert eng.stats["prefill_pad_tokens"] == 3 + 2 + 1 + 3
+    exact, ref_outs, _ = serve(name, LENGTHS, pad=False)
+    assert exact.stats["prefill_exe_misses"] == len(set(asked))
+    assert outs == ref_outs

@@ -1,10 +1,13 @@
 """Grouped/speculative page allocation and the watermark replenisher.
 
 Admission maps a request's projected decode pages in the SAME free-list
-transaction as its prompt pages (all-or-nothing, falling back to
-prompt-only under pressure); copy-on-write prefix pins survive the
+transaction as its prompt pages (all-or-nothing: a request whose decode
+pages do not fit waits, so decode never finds the pool empty);
+copy-on-write prefix pins survive the
 speculative reservation even when the allocation evicts the index; and the
 background watermark reservation never strands a page."""
+import copy
+
 import pytest
 
 from repro.serve.pages import PagePool, PageSpec
@@ -58,17 +61,21 @@ def test_alloc_n_is_all_or_nothing():
 
 
 def test_grouped_falls_back_to_prompt_only_under_pressure():
+    """Under pressure a grouped admission no longer falls back to mapping
+    the prompt alone (its decode could then find no page): it waits."""
     pool = PagePool(PageSpec(page_size=4, n_pages=8, max_pages=8),
                     batch_slots=2)                          # 7 usable
     assert pool.admit(1, list(range(100, 112)), "tag") is not None  # 3 pages
     plan = pool.admit(0, list(range(12)), "tag", reserve_tokens=16)
-    # full group (7 pages) no longer fits; the 3 prompt pages do
-    assert plan is not None and plan.reserved_pages == 0
-    assert len(pool.slot_pages[0]) == 3
-    assert pool.stats["grouped_fallbacks"] == 1
+    # full group (7 pages) no longer fits, though the 3 prompt pages would
+    assert plan is None
+    assert pool.slot_pages[0] == []
+    assert pool.stats["blocked_admissions"] == 1
     assert pool.stats["grouped_admissions"] == 0
-    # decode growth falls back to the incremental path and still works
-    assert pool.ensure_decode_page(0, 12) is True
+    # once the other slot frees its pages the whole group fits
+    pool.free_slot(1)
+    plan = pool.admit(0, list(range(12)), "tag", reserve_tokens=16)
+    assert plan is not None and plan.reserved_pages == 4
     pool.assert_consistent()
 
 
@@ -156,3 +163,69 @@ def test_replenish_measures_headroom_under_reclaim_limit():
         pool.replenish(low=pool.spec.usable, high=pool.spec.usable)
     assert pool.used == 0 and len(pool.free) == pool.spec.usable
     pool.assert_consistent()
+
+
+def test_admit_that_does_not_fit_returns_none_and_changes_nothing():
+    pool = PagePool(PageSpec(page_size=4, n_pages=16, max_pages=8),
+                    batch_slots=3)                          # 15 usable
+    assert pool.admit(0, list(range(20)), "tag", reserve_tokens=8)  # 7
+    assert pool.admit(1, list(range(50, 62)), "tag")                # 3
+    state = lambda: copy.deepcopy((
+        [list(f) for f in pool._free], list(pool.ref), pool.blocks.tolist(),
+        pool.slot_pages, sorted(pool.index), pool.used,
+        {k: v for k, v in pool.stats.items() if k != "blocked_admissions"},
+        list(pool.scrub_pending)))
+    before = state()
+    # the prompt's 3 pages fit, prompt and decode (8 pages) do not
+    assert pool.admit(2, list(range(100, 112)), "tag",
+                      reserve_tokens=20) is None
+    assert state() == before
+    assert pool.stats["blocked_admissions"] == 1
+    pool.assert_consistent()
+
+
+def test_chained_prefix_pages_count_as_evictable():
+    """The chained prefix entries of one prompt share their leading pages
+    (each page pinned once per entry): an admission that needs them freed
+    must see them as evictable, or it would wait forever on an idle
+    pool."""
+    pool = PagePool(PageSpec(page_size=4, n_pages=16, max_pages=16),
+                    batch_slots=2)                          # 15 usable
+    prompt = list(range(40))                                # 10 pages
+    plan = pool.admit(0, prompt, "tag")
+    for b in plan.register:                   # as the engine's prefill does
+        pool.register_prefix(0, prompt, "tag", b)
+    pool.free_slot(0)                         # its entries keep them
+    assert pool.used == 10 and max(pool.ref) > 1
+    plan = pool.admit(1, list(range(500, 540)), "tag", reserve_tokens=12)
+    assert plan is not None and len(pool.slot_pages[1]) == 13
+    pool.assert_consistent()
+
+
+def test_full_pool_serves_every_request():
+    """The pool fault's witness at test size (4 slots, 39 usable pages,
+    prompts 40-200, outputs 4-48, seed 13): with the prompt mapped alone
+    when its decode pages did not fit, a decode found the pool full and
+    raised 'page pool exhausted mid-decode'."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+    from repro.configs import get_config
+    from repro.models import api
+    from repro.serve.engine import Request, ServeEngine
+    cfg = get_config("phi4-mini-3.8b-smoke")
+    eng = ServeEngine(cfg, batch_slots=4, max_len=256,
+                      params=api.init(cfg, jax.random.PRNGKey(0),
+                                      jnp.float32),
+                      prefill_chunk=32, paged=True, page_size=16,
+                      n_pages=40, megastep_k=4)
+    assert eng.pool.spec.usable == 39
+    rng = np.random.default_rng(13)
+    reqs = [Request(i, list(map(int, rng.integers(
+        1, cfg.vocab_size, int(rng.integers(40, 201))))),
+        max_new=int(rng.integers(4, 49))) for i in range(24)]
+    for r in reqs:
+        eng.submit(r)
+    eng.run()
+    assert all(r.done and len(r.out) == r.max_new for r in reqs)
+    assert eng.pool.stats["blocked_admissions"] > 0    # the pool did fill

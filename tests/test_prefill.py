@@ -20,10 +20,8 @@ def test_prefill_handoff_matches_decode_warmup(name):
         # once while per-token warmup routes tiny batches — different drop
         # sets are expected behavior under tight capacity (see test_dist)
         import dataclasses
-        from repro.configs.base import MoEConfig
         cfg = dataclasses.replace(
-            cfg, moe=MoEConfig(cfg.moe.n_experts, cfg.moe.top_k,
-                               capacity_factor=16.0))
+            cfg, moe=dataclasses.replace(cfg.moe, capacity_factor=16.0))
     params = api.init(cfg, jax.random.PRNGKey(0), jnp.float32)
     B, S, max_len = 2, 12, 32
     toks = jax.random.randint(jax.random.PRNGKey(1), (B, S), 0,

@@ -124,3 +124,53 @@ def test_flash_attention_compiles(one_chip):
                    ((B, KVH, S, hd), jnp.bfloat16),
                    ((B, KVH, S, hd), jnp.bfloat16))
     assert "tpu_custom_call" in hlo
+
+
+# moonlight-16b-a3b (the four-chip deployment's one-chip share) decode: 48
+# slots x 2688 tokens, 16-token pages, 26 expert layers of 16 held experts
+MOON = dict(B=48, H=16, W=640, dv=512, P=16, max_len=2688, L=26)
+
+
+@pytest.mark.parametrize("kv_dtype,kv_scale", [(jnp.bfloat16, 0.0),
+                                               (jnp.int8, 0.05)])
+def test_paged_latent_attention_compiles(one_chip, kv_dtype, kv_scale):
+    """The latent pool at bf16, and int8 for the int8 rung."""
+    from repro.kernels.paged_attention import paged_latent_attention_impl
+    B, H, W, dv, P, L = (MOON[k] for k in ("B", "H", "W", "dv", "P", "L"))
+    M = MOON["max_len"] // P
+    n_pages = B * M + 1
+
+    def fn(q, cp, ppos, block, pos, layer):
+        return paged_latent_attention_impl(q, cp, ppos, block, pos, layer,
+                                           dv=dv, scale=192 ** -0.5,
+                                           kv_scale=kv_scale)
+
+    hlo = _compile(fn, one_chip,
+                   ((B, H, W), jnp.bfloat16),
+                   ((L, n_pages, P, W), kv_dtype),
+                   ((L, n_pages, P), jnp.int32),
+                   ((B, M), jnp.int32), ((B,), jnp.int32), ((), jnp.int32))
+    assert "paged_latent_decode" in hlo
+
+
+@pytest.mark.parametrize("tokens", [48, 128])
+def test_moe_experts_compiles(one_chip, tokens):
+    """A decode batch of 48 rows and a 128-token admission chunk, top-6 of
+    64 experts, through 16 held experts of width 1408."""
+    from repro.kernels.moe_experts import moe_experts
+    from repro.models.moe import row_tile
+    L, E, F, D, k = MOON["L"], 16, 1408, 2048, 6
+    tm = row_tile(tokens, k, 64)
+    n_tiles = min(tokens * k, (tokens * k + E * (tm - 1)) // tm)
+
+    def fn(x, wg, wu, wo, tile_e, tile_n, layer):
+        return moe_experts(x, wg, wu, wo, tile_e, tile_n, layer, tm=tm)
+
+    hlo = _compile(fn, one_chip,
+                   ((n_tiles * tm, D), jnp.bfloat16),
+                   ((L, E, F, D), jnp.bfloat16),
+                   ((L, E, F, D), jnp.bfloat16),
+                   ((L, E, F, D), jnp.bfloat16),
+                   ((n_tiles,), jnp.int32), ((n_tiles,), jnp.int32),
+                   ((), jnp.int32))
+    assert "moe_experts" in hlo
