@@ -34,8 +34,8 @@ class MoEConfig:
     scoring: str = "softmax"         # softmax | sigmoid
     score_bias: bool = False         # selection adds a learned per-expert
                                      # bias (DeepSeek-V3 noaux_tc)
-    norm_topk: bool = True           # picked weights renormalised to sum 1
-    scale: float = 1.0               # routed_scaling_factor
+    scale: float = 1.0               # routed_scaling_factor (picked
+                                     # weights renormalised to sum 1 first)
 
     @property
     def held_range(self) -> Tuple[int, int]:

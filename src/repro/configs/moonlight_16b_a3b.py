@@ -38,8 +38,7 @@ CONFIG = ModelConfig(
     vocab_size=163840,
     pattern=(ATTN,),
     moe=MoEConfig(n_experts=64, top_k=6, d_expert=1408, d_shared=2816,
-                  scoring="sigmoid", score_bias=True, norm_topk=True,
-                  scale=2.446),
+                  scoring="sigmoid", score_bias=True, scale=2.446),
     rope_theta=50_000.0,
     norm_eps=1e-5,
     tie_embeddings=False,

@@ -54,19 +54,21 @@ def matmul(precision: str):
     return bf16_matmul
 
 
-def grouped_experts(x, wg, wu, wo, tile_e, tile_n, layer, *, tm: int,
-                    use_kernel=None, interpret: bool = False):
+def grouped_experts(x, wg, wu, wo, tile_e, tile_n, layer, scales=None, *,
+                    tm: int, use_kernel=None, interpret: bool = False):
     """The held experts' SwiGLU over expert-sorted row tiles, weights
     (L, E, F, D) at ``layer``: the Pallas ``moe_experts`` kernel on TPU (or
-    in interpret mode), jnp elsewhere."""
+    in interpret mode), jnp elsewhere. ``scales``: int8 weights and their
+    channel scales (the kernel's int8 mode)."""
     if use_kernel is None:
         use_kernel = _on_tpu()
     if use_kernel or interpret:
         DISPATCH_COUNTS["moe_experts"] += 1
-        return moe_experts(x, wg, wu, wo, tile_e, tile_n, layer, tm=tm,
-                           interpret=interpret)
+        return moe_experts(x, wg, wu, wo, tile_e, tile_n, layer, scales,
+                           tm=tm, interpret=interpret)
     DISPATCH_COUNTS["moe_experts_ref"] += 1
-    return ref.moe_experts_ref(x, wg, wu, wo, tile_e, tile_n, layer, tm=tm)
+    return ref.moe_experts_ref(x, wg, wu, wo, tile_e, tile_n, layer, scales,
+                               tm=tm)
 
 
 def ssd(x, dt, a, b, c, *, chunk=128, d_skip=None):

@@ -171,18 +171,44 @@ def ssd_chunked_ref(x, dt, a, b, c, *, chunk=64, d_skip=None,
 
 # ----------------------------------------------------- grouped experts ----
 
-def moe_experts_ref(x, wg, wu, wo, tile_e, tile_n, layer, *, tm: int):
+def moe_experts_ref(x, wg, wu, wo, tile_e, tile_n, layer, scales=None, *,
+                    tm: int):
     """Oracle of ``kernels.moe_experts``: each tile of ``tm`` sorted rows
     through its expert's SwiGLU (weights (L, E, F, D), layer ``layer``);
-    empty tiles give 0."""
+    empty tiles give 0. ``scales``: the kernel's int8 mode (int8 weights,
+    rows quantized per row, the SwiGLU's product per row of each f-block
+    of the kernel's width)."""
+    from repro.kernels.moe_experts import block_f
     wg, wu, wo = (jax.lax.dynamic_index_in_dim(w, layer, keepdims=False)
                   for w in (wg, wu, wo))
     n_t = x.shape[0] // tm
     xt = x.reshape(n_t, tm, -1)
     f32 = jnp.float32
-    g = jnp.einsum("tmd,tfd->tmf", xt, wg[tile_e], preferred_element_type=f32)
-    u = jnp.einsum("tmd,tfd->tmf", xt, wu[tile_e], preferred_element_type=f32)
-    h = (jax.nn.silu(g) * u).astype(x.dtype)
-    y = jnp.einsum("tmf,tfd->tmd", h, wo[tile_e], preferred_element_type=f32)
+    if scales is None:
+        g = jnp.einsum("tmd,tfd->tmf", xt, wg[tile_e],
+                       preferred_element_type=f32)
+        u = jnp.einsum("tmd,tfd->tmf", xt, wu[tile_e],
+                       preferred_element_type=f32)
+        h = (jax.nn.silu(g) * u).astype(x.dtype)
+        y = jnp.einsum("tmf,tfd->tmd", h, wo[tile_e],
+                       preferred_element_type=f32)
+    else:
+        gs, us, os_ = (jax.lax.dynamic_index_in_dim(s, layer, keepdims=False)
+                       for s in scales)
+        i32 = jnp.int32
+        xq, xs = quantize_rowwise(xt)
+        g = jnp.einsum("tmd,tfd->tmf", xq, wg[tile_e],
+                       preferred_element_type=i32).astype(f32) * xs
+        u = jnp.einsum("tmd,tfd->tmf", xq, wu[tile_e],
+                       preferred_element_type=i32).astype(f32) * xs
+        g, u = g * gs[tile_e], u * us[tile_e]
+        F = g.shape[-1]
+        tf = block_f(F)
+        hq, hs = quantize_rowwise(
+            (g / (1.0 + jnp.exp(-g)) * u).reshape(n_t, tm, F // tf, tf))
+        wob = wo[tile_e].reshape(n_t, F // tf, tf, -1)
+        y = jnp.sum(jnp.einsum("tmbf,tbfd->tmbd", hq, wob,
+                               preferred_element_type=i32).astype(f32) * hs,
+                    axis=2) * os_[tile_e]
     y = jnp.where((tile_n > 0)[:, None, None], y, 0.0)
     return y.reshape(x.shape).astype(x.dtype)

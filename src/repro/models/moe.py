@@ -69,8 +69,7 @@ def _scores(x2, params, m: MoEConfig, top_k: int):
         choice = probs + params["score_bias"].astype(jnp.float32)
     _, ids = jax.lax.top_k(choice, top_k)
     w = jnp.take_along_axis(probs, ids, axis=-1)
-    if m.norm_topk:
-        w = w / (jnp.sum(w, -1, keepdims=True) + 1e-20)
+    w = w / (jnp.sum(w, -1, keepdims=True) + 1e-20)
     return ids, w * m.scale, probs
 
 
@@ -159,13 +158,6 @@ def row_tile(n_tokens: int, top_k: int, n_experts: int) -> int:
     return int(min(128, max(16, -(-want // 16) * 16)))
 
 
-def _w8a8(a, axis: int):
-    """Values of a symmetric int8 quantization with per-channel scales over
-    ``axis`` (the int8 rung's rounding, on the bf16 grouped call)."""
-    q, scale = kref.quantize_rowwise(a, axis=axis)
-    return (q.astype(jnp.float32) * scale).astype(a.dtype)
-
-
 def held_experts(params, x2, cfg: ModelConfig, *, top_k: int = 0,
                  precision: str = "bf16", valid=None, layer=None,
                  use_kernel: Optional[bool] = None, interpret: bool = False):
@@ -222,17 +214,22 @@ def held_experts(params, x2, cfg: ModelConfig, *, top_k: int = 0,
         counts[tile_e] - (j - t_start[tile_e]) * tm, 0, tm), 0)
     xs = x2[row_tok]
     wg, wu, wo = params["wi_gate"], params["wi_up"], params["wo"]
-    if layer is not None and precision == "int8":
-        wg, wu, wo = (w[layer] for w in (wg, wu, wo))   # quantize one layer
-        layer = None
+    scales = None
     if precision == "int8":
-        xs, wg, wu, wo = (_w8a8(xs, -1), _w8a8(wg, -1), _w8a8(wu, -1),
-                          _w8a8(wo, 1))
+        # W8A8 like ``kops.quantized_matmul``: this layer's weights are
+        # quantized per output channel on each call, the grouped call runs
+        # int8 x int8
+        if layer is not None:
+            wg, wu, wo, layer = wg[layer], wu[layer], wo[layer], None
+        (wg, gs), (wu, us) = (kref.quantize_rowwise(w) for w in (wg, wu))
+        wo, os_ = kref.quantize_rowwise(wo, axis=1)
+        scales = (jnp.swapaxes(gs, 1, 2)[None], jnp.swapaxes(us, 1, 2)[None],
+                  os_[None])
     if layer is None:
         wg, wu, wo, layer = wg[None], wu[None], wo[None], 0
     ys = kops.grouped_experts(xs, wg, wu, wo, tile_e.astype(jnp.int32),
                               tile_n.astype(jnp.int32),
-                              jnp.asarray(layer, jnp.int32), tm=tm,
+                              jnp.asarray(layer, jnp.int32), scales, tm=tm,
                               use_kernel=use_kernel, interpret=interpret)
     yp = ys[jnp.minimum(dest, R - 1)].reshape(T, top_k, D)
     y = jnp.sum(jnp.where(take[..., None],

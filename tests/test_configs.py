@@ -61,8 +61,7 @@ def test_moe_knobs():
     m = ARCHS["moonlight-16b-a3b"].moe
     assert (m.n_experts, m.top_k, m.d_expert, m.d_shared) == (64, 6, 1408,
                                                               2816)
-    assert (m.scoring, m.score_bias, m.norm_topk, m.scale) == (
-        "sigmoid", True, True, 2.446)
+    assert (m.scoring, m.score_bias, m.scale) == ("sigmoid", True, 2.446)
     assert m.held_range == (0, 64)
     # one chip's share of the four-chip expert-parallel deployment
     ep4 = get_config("moonlight-16b-a3b-ep4")

@@ -21,6 +21,8 @@ import numpy as np
 import pytest
 
 from repro.configs import get_config
+from repro.kernels import ref as kref
+from repro.kernels.moe_experts import moe_experts
 from repro.models import api, attention, lm, mlp, moe
 from repro.serve.prefill import PAD_TOKEN
 from repro.train import step as step_mod
@@ -209,3 +211,51 @@ def test_padded_admission_chunk_equals_exact_length():
         for x, y in zip(a, b):              # every page but the null one
             np.testing.assert_allclose(np.asarray(x)[:, 1:],
                                        np.asarray(y)[:, 1:], atol=1e-5)
+
+
+def _int8_tiles(F=256, D=128, E=3, tm=16):
+    """Expert-sorted row tiles (one empty) and int8 weights of two layers
+    with their channel scales, as ``held_experts`` makes them."""
+    rng = np.random.default_rng(7)
+    x = jnp.asarray(rng.normal(size=(4 * tm, D)), jnp.bfloat16)
+    ws = [jnp.asarray(rng.normal(size=(2, E, F, D)) * D ** -0.5, jnp.float32)
+          for _ in range(3)]
+    (wg, gs), (wu, us) = (kref.quantize_rowwise(w) for w in ws[:2])
+    wo, os_ = kref.quantize_rowwise(ws[2], axis=2)
+    scales = (jnp.swapaxes(gs, 2, 3), jnp.swapaxes(us, 2, 3), os_)
+    tiles = (jnp.asarray([0, 0, 2, 2], jnp.int32),
+             jnp.asarray([tm, 5, tm, 0], jnp.int32))
+    return x, (wg, wu, wo), scales, tiles, tm
+
+
+def test_int8_grouped_kernel_matches_reference():
+    """The grouped kernel's int8 mode (interpret) against its oracle, two
+    f-blocks a tile: the int32 products are exact, so only float32 scale
+    products and an int8 rounding that lands on a tie can differ."""
+    x, w, scales, (te, tn), tm = _int8_tiles()
+    layer = jnp.asarray(1, jnp.int32)
+    got = moe_experts(x, *w, te, tn, layer, scales, tm=tm, interpret=True)
+    want = kref.moe_experts_ref(x, *w, te, tn, layer, scales, tm=tm)
+    got, want = np.asarray(got, np.float32), np.asarray(want, np.float32)
+    np.testing.assert_allclose(got, want, atol=1e-3 * np.abs(want).max())
+    assert not got[3 * tm:].any()                       # the empty tile
+
+
+@pytest.mark.parametrize("use_kernel", [False, True],
+                         ids=["gather", "kernel"])
+def test_int8_held_experts_near_bf16(use_kernel):
+    """The expert layer's int8 rung runs int8 weights through the grouped
+    call and stays within int8 rounding of the precise layer, on the layer
+    stack the scan hands it."""
+    cfg, params = model()
+    stack = params["groups"]["pos0"]["moe"]              # 2 layers
+    p = dict(jax.tree.map(lambda a: a[1], stack),
+             **{k: stack[k] for k in ("wi_gate", "wi_up", "wo")})
+    x = jax.random.normal(jax.random.PRNGKey(8), (24, cfg.d_model))
+    layer = jnp.asarray(1, jnp.int32)
+    kw = dict(layer=layer, use_kernel=use_kernel, interpret=use_kernel)
+    want, _, cw = moe.held_experts(p, x, cfg, **kw)
+    got, _, c8 = moe.held_experts(p, x, cfg, precision="int8", **kw)
+    err = np.abs(np.asarray(got - want)).max() / np.abs(np.asarray(want)).max()
+    assert 0 < err < 0.05, err
+    assert np.array_equal(np.asarray(c8), np.asarray(cw))

@@ -153,24 +153,34 @@ def test_paged_latent_attention_compiles(one_chip, kv_dtype, kv_scale):
     assert "paged_latent_decode" in hlo
 
 
-@pytest.mark.parametrize("tokens", [48, 128])
-def test_moe_experts_compiles(one_chip, tokens):
+@pytest.mark.parametrize("tokens,quant", [
+    pytest.param(48, False, id="48"), pytest.param(128, False, id="128"),
+    pytest.param(48, True, id="48-int8"),
+    pytest.param(128, True, id="128-int8")])
+def test_moe_experts_compiles(one_chip, tokens, quant):
     """A decode batch of 48 rows and a 128-token admission chunk, top-6 of
-    64 experts, through 16 held experts of width 1408."""
+    64 experts, through 16 held experts of width 1408; bf16, and the int8
+    rung's mode (one layer's int8 weights and channel scales)."""
     from repro.kernels.moe_experts import moe_experts
     from repro.models.moe import row_tile
     L, E, F, D, k = MOON["L"], 16, 1408, 2048, 6
     tm = row_tile(tokens, k, 64)
     n_tiles = min(tokens * k, (tokens * k + E * (tm - 1)) // tm)
+    if quant:
+        L, wdt = 1, jnp.int8
+        scales = (((L, E, 1, F), jnp.float32), ((L, E, 1, F), jnp.float32),
+                  ((L, E, 1, D), jnp.float32))
+    else:
+        wdt, scales = jnp.bfloat16, ()
 
-    def fn(x, wg, wu, wo, tile_e, tile_n, layer):
-        return moe_experts(x, wg, wu, wo, tile_e, tile_n, layer, tm=tm)
+    def fn(x, wg, wu, wo, tile_e, tile_n, layer, *s):
+        return moe_experts(x, wg, wu, wo, tile_e, tile_n, layer, s or None,
+                           tm=tm)
 
     hlo = _compile(fn, one_chip,
                    ((n_tiles * tm, D), jnp.bfloat16),
-                   ((L, E, F, D), jnp.bfloat16),
-                   ((L, E, F, D), jnp.bfloat16),
-                   ((L, E, F, D), jnp.bfloat16),
+                   ((L, E, F, D), wdt), ((L, E, F, D), wdt),
+                   ((L, E, F, D), wdt),
                    ((n_tiles,), jnp.int32), ((n_tiles,), jnp.int32),
-                   ((), jnp.int32))
+                   ((), jnp.int32), *scales)
     assert "moe_experts" in hlo
