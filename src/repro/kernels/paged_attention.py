@@ -30,7 +30,13 @@ scaled in VMEM, never round-tripping through an fp32 HBM buffer.
 A latent mode (``paged_latent_attention``, MLA's absorbed decode) reads a
 pool of one [latent | rotary key] entry per position: every query head
 attends over it as one KV head, and the value is the entry's leading
-latent columns of the same page block.
+latent columns of the same entries. It walks live pages only: the pool
+stays in HBM, and one kernel call runs through every slot's blocks of
+``128 // P`` pages up to the block holding its query position, copying
+each block's live pages into a double-buffered VMEM block itself (the
+next block's copies, the next slot's first among them, start before the
+current block is attended). Dead blocks cost no step, and dead pages of
+the last block are never fetched.
 """
 from __future__ import annotations
 
@@ -185,57 +191,114 @@ paged_attention = functools.partial(jax.jit, static_argnames=(
 
 # ---- latent mode (MLA): one KV "head" per token, its latent as the value
 
-def _latent_kernel(layer_ref, block_ref, pos_ref, q_ref, *refs, page: int,
-                   n_m: int, nb: int, dv: int, kv_scale: float,
+def _latent_kernel(layer_ref, block_ref, pos_ref, q_ref, cp_ref, kvp_ref,
+                   o_ref, c_buf, p_buf, m_ref, l_ref, acc_ref, sem, *,
+                   page: int, ppb: int, n_m: int, dv: int, kv_scale: float,
                    scale: float):
-    c_refs, ppos_refs = refs[:nb], refs[nb:2 * nb]
-    o_ref, m_ref, l_ref, acc_ref = refs[2 * nb:]
-    i = pl.program_id(0)
-    m = pl.program_id(1)
+    B = q_ref.shape[0]
+    bk = ppb * page                      # tokens of one compute block
+    n_blk = kvp_ref.shape[1]
+    layer = layer_ref[0]
 
-    @pl.when(m == 0)
-    def _init():
-        m_ref[...] = jnp.full_like(m_ref, NEG_INF)
-        l_ref[...] = jnp.zeros_like(l_ref)
-        acc_ref[...] = jnp.zeros_like(acc_ref)
+    def n_walk(b):
+        # blocks up to the one holding the query position; at least one,
+        # so every slot's output is written (a slot with no live page
+        # emits 0)
+        return jnp.clip(pos_ref[b] // bk + 1, 1, n_blk)
 
-    for j in range(nb):                 # static: the step's slots
-        b = i * nb + j
+    def copies(b, k, slot):
+        """Block k of slot b into buffer ``slot``: one copy per live page
+        (mapped and starting at or before the query position) with its
+        liveness, and the block's row of gathered positions."""
+        pages = []
+        for j in range(ppb):             # static: the block's pages
+            m = k * ppb + j
+            # a page past the table (m >= n_m) starts past every position,
+            # so it is dead; the clamp only keeps the table read in bounds
+            pid = block_ref[b, jnp.minimum(m, n_m - 1)]
+            live = (pid != 0) & (m * page <= pos_ref[b])
+            pages.append((live, pltpu.make_async_copy(
+                cp_ref.at[layer, pid], c_buf.at[slot, j], sem.at[slot])))
+        pos_row = pltpu.make_async_copy(kvp_ref.at[b, k], p_buf.at[slot],
+                                        sem.at[slot])
+        return pages, pos_row
+
+    def start(b, k, slot):
+        pages, pos_row = copies(b, k, slot)
+        pos_row.start()
+        for live, cp in pages:
+            @pl.when(live)
+            def _():
+                cp.start()
+
+    def wait(b, k, slot):
+        """Wait for block k of slot b; returns whether any page is live."""
+        pages, pos_row = copies(b, k, slot)
+        pos_row.wait()
+        any_live = False
+        for live, cp in pages:
+            @pl.when(live)
+            def _():
+                cp.wait()
+            any_live = any_live | live
+        return any_live
+
+    def attend(b, slot):
+        q = q_ref[b]                                     # (H, W)
+        c = c_buf[slot].astype(q.dtype)                  # (ppb, P, W)
+        if kv_scale:                                     # int8 pool
+            c = c * kv_scale
+        c = c.reshape(bk, c.shape[-1])
+        kv_pos = p_buf[slot]                             # (1, bk)
         pos = pos_ref[b]
+        valid = (kv_pos >= 0) & (kv_pos <= pos)
+        s = jax.lax.dot_general(
+            q, c, (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32) * scale
+        s = jnp.where(valid, s, NEG_INF)                 # (H, bk)
+        m_prev, l_prev = m_ref[...], l_ref[...]
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+        p = jnp.exp(s - m_new)
+        alpha = jnp.exp(m_prev - m_new)
+        l_ref[...] = l_prev * alpha + jnp.sum(p, -1, keepdims=True)
+        m_ref[...] = m_new
+        acc_ref[...] = acc_ref[...] * alpha + jax.lax.dot_general(
+            p.astype(q.dtype), c[:, :dv], (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)
 
-        @pl.when((block_ref[b, m] != 0) & (m * page <= pos))
-        def _body():
-            kv_pos = ppos_refs[j][0, 0]                  # (1, P)
-            valid = (kv_pos >= 0) & (kv_pos <= pos)
-            q = q_ref[j].astype(jnp.float32)             # (H, W)
-            c = c_refs[j][0, 0].astype(jnp.float32)      # (P, W)
-            if kv_scale:
-                c = c * kv_scale
-            s = jax.lax.dot_general(
-                q, c, (((1,), (1,)), ((), ())),
-                preferred_element_type=jnp.float32) * scale
-            s = jnp.where(valid, s, NEG_INF)             # (H, P)
-            m_prev, l_prev = m_ref[j], l_ref[j]
-            m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
-            p = jnp.exp(s - m_new)
-            alpha = jnp.exp(m_prev - m_new)
-            l_ref[j] = l_prev * alpha + jnp.sum(p, -1, keepdims=True)
-            m_ref[j] = m_new
-            acc_ref[j] = acc_ref[j] * alpha + jax.lax.dot_general(
-                p, c[:, :dv], (((1,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32)
+    # a masked entry weighs exactly 0, and so does a buffer row no copy
+    # filled: it holds an earlier block's entry or this zero, never NaN
+    c_buf[...] = jnp.zeros_like(c_buf)
+    start(0, 0, 0)
 
-    @pl.when(m == n_m - 1)
-    def _finish():
-        l = jnp.maximum(l_ref[...], 1e-30)
-        o_ref[...] = (acc_ref[...] / l).astype(o_ref.dtype)
+    def step(_, carry):
+        b, k, slot = carry
+        last = k + 1 == n_walk(b)
+        nb = jnp.where(last, b + 1, b)
+        nk = jnp.where(last, 0, k + 1)
 
+        @pl.when(nb < B)                 # the next block, across slots too
+        def _():
+            start(nb, nk, 1 - slot)
 
-def latent_slots_per_step(batch: int) -> int:
-    """Slots one grid step of the latent kernel serves: the most of 8, 4,
-    2, 1 that divides the batch. Each grid step costs a fixed overhead
-    even where its pages are dead, so fewer, wider steps pay it less."""
-    return next(n for n in (8, 4, 2, 1) if batch % n == 0)
+        @pl.when(k == 0)
+        def _():
+            m_ref[...] = jnp.full_like(m_ref, NEG_INF)
+            l_ref[...] = jnp.zeros_like(l_ref)
+            acc_ref[...] = jnp.zeros_like(acc_ref)
+
+        @pl.when(wait(b, k, slot))
+        def _():
+            attend(b, slot)
+
+        @pl.when(last)
+        def _():
+            l = jnp.maximum(l_ref[...], 1e-30)
+            o_ref[b] = (acc_ref[...] / l).astype(o_ref.dtype)
+        return nb, nk, 1 - slot
+
+    n_steps = jax.lax.fori_loop(0, B, lambda b, n: n + n_walk(b), 0)
+    jax.lax.fori_loop(0, n_steps, step, (0, 0, 0))
 
 
 def paged_latent_attention_impl(q, cp, ppos, block, position, layer, *,
@@ -251,50 +314,66 @@ def paged_latent_attention_impl(q, cp, ppos, block, position, layer, *,
     copied for it (int8 when ``kv_scale``); ppos: (L, n_pages, P); block:
     (B, M) the layer's block table; position: (B,). Every head attends
     over the same one entry per position, and the value is that entry's
-    first ``dv`` columns, so each page is read once for all heads. The grid
-    is (B / nb, M): a step streams page m of each of its ``nb`` slots
-    (``latent_slots_per_step``), one operand per slot. Returns (B, H, dv)
-    latent outputs in q.dtype (the caller applies ``W_UV``)."""
+    first ``dv`` columns, so each page is read once for all heads.
+    Returns (B, H, dv) latent outputs in q.dtype (the caller applies
+    ``W_UV``).
+
+    One kernel call walks every slot's live blocks in turn. A block is
+    ``ppb = 128 // P`` pages (at least one): the kernel copies a block's
+    live pages (mapped, starting at or before the query position) from the
+    pool, which stays in HBM, into a VMEM buffer, and starts the next
+    block's copies — the next slot's first block after a slot's last —
+    before it attends over the current one. A slot's walk ends at the
+    block holding its query position, so dead blocks cost nothing and
+    dead pages of the last block are never fetched. The entries' positions
+    come gathered per block table, -1 where a page is unmapped or past the
+    query: the kernel masks by them exactly as ``_latent_attend`` masks
+    (``block != 0``, ``0 <= ppos <= position``)."""
     B, H, W = q.shape
-    L, n_pages, P = ppos.shape
+    P = ppos.shape[-1]
     M = block.shape[1]
-    nb = latent_slots_per_step(B)
-
-    def _map(j):
-        def c_map(i, m, l_ref, block_ref, pos_ref):
-            b = i * nb + j
-            pid = jnp.where(m * P > pos_ref[b], 0, block_ref[b, m])
-            return (l_ref[0], pid, 0, 0)
-        return c_map
-
-    qo_map = lambda i, m, *_: (i, 0, 0)
+    ppb = max(1, 128 // P)
+    n_blk = -(-M // ppb)
+    block = block.astype(jnp.int32)
+    position = position.astype(jnp.int32)
+    # a kernel copy moves whole 128-lane tiles and a page's ppos row is 16
+    # lanes, so the positions travel gathered, one (1, block) row a block
+    start = jnp.arange(M, dtype=jnp.int32) * P
+    live = (block != 0) & (start[None] <= position[:, None])
+    kv_pos = jnp.where(live[..., None], jnp.take(ppos[layer], block, axis=0),
+                       -1)
+    kv_pos = jnp.pad(kv_pos.reshape(B, M * P),
+                     ((0, 0), (0, n_blk * ppb * P - M * P)),
+                     constant_values=-1).reshape(B, n_blk, 1, ppb * P)
+    whole = lambda i, *_: (0, 0, 0)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3,
-        grid=(B // nb, M),
-        in_specs=([pl.BlockSpec((nb, H, W), qo_map)]
-                  + [pl.BlockSpec((1, 1, P, W), _map(j)) for j in range(nb)]
-                  + [pl.BlockSpec((1, 1, 1, P), _map(j))
-                     for j in range(nb)]),
-        out_specs=pl.BlockSpec((nb, H, dv), qo_map),
+        grid=(1,),
+        in_specs=[pl.BlockSpec((B, H, W), whole),
+                  pl.BlockSpec(memory_space=pl.ANY),
+                  pl.BlockSpec(memory_space=pl.ANY)],
+        out_specs=pl.BlockSpec((B, H, dv), whole),
         scratch_shapes=[
-            pltpu.VMEM((nb, H, 1), jnp.float32),
-            pltpu.VMEM((nb, H, 1), jnp.float32),
-            pltpu.VMEM((nb, H, dv), jnp.float32),
+            pltpu.VMEM((2, ppb, P, W), cp.dtype),
+            pltpu.VMEM((2, 1, ppb * P), jnp.int32),
+            pltpu.VMEM((H, 1), jnp.float32),
+            pltpu.VMEM((H, 1), jnp.float32),
+            pltpu.VMEM((H, dv), jnp.float32),
+            pltpu.SemaphoreType.DMA((2,)),
         ],
     )
-    kernel = functools.partial(_latent_kernel, page=P, n_m=M, nb=nb, dv=dv,
-                               kv_scale=kv_scale, scale=scale)
-    ppos4 = ppos.reshape(L, n_pages, 1, P)
+    kernel = functools.partial(_latent_kernel, page=P, ppb=ppb, n_m=M,
+                               dv=dv, kv_scale=kv_scale, scale=scale)
     return pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, H, dv), q.dtype),
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "arbitrary")),
+            dimension_semantics=("arbitrary",)),
         interpret=interpret,
         name="paged_latent_decode",
-    )(jnp.reshape(layer, (1,)).astype(jnp.int32), block.astype(jnp.int32),
-      position.astype(jnp.int32), q, *([cp] * nb), *([ppos4] * nb))
+    )(jnp.reshape(layer, (1,)).astype(jnp.int32), block, position, q, cp,
+      kv_pos)
 
 
 paged_latent_attention = functools.partial(jax.jit, static_argnames=(

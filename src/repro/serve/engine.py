@@ -1481,6 +1481,14 @@ class ServeEngine:
         self._fresh[exe] = ("decode_megastep", k)
         return exe
 
+    def _live_pages(self, rows) -> int:
+        """Pages a decode over ``rows`` attends over: ceil((position + 1)
+        / page) a row, from the host's positions (the ``live_pages`` of a
+        ``decode.dispatch`` span; with ``rows`` and the block table's width
+        it gives the live share of the pool the kernel walks)."""
+        return sum(int(self.positions[i]) // self.page_size + 1
+                   for i in rows)
+
     def _dispatch_megastep(self) -> Optional[dict]:
         """Dispatch ONE fused K-step decode over the live slots without
         waiting on it (async pipeline): pre-map every page the in-scan
@@ -1563,7 +1571,8 @@ class ServeEngine:
         self._inject_slots.clear()
         self.decode_dispatches += 1
         if sid:
-            self.spans.close(sid, k=k, rows=len(rows))
+            self.spans.close(sid, k=k, rows=len(rows),
+                             live_pages=self._live_pages(rows))
         # the admission chunks dispatched before this megastep have run by
         # its drain: their counters are read there without a wait
         counts = [counts] + self._chunk_counts if self.cfg.moe is not None \
@@ -1705,8 +1714,9 @@ class ServeEngine:
                 self.pool.replenish()     # keep headroom between steps
             self._control_tick()       # flush TTFT samples of 1-token admits
             return
-        sid = self.spans.on and self.spans.open("decode.dispatch", k=1,
-                                                 rows=len(rows))
+        sid = self.spans.on and self.spans.open(
+            "decode.dispatch", k=1, rows=len(rows),
+            live_pages=self._live_pages(rows))
         if self.paged:
             # map each live slot's write page before the step scatters to it
             # (live growth bypasses the reclaim limit — see serve.pages).

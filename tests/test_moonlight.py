@@ -160,6 +160,97 @@ def test_absorbed_decode_equals_expanded(use_kernel):
                                atol=1e-5, rtol=1e-5)
 
 
+# the latent kernel's walk at its edges: 16-token pages make 128-token
+# blocks of 8 pages; 40 pages a slot (5 blocks, max_len 640), or 37 (the
+# last block short of its 8 pages)
+WP = 16
+WBK = 128              # tokens of a block: 128 // WP pages
+INACTIVE = -1          # an inactive row: all-null block row, stale position
+
+
+def _edges(M):
+    return [0,                   # first position
+            WP - 1, WP,          # on and one past a page boundary
+            WBK - 1, WBK,        # on and one past a block boundary
+            3 * WBK + 7,         # live pages over four blocks
+            WP * M - 1,          # the slot fills max_len
+            INACTIVE]
+
+
+def _latent_pool(B, M, pool_dtype, rng, *, H=4, W=64, L=2):
+    """A two-layer latent pool, (B, M) block tables and positions covering
+    ``_edges(M)`` (then random positions). Each slot maps the pages up to its
+    position plus one reserved page past it (no entries yet); some slots
+    share the first pages of slot 5 (a prefix hit). The null page and the
+    unmapped pages hold finite entries with positions that the walk must
+    ignore."""
+    n_pages = B * (M + 1) + 1
+    edges = _edges(M)
+    pos = [edges[i] if i < len(edges) else int(rng.integers(0, WP * M))
+           for i in range(B)]
+    ppos = np.full((L, n_pages, WP), -1, np.int32)
+    ppos[:, 0] = np.arange(WP)                     # null page: stale rows
+    ppos[:, -1] = np.arange(WP)                    # an unmapped page too
+    block = np.zeros((B, M), np.int32)
+    free = iter(rng.permutation(np.arange(1, n_pages - 1)))
+    for b, p in enumerate(pos):
+        if p == INACTIVE:
+            pos[b] = 2 * WBK + 3
+            continue
+        for m in range(min(p // WP + 2, M)):
+            if b > len(edges) and b % 3 == 0 and m < 2 and pos[5] >= 2 * WP:
+                block[b, m] = block[5, m]          # shared prefix page
+                continue
+            block[b, m] = next(free)
+            t = m * WP + np.arange(WP)
+            ppos[:, block[b, m]] = np.where(t <= p, t, -1)
+    if pool_dtype == jnp.int8:
+        cp = rng.integers(-127, 128, (L, n_pages, WP, W)).astype(np.int8)
+    else:
+        cp = rng.normal(size=(L, n_pages, WP, W)).astype(np.float32)
+    q = rng.normal(size=(B, H, W)).astype(np.float32)
+    return (jnp.asarray(q), jnp.asarray(cp, pool_dtype), jnp.asarray(ppos),
+            jnp.asarray(block), jnp.asarray(pos, jnp.int32))
+
+
+@pytest.mark.parametrize("B,M,q_dtype,pool_dtype,kv_scale,tol", [
+    pytest.param(48, 40, jnp.float32, jnp.float32, 0.0, 1e-5, id="f32-B48"),
+    pytest.param(13, 40, jnp.float32, jnp.float32, 0.0, 1e-5, id="f32-B13"),
+    pytest.param(13, 37, jnp.float32, jnp.float32, 0.0, 1e-5,
+                 id="f32-B13-M37"),
+    pytest.param(13, 40, jnp.bfloat16, jnp.bfloat16, 0.0, 2 ** -6,
+                 id="bf16-B13"),
+    pytest.param(13, 40, jnp.float32, jnp.int8, 0.05, 1e-5, id="int8-B13"),
+    pytest.param(13, 40, jnp.bfloat16, jnp.int8, 0.05, 2 ** -6,
+                 id="int8-bf16-B13"),
+])
+def test_latent_kernel_walk_equals_gather(B, M, q_dtype, pool_dtype,
+                                          kv_scale, tol):
+    """The latent kernel (interpret) gives what the gather path
+    ``_latent_attend`` gives, slot by slot, at the walk's edges: position
+    0, page and block boundaries, live pages over several blocks, a slot
+    that fills max_len, shared prefix pages, a batch that 8 does not
+    divide and a block table that blocks do not divide, in float32, bf16
+    and the int8 pool. The inactive row attends
+    over nothing and emits zeros."""
+    from repro.kernels.paged_attention import paged_latent_attention
+    rng = np.random.default_rng(B + len(str(pool_dtype)))
+    q, cp, ppos, block, pos = _latent_pool(B, M, pool_dtype, rng)
+    q = q.astype(q_dtype)
+    layer = jnp.asarray(1, jnp.int32)
+    kw = dict(dv=48, scale=64 ** -0.5, kv_scale=kv_scale)
+    got = paged_latent_attention(q, cp, ppos, block, pos, layer,
+                                 interpret=True, **kw)
+    cache = attention.PagedLatentCache(cp=cp, ppos=ppos, block=None)
+    want = attention._latent_attend(q[:, None], cache, layer, block,
+                                    pos[:, None], **kw)[:, 0]
+    got, want = np.asarray(got, np.float32), np.asarray(want, np.float32)
+    idle = _edges(M).index(INACTIVE)
+    assert not got[idle].any()
+    live = np.arange(B) != idle
+    np.testing.assert_allclose(got[live], want[live], atol=tol, rtol=tol)
+
+
 def test_held_shares_sum_to_uncut_layer():
     """The two chips' shares of the expert layer (experts 0-3 and 4-7),
     with the shared expert that both compute counted once, add up to the

@@ -96,6 +96,29 @@ def test_prefill_chunks_carry_their_request(megastep_k):
 
 
 @pytest.mark.parametrize("megastep_k", [0, 4])
+def test_decode_dispatch_counts_live_pages(megastep_k):
+    """Each ``decode.dispatch`` carries ``live_pages``: ceil(length /
+    page) summed over the decoding rows, where a row's length is its prompt
+    and the tokens it has drained (the position of the token it feeds,
+    plus one)."""
+    eng = engine(megastep_k)
+    eng.spans.enable()
+    want, open_span = [], eng.spans.open
+
+    def open_noting_lengths(name, **attrs):
+        if name == "decode.dispatch":
+            want.append(sum(-(-(len(r.prompt) + len(r.out)) // eng.page_size)
+                            for r in eng.slots if r is not None))
+        return open_span(name, **attrs)
+
+    eng.spans.open = open_noting_lengths
+    serve(eng)
+    spans = [s for s in eng.spans.take() if s.name == "decode.dispatch"]
+    assert [s.attrs["live_pages"] for s in spans] == want
+    assert any(s.attrs["live_pages"] > s.attrs["rows"] for s in spans)
+
+
+@pytest.mark.parametrize("megastep_k", [0, 4])
 def test_program_obtain_spans_match_the_counter(megastep_k):
     eng, _, spans = traced(megastep_k)
     got = [s for s in spans if s.name == "program.obtain"]
