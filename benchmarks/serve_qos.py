@@ -116,8 +116,7 @@ def main(rows: Rows):
     runtime = PliantRuntime(ptable, monitor,
                             ControllerConfig(decision_interval_s=0.0))
     eng = ServeEngine(cfg, batch_slots=SLOTS, max_len=MAX_LEN, params=params,
-                      runtime=runtime, paged=True, page_size=4,
-                      sync_timing=True)
+                      runtime=runtime, page_size=4, sync_timing=True)
     stats = _drive(eng, cfg, np.random.default_rng(3),
                    shared_prefix=PROMPT - 2)
     s = eng.pool.stats
@@ -133,59 +132,41 @@ def main(rows: Rows):
              f"tok_s={stats['tok_s']:.1f};"
              f"hit_rate={stats['prefix_hit_rate']:.2f};"
              f"reclaims={stats['reclaim_events']}")
-    # dense vs paged vs megastep at EQUAL batch — the ROADMAP "close the
-    # paged gap" acceptance metric, on the paged engine's target workload:
-    # a shared system prompt (16-token prompts, 12 shared) with short
-    # completions. Each engine runs the same trace twice: a warm-up pass
-    # (compiles; paged prefix registration — the steady state a
-    # long-running server sits in) and a measured pass with fresh
-    # counters. All three run sync_timing (drain before stamping) so the
-    # latency numbers measure compute, not async dispatch enqueue. CI
-    # asserts paged tok/s >= dense, megastep tok/s >= paged, queue-wait
-    # p95 within 1.25x, and megastep dispatches/token < 1.
-    comparison = {}
+    # the megastep engine (K=4) on the paged pool's target workload: a
+    # shared system prompt (16-token prompts, 12 shared) with short
+    # completions. It runs the same trace twice: a warm-up pass (compiles;
+    # prefix registration — the steady state a long-running server sits
+    # in) and a measured pass with fresh counters, under sync_timing (drain
+    # before stamping) so the latency numbers measure compute, not async
+    # dispatch enqueue. CI asserts counts only (dispatches/token < 1, the
+    # host share in [0, 1]); speed is the chip benchmark's (bench/).
     cmp_trace = dict(shared_prefix=12, prompt_len=16, max_new=6)
-    for name, ekw in (("dense", dict(paged=False)),
-                      ("paged", dict(paged=True)),
-                      ("megastep", dict(paged=True, megastep_k=4))):
-        eng = ServeEngine(cfg, batch_slots=SLOTS, max_len=MAX_LEN,
-                          params=params, page_size=4, sync_timing=True,
-                          **ekw)
-        _drive(eng, cfg, np.random.default_rng(5), **cmp_trace)
-        eng.step_latencies.clear()
-        eng.step_admission_chunks.clear()
-        eng.decode_dispatches = eng.row_dispatches = eng.row_tokens = 0
-        eng.drain_block_s = 0.0
-        st = _drive(eng, cfg, np.random.default_rng(5), **cmp_trace)
-        st["mesh_shape"] = dict(eng.mesh.shape) if eng.mesh is not None \
-            else None
-        st["sharded_kernel"] = eng.sharded_kernel
-        st["decode_dispatches"] = eng.decode_dispatches
-        st["dispatches_per_token"] = (eng.row_dispatches
-                                      / max(eng.row_tokens, 1))
-        # fraction of the wall the host spent NOT blocked on device
-        # transfers — the megastep pipeline's target metric
-        st["host_overhead_frac"] = max(
-            0.0, 1.0 - eng.drain_block_s / max(st["wall_s"], 1e-9))
-        if eng.paged:
-            s = eng.pool.stats
-            st["pool_occupancy_peak"] = s["peak_used"] / eng.pool.spec.usable
-            st["grouped_pages"] = s["grouped_pages"]
-            st["grouped_fallbacks"] = s["grouped_fallbacks"]
-            st["admission_chunks_max"] = max(
-                (c for c, _ in eng.step_admission_chunks), default=0)
-        comparison[name] = st
-    out["comparison"] = comparison
-    ratio = comparison["paged"]["tok_s"] / max(comparison["dense"]["tok_s"],
-                                               1e-9)
-    rows.add("serve.paged_vs_dense", ratio,
-             f"dense={comparison['dense']['tok_s']:.1f};"
-             f"paged={comparison['paged']['tok_s']:.1f};"
-             f"qw_dense_ms={comparison['dense']['queue_wait_p95_ms']:.1f};"
-             f"qw_paged_ms={comparison['paged']['queue_wait_p95_ms']:.1f}")
-    mega = comparison["megastep"]
-    rows.add("serve.megastep_vs_paged",
-             mega["tok_s"] / max(comparison["paged"]["tok_s"], 1e-9),
+    eng = ServeEngine(cfg, batch_slots=SLOTS, max_len=MAX_LEN,
+                      params=params, page_size=4, sync_timing=True,
+                      megastep_k=4)
+    _drive(eng, cfg, np.random.default_rng(5), **cmp_trace)
+    eng.step_latencies.clear()
+    eng.step_admission_chunks.clear()
+    eng.decode_dispatches = eng.row_dispatches = eng.row_tokens = 0
+    eng.drain_block_s = 0.0
+    mega = _drive(eng, cfg, np.random.default_rng(5), **cmp_trace)
+    mega["mesh_shape"] = dict(eng.mesh.shape) if eng.mesh is not None \
+        else None
+    mega["sharded_kernel"] = eng.sharded_kernel
+    mega["decode_dispatches"] = eng.decode_dispatches
+    mega["dispatches_per_token"] = (eng.row_dispatches
+                                    / max(eng.row_tokens, 1))
+    # fraction of the wall the host spent NOT blocked on device
+    # transfers — the megastep pipeline's target metric
+    mega["host_overhead_frac"] = max(
+        0.0, 1.0 - eng.drain_block_s / max(mega["wall_s"], 1e-9))
+    s = eng.pool.stats
+    mega["pool_occupancy_peak"] = s["peak_used"] / eng.pool.spec.usable
+    mega["grouped_pages"] = s["grouped_pages"]
+    mega["admission_chunks_max"] = max(
+        (c for c, _ in eng.step_admission_chunks), default=0)
+    out["comparison"] = {"megastep": mega}
+    rows.add("serve.megastep", 1e3 * mega["p95_ms"],
              f"tok_s={mega['tok_s']:.1f};"
              f"dispatches_per_token={mega['dispatches_per_token']:.2f};"
              f"host_overhead_frac={mega['host_overhead_frac']:.2f}")
@@ -196,7 +177,7 @@ def main(rows: Rows):
     admission = {"1x1": {
         "mesh_shape": None,
         "prefill_dispatch": eng.explain_prefill_dispatch(),
-        "admit_compute_p95_ms": comparison["paged"]["admit_compute_p95_ms"],
+        "admit_compute_p95_ms": mega["admit_compute_p95_ms"],
     }}
     env = dict(os.environ, JAX_PLATFORMS="cpu",
                XLA_FLAGS="--xla_force_host_platform_device_count=8")
@@ -246,7 +227,7 @@ cfg = get_config("gemma2-27b-smoke")
 params = api.init(cfg, jax.random.PRNGKey(0), jnp.float32)
 mesh = make_mesh((2, 4), ("data", "model"))
 eng = ServeEngine(cfg, batch_slots=4, max_len=32, params=params, mesh=mesh,
-                  paged=True, page_size=4, prefill_chunk=8,
+                  page_size=4, prefill_chunk=8,
                   use_kernel=True, kernel_interpret=True)
 rng = np.random.default_rng(0)
 reqs = [Request(i, prompt=list(rng.integers(1, cfg.vocab_size, 6)),
@@ -283,7 +264,7 @@ prompts = [list(rng.integers(1, cfg.vocab_size, 6)) for _ in range(8)]
 def run(script):
     mesh = make_mesh((2, 4), ("data", "model"))
     eng = ServeEngine(cfg, batch_slots=4, max_len=32, params=params,
-                      mesh=mesh, paged=True, page_size=4, prefill_chunk=8,
+                      mesh=mesh, page_size=4, prefill_chunk=8,
                       use_kernel=True, kernel_interpret=True)
     reqs = [Request(i, prompt=list(p), max_new=8)
             for i, p in enumerate(prompts)]

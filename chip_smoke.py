@@ -6,7 +6,7 @@
 One chip: ``launch.serve.serve`` builds and drives exactly what
 ``python -m repro.launch.serve`` would: phi4-mini-3.8b at its published
 widths and all 32 layers, bf16 params and pages, 8 requests on 8 slots,
-``--paged --megastep 8`` at page size 16. Partway through, the engine is
+``--megastep 8`` at page size 16. Partway through, the engine is
 forced down to the int8 rung (int8 matmuls + int8 KV pages). Checks:
 
 * every request finishes; the attention audit saw the fused paged kernel
@@ -20,11 +20,11 @@ forced down to the int8 rung (int8 matmuls + int8 KV pages). Checks:
 Four chips: the same requests served by a paged engine on a (data=4,
 model=1) mesh, which runs the shard_map'd decode kernel and the ring
 prefill, must give the same greedy tokens as one chip; the mesh run also
-revokes and restores two devices (elastic re-home with background
-precompile). This comparison runs in float32 with ``highest`` matmul
-precision at 8 of the 32 layers (float32 at full depth does not fit one
-chip): ring and single-device prefill reduce in different orders, and in
-bf16 that noise flips near-tied argmaxes of random-weight logits.
+revokes and restores two devices (elastic re-home). This comparison runs
+in float32 with ``highest`` matmul precision at 8 of the 32 layers
+(float32 at full depth does not fit one chip): ring and single-device
+prefill reduce in different orders, and in bf16 that noise flips
+near-tied argmaxes of random-weight logits.
 
 Every phase must pass; the script exits nonzero otherwise and prints the
 result JSON only as its last line on success. It needs a TPU: with none
@@ -102,7 +102,7 @@ def rel_rms(a, b) -> float:
 
 
 def serve_argv(extra=()) -> list:
-    return ["--arch", ARCH, "--paged", "--megastep", str(MEGASTEP),
+    return ["--arch", ARCH, "--megastep", str(MEGASTEP),
             "--page-size", str(PAGE), "--slots", str(SLOTS),
             "--requests", str(REQUESTS), "--max-len", str(MAX_LEN),
             "--prompt-len", str(PROMPT), "--prefill-chunk", str(CHUNK),
@@ -259,7 +259,6 @@ def four_chips(log: CompileLog) -> None:
     kinds = [e.get("kind") for e in eng.elastic_log]
     print(f"elastic log kinds: {kinds}; rehomes={eng.stats['rehomes']}")
     check(eng.stats["rehomes"] >= 2, "revoke and restore both re-homed")
-    check("precompile_failed" not in kinds, "no precompile failed")
     print(f"XLA compiles={log.n} compile_s={log.s:.1f}", flush=True)
 
 

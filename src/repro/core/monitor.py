@@ -75,7 +75,7 @@ class LatencyMonitor:
         so each row that emitted ``n > 0`` tokens contributes ``n`` samples
         of ``wall_s / n`` — total mass per row equals the wall time the
         client actually experienced, and the estimator keeps seeing
-        per-token latencies comparable with the per-step engine's."""
+        per-token latencies comparable with a one-token dispatch's."""
         lat = []
         for n in tokens_per_row:
             n = int(n)

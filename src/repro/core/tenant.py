@@ -170,11 +170,10 @@ class TrainTenant(Tenant):
 
 @dataclass
 class ServeTenant(Tenant):
-    """Paged ``ServeEngine`` adapter. Variant swaps go through
+    """``ServeEngine`` adapter. Variant swaps go through
     ``engine.request_variant`` (applied at the next SAFE step boundary — a
     mid-admission swap would mix prefill executables within one request);
-    quanta are ``PagePool`` pages via ``set_reclaimed``. Dense engines have
-    no reclaimable pool, so their budget is 0 (variant knob only)."""
+    quanta are ``PagePool`` pages via ``set_reclaimed``."""
     engine: Any = None
     name: str = "serve"
     table: VariantTable = field(init=False)
@@ -185,10 +184,9 @@ class ServeTenant(Tenant):
 
     def __post_init__(self):
         self.table = self.engine.table
-        pool = getattr(self.engine, "pool", None)
-        self.max_reclaim = pool.max_quanta if pool is not None else 0
-        self.n_quanta = (max(pool.spec.usable // max(pool.quantum, 1), 1)
-                         if pool is not None else 1)
+        pool = self.engine.pool
+        self.max_reclaim = pool.max_quanta
+        self.n_quanta = max(pool.spec.usable // max(pool.quantum, 1), 1)
         self._variant = self.engine.active_variant
 
     @property
@@ -200,8 +198,7 @@ class ServeTenant(Tenant):
         self.engine.request_variant(idx)
 
     def _on_reclaimed(self, total: int) -> None:
-        if self.engine.pool is not None:
-            self.engine.pool.set_reclaimed(total)
+        self.engine.pool.set_reclaimed(total)
         super()._on_reclaimed(total)     # honor a late-bound actuator too
 
     def on_capacity(self, ev) -> None:
@@ -211,17 +208,15 @@ class ServeTenant(Tenant):
 
     def pressure(self, t: float = 0.0,
                  variant: Optional[int] = None) -> ResourcePressure:
-        """Roofline pressure of the (target) serving variant; for paged
-        engines the HBM term scales with live-page occupancy — the fused
-        decode kernel streams mapped pages, not ``slots x max_len`` rings
-        (DESIGN.md §10), so a half-empty pool exerts half the KV traffic."""
+        """Roofline pressure of the (target) serving variant; the HBM term
+        scales with live-page occupancy — the fused decode kernel streams
+        mapped pages, not ``slots x max_len`` rings (DESIGN.md §10), so a
+        half-empty pool exerts half the KV traffic."""
         v = self.variant if variant is None else variant
         p = self.table.variants[v].pressure
-        pool = self.engine.pool
-        if pool is not None:
-            p = ResourcePressure(hbm=p.hbm * max(pool.occupancy(), 0.05),
-                                 ici=p.ici, flops=p.flops)
-        return p
+        return ResourcePressure(
+            hbm=p.hbm * max(self.engine.pool.occupancy(), 0.05),
+            ici=p.ici, flops=p.flops)
 
 
 @dataclass

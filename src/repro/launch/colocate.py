@@ -86,7 +86,7 @@ def main(argv=None) -> int:
         page_occupancy=min(1.0, (args.prompt_len + args.max_new)
                            / args.max_len))
     eng = ServeEngine(scfg, batch_slots=args.slots, max_len=args.max_len,
-                      params=sparams, table=stable, paged=True,
+                      params=sparams, table=stable,
                       page_size=args.page_size, seed=args.seed)
     serve_tenant = ServeTenant(engine=eng, name="serve")
 

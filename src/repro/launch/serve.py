@@ -95,20 +95,17 @@ def parser() -> argparse.ArgumentParser:
     p.add_argument("--temperature", type=float, default=0.0)
     p.add_argument("--mesh", default="",
                    help="serve sharded, e.g. 2x4 -> (data=2, model=4)")
-    p.add_argument("--paged", action="store_true",
-                   help="paged page-pool caches with prefix reuse and the "
-                        "pool_pages Pliant knob (default: dense rings)")
     p.add_argument("--page-size", type=int, default=8)
     p.add_argument("--pool-pages", type=int, default=0,
                    help="physical pages (0 = auto-size)")
     p.add_argument("--shared-prefix", type=int, default=0,
                    help="first N prompt tokens identical across requests "
-                        "(exercises the prefix cache under --paged)")
-    p.add_argument("--megastep", type=int, default=0,
+                        "(exercises the prefix cache)")
+    p.add_argument("--megastep", type=int, default=1,
                    help="fuse up to K decode steps per dispatch (lax.scan "
                         "megastep: on-device sampling + EOS/budget stop "
-                        "masking, async double-buffered host loop); paged "
-                        "only, 0 = one dispatch per token")
+                        "masking, async double-buffered host loop); "
+                        "1 = one dispatch per token")
     p.add_argument("--eos-id", type=int, default=-1,
                    help="stop-token id; a request emitting it finishes "
                         "early (-1 = generate max-new tokens)")
@@ -157,13 +154,12 @@ def serve(argv=None, *, on_step: Optional[Callable] = None) -> Served:
         cfg = dataclasses.replace(cfg, n_layers=args.layers)
     dtype = jnp.dtype(args.dtype)
     params = api.init(cfg, jax.random.PRNGKey(args.seed), dtype)
-    occupancy = (min(1.0, (args.prompt_len + args.max_new) / args.max_len)
-                 if args.paged else None)
+    occupancy = min(1.0, (args.prompt_len + args.max_new) / args.max_len)
     dev = jax.devices()[0]
     target = dev.device_kind if dev.platform == "tpu" else DEFAULT_TARGET
     table = serving_table(cfg, slots=args.slots, max_len=args.max_len,
                           page_occupancy=occupancy,
-                          price_from_compile=args.paged, dtype=dtype,
+                          price_from_compile=True, dtype=dtype,
                           target=target)
     names = [v.name for v in table.variants]
 
@@ -187,8 +183,7 @@ def serve(argv=None, *, on_step: Optional[Callable] = None) -> Served:
                       params=params, table=table, runtime=runtime,
                       temperature=args.temperature, mesh=mesh,
                       prefill_chunk=args.prefill_chunk, seed=args.seed,
-                      cache_dtype=dtype,
-                      paged=args.paged, page_size=args.page_size,
+                      cache_dtype=dtype, page_size=args.page_size,
                       n_pages=args.pool_pages,
                       max_admission_chunks=args.max_admission_chunks,
                       qos_guard=args.qos_guard,
@@ -275,27 +270,25 @@ def serve(argv=None, *, on_step: Optional[Callable] = None) -> Served:
           f"p95={1e3 * pct[95]:.1f} p99={1e3 * pct[99]:.1f}  "
           f"ttft p95={1e3 * ttft95:.1f}  queue-wait p95={1e3 * q95:.1f}  "
           f"admit-compute p95={1e3 * a95:.1f}")
-    if args.paged:
-        s = eng.pool.stats
-        looks = s["prefix_hits"] + s["prefix_misses"]
-        chunks = [c for c, _ in eng.step_admission_chunks]
-        print(f"paged: pages={eng.pool.spec.n_pages} "
-              f"occupancy={eng.pool.occupancy():.2f} "
-              f"peak_used={s['peak_used']} "
-              f"prefix_hit_rate={s['prefix_hits'] / max(looks, 1):.2f} "
-              f"tokens_skipped={s['tokens_skipped']} "
-              f"reclaim_events={s['reclaim_events']}")
-        print(f"admission: grouped_pages={s['grouped_pages']} "
-              f"blocked={s['blocked_admissions']} "
-              f"replenish_evictions={s['replenish_evictions']} "
-              f"chunks/step max={max(chunks, default=0)} "
-              f"budget_cap={args.max_admission_chunks}")
-    if args.megastep:
-        d_t = eng.row_dispatches / max(eng.row_tokens, 1)
-        print(f"megastep: k={args.megastep} "
-              f"decode_dispatches={eng.decode_dispatches} "
-              f"dispatches/token={d_t:.2f} "
-              f"drain_block_s={eng.drain_block_s:.3f}")
+    s = eng.pool.stats
+    looks = s["prefix_hits"] + s["prefix_misses"]
+    chunks = [c for c, _ in eng.step_admission_chunks]
+    print(f"paged: pages={eng.pool.spec.n_pages} "
+          f"occupancy={eng.pool.occupancy():.2f} "
+          f"peak_used={s['peak_used']} "
+          f"prefix_hit_rate={s['prefix_hits'] / max(looks, 1):.2f} "
+          f"tokens_skipped={s['tokens_skipped']} "
+          f"reclaim_events={s['reclaim_events']}")
+    print(f"admission: grouped_pages={s['grouped_pages']} "
+          f"blocked={s['blocked_admissions']} "
+          f"replenish_evictions={s['replenish_evictions']} "
+          f"chunks/step max={max(chunks, default=0)} "
+          f"budget_cap={args.max_admission_chunks}")
+    d_t = eng.row_dispatches / max(eng.row_tokens, 1)
+    print(f"megastep: k={args.megastep} "
+          f"decode_dispatches={eng.decode_dispatches} "
+          f"dispatches/token={d_t:.2f} "
+          f"drain_block_s={eng.drain_block_s:.3f}")
     if args.qos_target > 0:
         acts = [h["action"] for h in runtime.history if h["action"] != "hold"]
         print(f"qos: target={1e3 * args.qos_target:.1f}ms "

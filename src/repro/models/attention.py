@@ -636,8 +636,9 @@ def paged_chunk_attention(params, x, positions, cache: PagedKVCache,
     B, C, D = x.shape
     hd = cfg.resolved_head_dim
     G, R = cfg.n_kv_heads, cfg.n_heads // cfg.n_kv_heads
-    # gather chunk Q/K/V before rope (0.4.x TP-sharded head_dim hazard,
-    # see chunk_decode_attention)
+    # gather the chunk Q/K/V before rope: the 0.4.x partitioner miscompiles
+    # split+concat over a TP-sharded head_dim (wrong values, not just slow);
+    # these are only a few tokens wide, so the gather is cheap
     q = constrain_replicated(_split_heads(x @ params["wq"], cfg.n_heads, hd))
     k = constrain_replicated(_split_heads(x @ params["wk"], G, hd))
     v = constrain_replicated(_split_heads(x @ params["wv"], G, hd))
@@ -696,96 +697,6 @@ def paged_chunk_attention(params, x, positions, cache: PagedKVCache,
     qg = q.reshape(B, C, G, R, hd)
     o = _sdpa(qg, dq(kk), dq(vv), mask=valid[:, None, None],
               cap=cfg.attn_softcap)
-    return _merge(o, B, C, cfg.q_dim) @ params["wo"], new_cache
-
-
-def chunk_decode_attention(params, x, positions, cache: KVCache,
-                           cfg: ModelConfig, *, window: int = 0,
-                           kv_scale: float = 0.0, mesh=None,
-                           use_kernel: Optional[bool] = None,
-                           interpret: bool = False):
-    """C-token prompt-chunk step against an existing ring cache.
-
-    x: (B,C,D); positions: (B,C) absolute. The chunk attends to every valid
-    cache entry PLUS itself (causal within the chunk), then the last
-    ``min(C, W)`` chunk entries are written into the ring at the slots the
-    token-by-token warmup would have used — so decode continues bit-compatibly
-    from ``cache.cursor + C``. The generalization of ``decode_attention`` to
-    C tokens (C=1 reduces to it); the chunked-prefill admission path.
-
-    ``mesh`` + kernel requested: when ``dist.sharding.prefill_plan`` finds a
-    sequence layout, the attend runs in ``kernels.ring_attention`` — queries
-    resident per shard, the [cache; chunk] context rotating by ``ppermute``
-    with the online-softmax state carried across hops — so admission compute
-    scales 1/n_shards per device. Otherwise the whole-chunk ``_sdpa`` below
-    is taken and ``_warn_prefill`` says so (once per reason).
-    """
-    from repro.dist.annotate import constrain_replicated
-    B, C, D = x.shape
-    hd = cfg.resolved_head_dim
-    G, R = cfg.n_kv_heads, cfg.n_heads // cfg.n_kv_heads
-    # gather the chunk Q/K/V before rope: the 0.4.x partitioner miscompiles
-    # split+concat over a TP-sharded head_dim (wrong values, not just slow);
-    # these are only a few tokens wide, so the gather is cheap
-    q = constrain_replicated(_split_heads(x @ params["wq"], cfg.n_heads, hd))
-    k = constrain_replicated(_split_heads(x @ params["wk"], G, hd))
-    v = constrain_replicated(_split_heads(x @ params["wv"], G, hd))
-    q = apply_rope(q, positions, cfg.rope_theta)
-    k = apply_rope(k, positions, cfg.rope_theta)
-    if kv_scale:
-        k_store = quantize_kv(k, kv_scale)
-        v_store = quantize_kv(v, kv_scale)
-    else:
-        k_store, v_store = k.astype(cache.k.dtype), v.astype(cache.v.dtype)
-
-    # ring write: last n_keep chunk entries land at (cursor + C - n_keep + j)
-    # mod W — identical slots to C successive decode-step writes. Expressed
-    # as a one-hot contraction, NOT jnp.roll/dynamic-slice at a traced shift:
-    # the dynamic-slice lowering misplaces entries under GSPMD once the chunk
-    # K/V are TP-sharded (same hazard as decode_attention's masked write).
-    W = cache.k.shape[1]
-    n_keep = min(C, W)
-    dest = (cache.cursor + C - n_keep + jnp.arange(n_keep)) % W
-    sel = dest[:, None] == jnp.arange(W)[None, :]        # (n_keep, W) one-hot
-    wmask = sel.any(axis=0)
-
-    def ring_write(buf, chunk_tail):
-        scat = jnp.einsum("jw,bj...->bw...", sel.astype(jnp.float32),
-                          chunk_tail.astype(jnp.float32))
-        expand = (None,) * (buf.ndim - 2)
-        return jnp.where(wmask[(None, slice(None)) + expand],
-                         scat.astype(buf.dtype), buf)
-
-    nk = ring_write(cache.k, k_store[:, C - n_keep:])
-    nv = ring_write(cache.v, v_store[:, C - n_keep:])
-    npos = ring_write(cache.pos, positions[:, C - n_keep:])
-    new_cache = KVCache(nk, nv, npos, cache.cursor + C)
-
-    # attend over [prior ring entries; full chunk] so intra-chunk tokens are
-    # visible even when C exceeds the ring (local layers attend pre-eviction,
-    # exactly like the full-sequence banded path).
-    plan, _ = _prefill_ring_plan(cfg, mesh, C, use_kernel)
-    if plan is not None:
-        from repro.kernels.ring_attention import ring_chunk_attention
-        kk_s = jnp.concatenate([cache.k, k_store], axis=1)  # storage dtype
-        vv_s = jnp.concatenate([cache.v, v_store], axis=1)
-        kv_pos = jnp.concatenate([cache.pos, positions], axis=1)
-        o = ring_chunk_attention(q.reshape(B, C, G, R, hd), kk_s, vv_s,
-                                 positions, kv_pos, mesh=mesh, plan=plan,
-                                 window=window, cap=cfg.attn_softcap,
-                                 kv_scale=kv_scale, interpret=interpret)
-        return _merge(o, B, C, cfg.q_dim) @ params["wo"], new_cache
-    dq = (lambda a: dequantize_kv(a, q.dtype, kv_scale)) if kv_scale else \
-        (lambda a: a.astype(q.dtype))
-    kk = jnp.concatenate([dq(cache.k), dq(k_store)], axis=1)
-    vv = jnp.concatenate([dq(cache.v), dq(v_store)], axis=1)
-    kv_pos = jnp.concatenate([cache.pos, positions], axis=1)   # (B, W+C)
-    valid = kv_pos[:, None, :] >= 0
-    valid &= kv_pos[:, None, :] <= positions[:, :, None]
-    if window:
-        valid &= kv_pos[:, None, :] > positions[:, :, None] - window
-    qg = q.reshape(B, C, G, R, hd)
-    o = _sdpa(qg, kk, vv, mask=valid[:, None, None], cap=cfg.attn_softcap)
     return _merge(o, B, C, cfg.q_dim) @ params["wo"], new_cache
 
 

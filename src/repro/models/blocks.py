@@ -100,42 +100,6 @@ def block_forward(kind: str, params, h, positions, cfg: ModelConfig,
     return h, aux
 
 
-def block_prefill(kind: str, params, h, positions, cache, cfg: ModelConfig,
-                  knobs: ApproxKnobs = PRECISE, *,
-                  ep_axis: Optional[str] = None, mesh=None,
-                  use_kernel: Optional[bool] = None, interpret: bool = False):
-    """C-token prompt-chunk step against an existing cache.
-
-    h: (B,C,D); positions: (B,C) absolute. Returns (h, new_cache, aux) — the
-    chunk-sized sibling of ``block_decode`` (serving admission path). Under
-    a ``mesh`` the attention runs ring-sequence-parallel when
-    ``dist.sharding.prefill_plan`` allows (``use_kernel``/``interpret``
-    mirror ``block_decode``'s kernel dispatch knobs)."""
-    aux = jnp.zeros((), jnp.float32)
-    prec = knobs.matmul_precision
-    if kind == MAMBA:
-        y, new_cache = mamba_mod.mamba_prefill(
-            params["mixer"], rms_norm(h, params["norm"], cfg.norm_eps),
-            cache, cfg, precision=prec)
-        return h + y, new_cache, aux
-    window = cfg.window if kind == LOCAL_ATTN else 0
-    kv_scale = attn_mod.KV_SCALE if knobs.kv_quant else 0.0
-    y, new_cache = attn_mod.chunk_decode_attention(
-        params["attn"], rms_norm(h, params["norm_attn"], cfg.norm_eps),
-        positions, cache, cfg, window=window, kv_scale=kv_scale, mesh=mesh,
-        use_kernel=use_kernel, interpret=interpret)
-    h = h + y
-    hn = rms_norm(h, params["norm_mlp"], cfg.norm_eps)
-    if "moe" in params:
-        y, aux = moe_mod.moe(params["moe"], hn, cfg,
-                             top_k=knobs.topk_override, precision=prec,
-                             ep_axis=ep_axis, mesh=mesh)
-        h = h + y
-    else:
-        h = h + mlp_mod.mlp(params["mlp"], hn, precision=prec)
-    return h, new_cache, aux
-
-
 def block_prefill_paged(kind: str, params, h, positions, cache,
                         cfg: ModelConfig, knobs: ApproxKnobs = PRECISE, *,
                         slot, valid=None, layer=None,
@@ -143,8 +107,9 @@ def block_prefill_paged(kind: str, params, h, positions, cache,
                         mesh=None, dyn_scatter: bool = False,
                         use_kernel: Optional[bool] = None,
                         interpret: bool = False):
-    """Paged sibling of ``block_prefill``: one slot's prompt chunk against
-    the shared page pool / per-slot Mamba rows. h: (1,C,D); ``slot`` traced.
+    """C-token prompt-chunk step for one slot (serving admission): the
+    chunk against the shared page pool / per-slot Mamba rows. h: (1,C,D);
+    ``slot`` traced.
     ``valid`` (C,) bool marks the rows whose K/V reach the slot's pages
     (``attention.paged_chunk_attention``) and that take expert rows; a
     Mamba layer takes every row. ``layer``: latent caches and expert

@@ -1,5 +1,5 @@
-"""Batched serving engine: continuous-batching slots over the decode step,
-run under Pliant control.
+"""Batched serving engine: continuous-batching slots over the paged pool,
+decoded by fused megasteps and run under Pliant control.
 
 Each slot holds one request's progress; finished slots are refilled from the
 queue without stopping the batch ("continuous batching"). Admission is
@@ -7,33 +7,27 @@ chunked prefill: the prompt streams through fixed-size full-sequence chunks
 (``serve.prefill``) — no O(prompt) token-by-token warmup on the decode path,
 so 32k prompts admit in a handful of executable calls.
 
-Two cache data models, selected by ``paged``:
+The cache is a shared physical page pool + per-slot block tables
+(``serve.pages.PagePool`` owns allocation host-side; the jitted paths in
+``models.attention`` gather/scatter through the tables). Admission maps
+shared prompt-prefix pages copy-on-write — a prefix hit SKIPS those prefill
+chunks entirely — and prefills the remainder straight into the pool;
+completion returns pages to the free list. The pool budget is a Pliant
+knob: the engine binds itself to an attached ``PliantRuntime`` as a
+``core.tenant.ServeTenant`` whose reclaimable quanta are pool pages —
+RECLAIM/RETURN shrink/regrow ``pool_pages``, evicting prefix-cache pages
+first and never touching live requests.
 
-* **dense** (default): per-slot ``max_len`` rings; admission prefills a
-  single-request cache and slot-scatters it (``serve.slots``).
-* **paged**: a shared physical page pool + per-slot block tables
-  (``serve.pages.PagePool`` owns allocation host-side; the jitted paths in
-  ``models.attention`` gather/scatter through the tables). Admission maps
-  shared prompt-prefix pages copy-on-write — a prefix hit SKIPS those
-  prefill chunks entirely — and prefills the remainder straight into the
-  pool; completion returns pages to the free list. The pool budget is a
-  Pliant knob: the engine binds itself to an attached ``PliantRuntime`` as
-  a ``core.tenant.ServeTenant`` whose reclaimable quanta are pool pages —
-  RECLAIM/RETURN shrink/regrow ``pool_pages``, evicting prefix-cache pages
-  first and never touching live requests.
-
-The paged loop is **continuously batched** and **stall-free**: admission
-prefill never runs to completion inside ``step()``. EVERY free slot opens
-its own in-flight admission each step (no wave barrier — freed slots refill
-while their neighbours keep decoding), and the step advances the in-flight
+The loop is **continuously batched** and **stall-free**: admission prefill
+never runs to completion inside ``step()``. EVERY free slot opens its own
+in-flight admission each step (no wave barrier — freed slots refill while
+their neighbours keep decoding), and the step advances the in-flight
 admissions round-robin under a QoS-aware chunk budget: ONE bounded chunk
 per step while any decoder is live (unless the attached runtime's
 ``LatencyMonitor`` reports p99 comfortably inside the QoS target — the
 ``qos_guard`` band), bursting up to ``max_admission_chunks`` when there is
 no decoder to protect or headroom to spare. A long prompt therefore adds at
-most one budget's worth of work between any two decode steps. The decode
-executable takes a per-slot ``active`` mask so admitting slots' dead batch
-rows cannot scatter garbage into their (already mapped) pages or SSM rows.
+most one budget's worth of work between any two decode dispatches.
 Admission is also **page-aware packed**: when the head of the queue does
 not fit the pool budget, the first of the leading ``pack_window`` pending
 requests that does fit is admitted instead — and after ``max_head_skips``
@@ -45,23 +39,22 @@ the allocator; a per-step ``PagePool.replenish`` keeps free-list headroom
 above a watermark by evicting prefix entries off the admission path.
 Banded-attention archs (every attention layer LOCAL) skip the speculative
 reservation — they free pages that fall out of the window as decode
-advances, keeping pool occupancy flat for long generations. On device,
-hybrid decode is ONE fused executable per step (attention pages and SSM
-rows advance inside a single lowered scan — ``models.lm.decode_step``);
-single-device engines use dynamic-index cache writes and, when greedy,
-fuse argmax into the step so only (B,) token ids cross the host boundary.
-The dense path keeps the legacy synchronous admission (its slot-insert is
-exact-output-critical).
+advances, keeping pool occupancy flat for long generations.
+
+Decode is the **megastep**: up to ``megastep_k`` decode steps fused into
+one executable (``lm.decode_megastep``) with on-device sampling and
+EOS/budget stop masking, dispatched by an async double-buffered host loop
+(``_megastep_round``: dispatch N+1, then drain N). Rows not decoding ride
+along masked, so their pages and SSM rows stay as they were.
 
 Serving variants come from a ``VariantTable`` (the explorer's serving grid):
-every variant's decode executable is registered up front and the active one
-is swapped at a step boundary — an O(µs) dictionary lookup, the DynamoRIO
-function-pointer swap analogue. When a ``PliantRuntime`` is attached, the
-engine feeds per-token latency to its ``LatencyMonitor``, ticks the arbiter
-at step boundaries, and receives its decisions back through the tenant
-protocol (``request_variant`` — deferred while an admission is in flight),
-converting cache dtype when a swap crosses the ``kv_quant`` boundary. A
-multi-tenant runtime (``launch/colocate.py``) attaches the same way via
+the active one is swapped at a step boundary and its executables are
+cached per variant. When a ``PliantRuntime`` is attached, the engine feeds
+per-token latency to its ``LatencyMonitor``, ticks the arbiter at step
+boundaries, and receives its decisions back through the tenant protocol
+(``request_variant`` — deferred while an admission is in flight),
+converting the pool's dtype when a swap crosses the ``kv_quant`` boundary.
+A multi-tenant runtime (``launch/colocate.py``) attaches the same way via
 ``attach_runtime``. Under a mesh, params shard via
 ``dist.param_shardings`` and caches via ``dist.cache_shardings``.
 """
@@ -69,8 +62,6 @@ from __future__ import annotations
 
 import collections
 import contextlib
-import functools
-import threading
 import time
 from dataclasses import dataclass, field
 from typing import Deque, Dict, List, Optional, Set, Tuple
@@ -185,7 +176,7 @@ class ServeEngine:
     prefill_chunk: int = 16
     seed: int = 0
     cache_dtype: object = jnp.float32
-    paged: bool = False                # paged pool instead of dense rings
+    paged: bool = True                 # harness still passes it; False raises
     page_size: int = 8
     n_pages: int = 0                   # 0 = auto (serve.pages.spec_for)
     use_kernel: Optional[bool] = None  # paged-attention dispatch override
@@ -213,23 +204,20 @@ class ServeEngine:
                                        # AdmissionTimeout (engine.rejected)
     backoff_base: int = 1              # steps before retrying a pool-blocked
     backoff_cap: int = 8               # request; doubles per failure, capped
-    background_compile: bool = True    # AOT-compile surviving-mesh decode
-                                       # during a revocation's grace window
-    megastep_k: int = 0                # > 0: fuse up to K decode steps per
+    megastep_k: int = 1                # fuse up to K decode steps per
                                        # dispatch (lax.scan megastep with
                                        # on-device sampling/stop masking +
-                                       # async double-buffered host loop);
-                                       # paged engines only. 0 = per-step
+                                       # async double-buffered host loop)
     eos_id: int = -1                   # stop-token id (-1 = none): a row
                                        # emitting it finishes early, on
-                                       # device mid-megastep or on host in
-                                       # the per-step path — same contract
+                                       # device mid-megastep, or at its
+                                       # first token on the host
     sync_timing: bool = False          # drain each megastep before
                                        # dispatching the next: no pipeline
                                        # overlap, but per-token stamps
                                        # measure compute, not enqueue
                                        # (benchmarks set this)
-    donate: bool = True                # donate cache buffers into decode /
+    donate: bool = True                # donate cache buffers into the
                                        # megastep / admission executables
                                        # (in-place pool + SSM update — no
                                        # per-step full-cache copy); rebuilt
@@ -237,6 +225,11 @@ class ServeEngine:
                                        # elastic re-home or variant swap
 
     def __post_init__(self):
+        if not self.paged:
+            raise ValueError("ServeEngine serves only from the paged pool")
+        if self.megastep_k < 1:
+            raise ValueError(
+                f"megastep_k must be at least 1, got {self.megastep_k}")
         # layer spans, off until ``spans.enable()`` (serve.spans)
         self.spans = spans_mod.SpanLog()
         # executables made here and not yet called by the engine, with the
@@ -247,53 +240,32 @@ class ServeEngine:
         self._variant_knobs = ([v.knobs for v in self.table.variants]
                                if self.table is not None else [self.knobs])
         self._active = 0
-        self.pool: Optional[pages_mod.PagePool] = None
-        self._page_spec = None
-        self.stores: List[pages_mod.CacheStore] = []
-        # greedy paged engines fuse argmax into the decode executable: the
-        # step returns (B,) token ids, so the host never pulls (B, V) logits
-        self._fused_sample = bool(self.paged and self.temperature <= 0.0)
-        # paged admission pads every shorter chunk to ``prefill_chunk``
-        # where pad rows are exact, so one program serves every tail
-        self._pad_chunks = bool(self.paged
-                                and prefill_mod.pads_exactly(self.cfg))
+        self._retired: Set[int] = set()    # variants retire_variant dropped
+        # admission pads every shorter chunk to ``prefill_chunk`` where pad
+        # rows are exact, so one program serves every tail
+        self._pad_chunks = prefill_mod.pads_exactly(self.cfg)
         self._derive_plans()
-        if self.paged:
-            self._page_spec = pages_mod.spec_for(
-                self.batch_slots, self.max_len, self.page_size, self.n_pages,
-                n_shards=self._plan_shards())
-            self.pool = pages_mod.PagePool(self._page_spec, self.batch_slots)
-            # one store per cache kind behind the shared CacheStore protocol:
-            # the page pool for attention state, the trivial per-slot store
-            # for SSM state — the engine frees every kind uniformly
-            self.stores = [self.pool]
-            if MAMBA in self.cfg.pattern:
-                self.stores.append(pages_mod.MambaSlotStore())
+        self._page_spec = pages_mod.spec_for(
+            self.batch_slots, self.max_len, self.page_size, self.n_pages,
+            n_shards=self._plan_shards())
+        self.pool = pages_mod.PagePool(self._page_spec, self.batch_slots)
+        # one store per cache kind behind the shared CacheStore protocol:
+        # the page pool for attention state, the trivial per-slot store for
+        # SSM state — the engine frees every kind uniformly
+        self.stores: List[pages_mod.CacheStore] = [self.pool]
+        if MAMBA in self.cfg.pattern:
+            self.stores.append(pages_mod.MambaSlotStore())
         self._derive_shardings()
         if self._param_sh is not None:
             with self._ctx():
                 self.params = jax.device_put(self.params, self._param_sh)
 
-        # the variant table of decode executables: registered once up front,
-        # hot-swapped between steps (no recompilation on the critical path).
-        # Engine-owned, never written into the (possibly shared) table —
-        # executables are lowered against THIS engine's mesh/shardings.
-        # Paged engines take the per-slot ``active`` write mask so decode
-        # can interleave with background admission (stall-free loop). Under
-        # a mesh the fused kernel runs shard_map'd over the slot-affinity
-        # pool when the decode plan allows; otherwise the attention layer
-        # takes the GSPMD gather path and logs why (attention.explain_
-        # dispatch reports the decision up front).
-        self._decodes: Dict[int, object] = {
-            i: None for i in range(len(self._variant_knobs))}
-        self._build_decodes()
-        # admission executables, keyed by (knobs, chunk len, paged) — NOT by
+        # admission executables, keyed by (knobs, chunk len) — NOT by
         # variant index, so table entries with identical admission knobs
         # share one compiled chunk cell — and LRU-bounded; a padding engine
         # keys every length as the full chunk
         self._prefills: "collections.OrderedDict[Tuple, object]" = \
             collections.OrderedDict()
-        self._insert = jax.jit(slots_mod.insert_request)
 
         self.caches = self._init_caches(self.active_knobs.kv_quant)
         self.positions = np.zeros(self.batch_slots, np.int32)
@@ -306,10 +278,14 @@ class ServeEngine:
         # first-token sampling waits for the step's single drain point so
         # the final chunk's compute overlaps the decode dispatched after it
         self._await_admit: Dict[int, _Admission] = {}
-        # ---- megastep pipeline state (megastep_k > 0) ----
-        if self.megastep_k:
-            assert self.paged, "megastep decode requires the paged engine"
-        self._megasteps: Dict[Tuple[int, int], object] = {}  # (variant, k)
+        # ---- megastep pipeline state ----
+        # fused K-step executables per (variant, k), lowered lazily against
+        # THIS engine's mesh/shardings (never written into the possibly
+        # shared table). Under a mesh the fused kernel runs shard_map'd
+        # over the slot-affinity pool when the decode plan allows; otherwise
+        # the attention layer takes the GSPMD gather path and logs why
+        # (attention.explain_dispatch reports the decision up front)
+        self._megasteps: Dict[Tuple[int, int], object] = {}
         self._inflight: Optional[dict] = None  # dispatched, undrained round
         self._carry = None             # device (cur, pos, alive, draws,
                                        # budget) chained between dispatches;
@@ -321,16 +297,16 @@ class ServeEngine:
         self._pos_ub = np.zeros(self.batch_slots, np.int32)  # exclusive ub
                                        # on positions in-flight megasteps
                                        # may write (page pre-map horizon)
-        self.decode_dispatches = 0     # decode/megastep executable calls
+        self.decode_dispatches = 0     # megastep executable calls
         self.row_dispatches = 0        # per-row dispatch count: a row in a
         self.row_tokens = 0            # drain with n>=1 tokens adds (1, n)
-                                       # — dispatches/token = 1.0 per-step,
+                                       # — dispatches/token = 1.0 at K=1,
                                        # ~1/K under a sustained megastep
         self.drain_block_s = 0.0       # wall spent blocked at drain points
         self._head_skips = 0           # consecutive pool-blocked head-of-queue
         # window-exit page freeing is sound only when EVERY attention layer
         # is banded (a single global/shared layer still reaches every page)
-        self._window_free = (self.cfg.window if self.paged and self.cfg.window
+        self._window_free = (self.cfg.window if self.cfg.window
                              and set(self.cfg.pattern) <= {LOCAL_ATTN, MAMBA}
                              else 0)
         self.cur_tokens = np.zeros(self.batch_slots, np.int32)
@@ -350,10 +326,8 @@ class ServeEngine:
         self._pending_capacity: List[Tuple[int, object]] = []  # (due, event)
         self._collective_failures = 0        # queued transient step failures
         self._recovering: List[dict] = []    # rehome entries awaiting first
-                                             # completed decode step
+                                             # completed megastep
         self.elastic_log: List[dict] = []
-        self._prepared: Dict[Tuple, object] = {}   # AOT-compiled decodes for
-        self._compile_threads: List[threading.Thread] = []  # a pending mesh
         # admission backoff/timeout state
         self._backoff: Dict[int, Tuple[int, int]] = {}  # uid -> (retry, dly)
         self.rejected: List[Request] = []
@@ -379,8 +353,8 @@ class ServeEngine:
             # bind this engine as the runtime's tenant (replacing the
             # constructor's placeholder wrap — unless the caller supplied
             # their own reshard actuator, which stays in charge of quanta):
-            # variant hot-swaps arrive via ``request_variant`` and — for
-            # paged engines — pool_pages is the tenant's reclaimable quanta
+            # variant hot-swaps arrive via ``request_variant`` and
+            # pool_pages is the tenant's reclaimable quanta
             # (RECLAIM shrinks the page budget, prefix cache evicted first;
             # RETURN grows it back)
             self._tenant = tenant_mod.ServeTenant(engine=self)
@@ -401,10 +375,9 @@ class ServeEngine:
         if self.mesh is None:
             return
         from repro.dist import sharding as dist_sharding
-        if self.paged:
-            self._decode_plan, self._plan_reason = \
-                dist_sharding.paged_decode_plan(
-                    self.cfg, self.mesh, self.batch_slots, self.n_pages)
+        self._decode_plan, self._plan_reason = \
+            dist_sharding.paged_decode_plan(
+                self.cfg, self.mesh, self.batch_slots, self.n_pages)
         self._prefill_plan, self._prefill_reason = \
             dist_sharding.prefill_plan(self.cfg, self.mesh,
                                        self.prefill_chunk)
@@ -424,41 +397,6 @@ class ServeEngine:
         self._cache_sh, _ = dist_sharding.cache_shardings(
             self.cfg, shp, self.mesh, paged=self._page_spec)
 
-    def _decode_builder(self):
-        if self.paged:
-            return functools.partial(
-                step_mod.make_paged_serve_step,
-                mesh=self.mesh,
-                use_kernel=self.use_kernel,
-                interpret=self.kernel_interpret,
-                dynamic_scatter=self.mesh is None,
-                sample_greedy=self._fused_sample)
-        return step_mod.make_serve_step
-
-    def _mesh_key(self, mesh) -> Tuple:
-        if mesh is None:
-            return ("1x1",)
-        return (tuple(sorted(mesh.shape.items())),
-                tuple(int(d.id) for d in np.asarray(mesh.devices).ravel()))
-
-    def _build_decodes(self) -> None:
-        """(Re)lower the decode executable of every REGISTERED variant
-        against the current mesh/shardings (retired variants stay retired).
-        jit is lazy, so rebuilding the whole dict costs wrapper setup only —
-        compilation happens at each variant's first post-(re)build call,
-        except where ``_prepared`` holds an AOT executable background-
-        compiled during a revocation grace window."""
-        mk = self._decode_builder()
-        mkey = self._mesh_key(self.mesh)
-        prepared = getattr(self, "_prepared", {})   # post-init ordering
-        for i in self._decodes:
-            exe = prepared.pop((mkey, i), None)
-            if exe is None:
-                exe = self._lower_decode(
-                    mk(self.cfg, self._variant_knobs[i]))
-                self._fresh[exe] = ("decode_step", i)
-            self._decodes[i] = exe
-
     # ----------------------------------------------------------- dispatch --
 
     @property
@@ -466,7 +404,7 @@ class ServeEngine:
         """True when this engine's decode executable runs the fused kernel
         shard_map'd over the slot-affinity pool (the multi-device fast
         path), False for single-device kernels and gather fallbacks."""
-        if not self.paged or self._decode_plan is None:
+        if self._decode_plan is None:
             return False
         if self.use_kernel is not None:
             return bool(self.use_kernel)
@@ -476,17 +414,13 @@ class ServeEngine:
     def explain_dispatch(self) -> str:
         """One-line paged-decode dispatch description (startup banner)."""
         from repro.models import attention as attn_mod
-        if not self.paged:
-            return "dense decode: ring caches (no paged dispatch)"
         return attn_mod.explain_dispatch(
             self.cfg, self.mesh, batch_slots=self.batch_slots,
             n_pages=self._page_spec.n_pages, use_kernel=self.use_kernel,
-            megastep_k=self.megastep_k if self.paged else 0)
+            megastep_k=self.megastep_k)
 
     def explain_megastep(self) -> str:
         """One-line megastep/pipeline description (startup banner)."""
-        if not self.paged or self.megastep_k <= 0:
-            return "megastep: off (one decode dispatch per token)"
         samp = ("greedy argmax" if self.temperature <= 0.0 else
                 f"temperature categorical, (seed,uid,draw) fold-in "
                 f"seed={self.seed}")
@@ -526,8 +460,8 @@ class ServeEngine:
         return self._variant_knobs[self._active]
 
     def set_variant(self, idx: int) -> None:
-        """Hot-swap the decode executable at a step boundary, converting the
-        KV rings/pages when the swap crosses the ``kv_quant`` boundary."""
+        """Hot-swap the active variant at a step boundary, converting the
+        pool's pages when the swap crosses the ``kv_quant`` boundary."""
         if idx == self._active:
             return
         old, new = self.active_knobs, self._variant_knobs[idx]
@@ -537,7 +471,7 @@ class ServeEngine:
                     self.caches, new.kv_quant, self.cache_dtype)
                 if self._cache_sh is not None:
                     self.caches = jax.device_put(self.caches, self._cache_sh)
-        if self.pool is not None and old != new:
+        if old != new:
             # prefix entries are tagged by the knobs that computed them; a
             # swap re-encodes the pool in place, so drop the stale index
             self.pool.flush_prefixes()
@@ -584,73 +518,43 @@ class ServeEngine:
         self._bound = tenant is not None
 
     def retire_variant(self, idx: int) -> None:
-        """Drop a retired table entry's executables. Admission cells are
-        knobs-keyed, so they survive while any live variant shares the
-        knobs and are evicted with the last user."""
+        """Drop a retired table entry's executables. Its megasteps go at
+        once; admission cells are knobs-keyed, so they survive while any
+        live variant shares the knobs and are evicted with the last user."""
         assert idx != self._active, "cannot retire the active variant"
-        self._decodes.pop(idx, None)
+        self._retired.add(idx)
+        for key in [k for k in self._megasteps if k[0] == idx]:
+            self._fresh.pop(self._megasteps.pop(key), None)
         kn = self._variant_knobs[idx]
         if any(k == kn for i, k in enumerate(self._variant_knobs)
-               if i != idx and i in self._decodes):
+               if i not in self._retired):
             return
         for key in [k for k in self._prefills if k[0] == kn]:
-            del self._prefills[key]
-
-    def _lower_decode(self, step):
-        # donate the caches argument: the pool/SSM state updates in place
-        # instead of being copied whole per step (the dominant decode HBM
-        # cost at high occupancy). Donation is an executable property, so a
-        # rebuild (_rehome, variant swap) re-donates automatically; the
-        # collective-failure retry path copies first (_call_decode)
-        cidx = 4 if self.paged else 3
-        kw = dict(donate_argnums=(cidx,)) if self.donate else {}
-        if self.mesh is None:
-            return jax.jit(step, **kw)
-        if self.paged:      # (params, tokens, position, active, caches)
-            return jax.jit(step,
-                           in_shardings=(self._param_sh, None, None, None,
-                                         self._cache_sh),
-                           out_shardings=(None, self._cache_sh), **kw)
-        return jax.jit(step,
-                       in_shardings=(self._param_sh, None, None,
-                                     self._cache_sh),
-                       out_shardings=(None, self._cache_sh), **kw)
+            self._fresh.pop(self._prefills.pop(key), None)
 
     def _prefill_exe(self, chunk_len: int):
         if self._pad_chunks:
             chunk_len = self.prefill_chunk
-        key = (self.active_knobs, chunk_len, self.paged)
+        key = (self.active_knobs, chunk_len)
         fn = self._prefills.get(key)
         if fn is not None:
             self._prefills.move_to_end(key)
             return fn
         self.stats["prefill_exe_misses"] += 1
-        # the caches argument (position 3 in both admission signatures)
-        # donates like the decode path: a chunked prefill updates the pool /
-        # fresh single-request cache in place instead of copying it per chunk
+        # the caches argument (position 3) donates like the megastep's: a
+        # chunked prefill updates the pool in place instead of copying it
         kw = dict(donate_argnums=(3,)) if self.donate else {}
-        if self.paged:
-            step = step_mod.make_paged_admission_step(
-                self.cfg, self.active_knobs,
-                dynamic_scatter=self.mesh is None, mesh=self.mesh,
-                use_kernel=self.use_kernel, interpret=self.kernel_interpret)
-            if self.mesh is None:
-                fn = jax.jit(step, **kw)
-            else:
-                fn = jax.jit(step,
-                             in_shardings=(self._param_sh, None, None,
-                                           self._cache_sh, None),
-                             out_shardings=(None, None, self._cache_sh),
-                             **kw)
+        step = step_mod.make_paged_admission_step(
+            self.cfg, self.active_knobs, dynamic_scatter=self.mesh is None,
+            mesh=self.mesh, use_kernel=self.use_kernel,
+            interpret=self.kernel_interpret)
+        if self.mesh is None:
+            fn = jax.jit(step, **kw)
         else:
-            step = step_mod.make_admission_step(
-                self.cfg, self.active_knobs, mesh=self.mesh,
-                use_kernel=self.use_kernel, interpret=self.kernel_interpret)
-            if self.mesh is None:
-                fn = jax.jit(step, **kw)
-            else:
-                fn = jax.jit(step, in_shardings=(self._param_sh, None, None,
-                                                 None), **kw)
+            fn = jax.jit(step,
+                         in_shardings=(self._param_sh, None, None,
+                                       self._cache_sh, None),
+                         out_shardings=(None, None, self._cache_sh), **kw)
         if self._pad_chunks:
             fn = _PaddedChunk(fn, chunk_len)
         self._prefills[key] = fn
@@ -683,15 +587,10 @@ class ServeEngine:
         return jax.set_mesh(self.mesh)
 
     def _init_caches(self, quantized: bool):
-        if self.paged:
-            sp = self._page_spec
-            caches = lm.init_paged_caches(
-                self.cfg, self.batch_slots, sp.n_pages, sp.page_size,
-                sp.max_pages, dtype=self.cache_dtype, quantized=quantized)
-        else:
-            caches = lm.init_caches(self.cfg, self.batch_slots, self.max_len,
-                                    dtype=self.cache_dtype,
-                                    quantized=quantized)
+        sp = self._page_spec
+        caches = lm.init_paged_caches(
+            self.cfg, self.batch_slots, sp.n_pages, sp.page_size,
+            sp.max_pages, dtype=self.cache_dtype, quantized=quantized)
         if self._cache_sh is not None:
             with self._ctx():
                 caches = jax.device_put(caches, self._cache_sh)
@@ -736,8 +635,7 @@ class ServeEngine:
         engine keeps serving on the doomed mesh while the runtime — notified
         here — treats the pending loss as contention (the variant ladder
         degrades through the normal Fig. 3 loop instead of traffic being
-        rejected) and the surviving-mesh executables start compiling in the
-        background. Everything else applies at the next step boundary.
+        rejected). Everything else applies at the next step boundary.
         ``notify_runtime=False`` is for tenant adapters whose runtime
         already saw the event (``PliantRuntime.inject`` fans out both
         ways)."""
@@ -750,9 +648,6 @@ class ServeEngine:
             self.elastic_log.append(dict(
                 step=self.step_count, kind="revoke_notice", count=ev.count,
                 devices=list(ev.devices), deadline_step=due))
-            if self.background_compile and self.paged \
-                    and self._base_mesh is not None:
-                self._precompile_async(ev)
         self._pending_capacity.append((due, ev))
 
     def _process_capacity(self) -> None:
@@ -791,14 +686,12 @@ class ServeEngine:
             entry["revoked"] = sorted(self._revoked)
             self._recovering.append(entry)
         elif ev.kind == elastic.QUOTA_CUT:
-            if self.pool is not None:
-                self.pool.set_capacity_cut(self.pool.capacity_cut + ev.quanta)
-                entry["capacity_cut"] = self.pool.capacity_cut
+            self.pool.set_capacity_cut(self.pool.capacity_cut + ev.quanta)
+            entry["capacity_cut"] = self.pool.capacity_cut
         elif ev.kind == elastic.QUOTA_RESTORE:
-            if self.pool is not None:
-                cut = (self.pool.capacity_cut - ev.quanta if ev.quanta else 0)
-                self.pool.set_capacity_cut(max(cut, 0))
-                entry["capacity_cut"] = self.pool.capacity_cut
+            cut = (self.pool.capacity_cut - ev.quanta if ev.quanta else 0)
+            self.pool.set_capacity_cut(max(cut, 0))
+            entry["capacity_cut"] = self.pool.capacity_cut
         elif ev.kind == elastic.COLLECTIVE_FAILURE:
             self._collective_failures += max(ev.count, 1)
             entry["queued_failures"] = self._collective_failures
@@ -818,10 +711,10 @@ class ServeEngine:
            and permute the host-staged device caches to match;
         3. re-put params under the new shardings (host-staged — the revoked
            devices may be gone);
-        4. rebuild the decode executables (AOT background-compiled ones are
-           picked up when ready; the rest compile lazily at first call) and
-           drop the admission-cell LRU — in-flight ``_Admission``s simply
-           resume at their chunk cursor on the new mesh."""
+        4. drop the megastep executables and the admission-cell LRU (they
+           are lowered again lazily, at first call) — in-flight
+           ``_Admission``s simply resume at their chunk cursor on the new
+           mesh."""
         t0 = time.perf_counter()
         # flush the async pipeline first: the in-flight megastep's tokens
         # must land (and its donated-cache chain settle) before the caches
@@ -834,29 +727,20 @@ class ServeEngine:
                 + list(self._await_admit.values()):
             if adm.logits is not None:
                 adm.logits = np.asarray(adm.logits)
-        old_shards = self._plan_shards() if self.paged else 1
+        old_shards = self._plan_shards()
         self.mesh = new_mesh
         self._derive_plans()
-        migrated = 0
-        if self.paged:
-            new_spec = pages_mod.spec_for(
-                self.batch_slots, self.max_len, self.page_size, self.n_pages,
-                n_shards=self._plan_shards())
-            new_pool, perm = self.pool.migrate(new_spec)
-            self._page_spec = new_spec
-            self._derive_shardings()
-            self.caches = self._migrate_paged_caches(perm, new_pool)
-            self.pool = new_pool
-            self.stores[0] = new_pool
-            migrated = int((perm >= 0).sum())
-        else:
-            self._derive_shardings()
-            with self._ctx():
-                self.caches = elastic.reshard_live(self.caches,
-                                                   self._cache_sh)
+        new_spec = pages_mod.spec_for(
+            self.batch_slots, self.max_len, self.page_size, self.n_pages,
+            n_shards=self._plan_shards())
+        new_pool, perm = self.pool.migrate(new_spec)
+        self._page_spec = new_spec
+        self._derive_shardings()
+        self.caches = self._migrate_paged_caches(perm, new_pool)
+        self.pool = new_pool
+        self.stores[0] = new_pool
         with self._ctx():
             self.params = elastic.reshard_live(self.params, self._param_sh)
-        self._build_decodes()
         self._prefills.clear()
         self._megasteps.clear()    # lowered against the old mesh/shardings;
                                    # rebuilt (and re-donated) lazily
@@ -865,8 +749,8 @@ class ServeEngine:
             step_index=len(self.step_latencies), why=why,
             mesh_shape=(dict(new_mesh.shape) if new_mesh is not None
                         else None),
-            n_shards=(old_shards, self._plan_shards() if self.paged else 1),
-            pages_migrated=migrated,
+            n_shards=(old_shards, self._plan_shards()),
+            pages_migrated=int((perm >= 0).sum()),
             cutover_s=time.perf_counter() - t0,
             recovery_steps=None, _t_rehome=t0)
 
@@ -904,76 +788,6 @@ class ServeEngine:
                 return jax.device_put(caches, self._cache_sh)
             return jax.tree.map(jnp.asarray, caches,
                                 is_leaf=lambda x: isinstance(x, np.ndarray))
-
-    def _precompile_async(self, ev) -> None:
-        """Best-effort AOT compile of the ACTIVE variant's decode executable
-        for the mesh that survives ``ev``, on a background thread during the
-        revocation grace window — the cutover's first step then skips the
-        full compile. Any failure just falls back to lazy compilation at
-        cutover; correctness never depends on this racing to finish."""
-        lost = self._revoked | set(ev.devices or elastic.pick_revoked(
-            self.mesh if self.mesh is not None else self._base_mesh,
-            ev.count, already=self._revoked))
-        new_mesh, _ = elastic.surviving_mesh(
-            self._base_mesh, lost, prefer_divisor_of=self.batch_slots)
-        if new_mesh is None:
-            return
-        variant = self._active
-        key = (self._mesh_key(new_mesh), variant)
-        if key in self._prepared:
-            return
-
-        def compile_target():
-            try:
-                from repro.dist import sharding as dist_sharding
-                plan, _ = dist_sharding.paged_decode_plan(
-                    self.cfg, new_mesh, self.batch_slots, self.n_pages)
-                spec = pages_mod.spec_for(
-                    self.batch_slots, self.max_len, self.page_size,
-                    self.n_pages,
-                    n_shards=plan.n_shards if plan is not None else 1)
-                psh = dist_sharding.param_shardings(self.cfg, new_mesh,
-                                                    self.policy)
-                shp = ShapeConfig("serve", self.max_len, self.batch_slots,
-                                  "decode")
-                csh, _ = dist_sharding.cache_shardings(
-                    self.cfg, shp, new_mesh, paged=spec)
-                step = step_mod.make_paged_serve_step(
-                    self.cfg, self._variant_knobs[variant], mesh=new_mesh,
-                    use_kernel=self.use_kernel,
-                    interpret=self.kernel_interpret, dynamic_scatter=False,
-                    sample_greedy=self._fused_sample)
-                sds = lambda t: jax.tree.map(
-                    lambda x: jax.ShapeDtypeStruct(np.shape(x),
-                                                   np.asarray(x).dtype
-                                                   if not hasattr(x, "dtype")
-                                                   else x.dtype), t)
-                caches_abs = jax.eval_shape(functools.partial(
-                    lm.init_paged_caches, self.cfg, self.batch_slots,
-                    spec.n_pages, spec.page_size, spec.max_pages,
-                    dtype=self.cache_dtype,
-                    quantized=self._variant_knobs[variant].kv_quant))
-                B = self.batch_slots
-                kw = dict(donate_argnums=(4,)) if self.donate else {}
-                exe = jax.jit(
-                    step, in_shardings=(psh, None, None, None, csh),
-                    out_shardings=(None, csh), **kw
-                ).lower(
-                    sds(self.params),
-                    jax.ShapeDtypeStruct((B, 1), jnp.int32),
-                    jax.ShapeDtypeStruct((B,), jnp.int32),
-                    jax.ShapeDtypeStruct((B,), jnp.bool_),
-                    caches_abs,
-                ).compile()
-                self._prepared[key] = exe
-            except Exception as e:     # pragma: no cover - best effort
-                self.elastic_log.append(dict(
-                    step=self.step_count, kind="precompile_failed",
-                    error=repr(e)))
-
-        th = threading.Thread(target=compile_target, daemon=True)
-        self._compile_threads.append(th)
-        th.start()
 
     def _expire_pending(self) -> None:
         """Admission-timeout sweep: reject (structured, loud in stats) every
@@ -1053,8 +867,7 @@ class ServeEngine:
     def _set_mamba_rows(self, slot: int, snap) -> None:
         """Seed the slot's SSM rows for a fresh admission: the prefix-entry
         snapshot on a hit, zeros otherwise — the previous tenant's state must
-        never leak into a new request (the dense path gets this for free
-        from its fresh single-request cache + insert)."""
+        never leak into a new request."""
         if not any(isinstance(c, MambaCache) for c in self.caches):
             return
         caches = list(self.caches)
@@ -1072,24 +885,6 @@ class ServeEngine:
                 self.caches = jax.device_put(self.caches, self._cache_sh)
 
     # ----------------------------------------------------------- admission --
-
-    def _chunked_prefill(self, prompt: List[int]):
-        """Dense path: stream the prompt through fixed-size chunks into a
-        fresh single-request cache. Returns (last-token logits, caches)."""
-        knobs = self.active_knobs
-        caches = lm.init_caches(self.cfg, 1, self.max_len,
-                                dtype=self.cache_dtype,
-                                quantized=knobs.kv_quant)
-        toks = np.asarray(prompt, np.int32)
-        S, start, logits = len(prompt), 0, None
-        with self._ctx():
-            while start < S:
-                C = min(self.prefill_chunk, S - start)
-                logits, caches = self._call(self._prefill_exe(C), (
-                    self.params, jnp.asarray(toks[None, start:start + C]),
-                    jnp.asarray(start, jnp.int32), caches))
-                start += C
-        return logits, caches
 
     def _prefix_dedup_wait(self, req: Request, shard: int = 0) -> bool:
         """Cold-start prefix dedup: True when an in-flight admission is
@@ -1249,7 +1044,7 @@ class ServeEngine:
         interleave, full K always. Queued work that CANNOT start — every
         slot occupied, nothing in flight — is not admission work: shrinking
         K for it would serialize the whole first wave at K=1 for nothing."""
-        cap = max(1, self.megastep_k)
+        cap = self.megastep_k
         admitting = bool(self._admissions or self._await_admit)
         can_start = bool(self.pending) and any(
             self.slots[i] is None and i not in self._admissions
@@ -1342,9 +1137,8 @@ class ServeEngine:
         each completed admission's final-chunk logits (the wait lands in
         ``admit_compute_s`` — the dispatch loop stamped only enqueue time),
         sample the first token, and hand the slot to the decode batch.
-        Newly activated slots join the NEXT dispatch: the per-step path
-        captures its row set before decoding, the megastep path merges them
-        into the device carry via ``_inject_slots``."""
+        Newly activated slots join the NEXT dispatch, merged into the device
+        carry via ``_inject_slots``."""
         if not self._await_admit:
             return
         sid = self.spans.on and self.spans.open(
@@ -1379,8 +1173,7 @@ class ServeEngine:
             self._uids[slot] = req.uid
             self._pos_ub[slot] = len(req.prompt)
             self.slots[slot] = req
-            if self.megastep_k:
-                self._inject_slots.add(slot)
+            self._inject_slots.add(slot)
         if freed:
             self._push_blocks()
         if sid:
@@ -1401,63 +1194,31 @@ class ServeEngine:
                                    self.stats["moe_active_experts"]))
         return rows, active
 
-    def _admit(self) -> None:
-        """Dense path: legacy synchronous admission (full chunked prefill
-        into a fresh cache + slot insert inside one step)."""
-        for i in range(self.batch_slots):
-            while self.slots[i] is None and self.pending:
-                req = self.pending[0]
-                assert len(req.prompt) <= self.max_len, \
-                    (len(req.prompt), self.max_len)
-                t0 = time.perf_counter()
-                req.t_admit_start = t0
-                logits, rcaches = self._chunked_prefill(req.prompt)
-                with self._ctx():
-                    self.caches = self._insert(self.caches, rcaches, i)
-                    if self._cache_sh is not None:
-                        self.caches = jax.device_put(self.caches,
-                                                     self._cache_sh)
-                self.pending.popleft()
-                tok = int(self._sample_rows(np.asarray(logits), [req])[0])
-                now = time.perf_counter()
-                self._token_lat.append(now - t0)   # TTFT sample
-                req.t_admit = now                  # admission COMPLETION
-                req.admit_compute_s = now - t0     # sync: compute == wall
-                req.out.append(tok)
-                req.token_times.append(now)
-                if len(req.out) >= req.max_new or (
-                        self.eos_id >= 0 and tok == self.eos_id):
-                    req.done = True                # 1-token request: no slot
-                    continue
-                self.positions[i] = len(req.prompt)
-                self.cur_tokens[i] = tok
-                self.slots[i] = req
-
     # --------------------------------------------------------------- steps --
 
-    def _call_decode(self, exe, args, cache_idx: int):
-        """Dispatch a decode/megastep executable with honest collective-
-        failure retry under donation: the call CONSUMES the caches argument
-        when donation is on, so a queued injected failure snapshots the
-        pre-step caches first and the retry re-issues from the snapshot —
-        the semantics stay "results discarded uncommitted, step re-run",
-        bounded by the injected count."""
+    def _call_decode(self, exe, args):
+        """Dispatch a megastep executable (caches last in ``args``) with
+        honest collective-failure retry under donation: the call CONSUMES
+        the caches argument when donation is on, so a queued injected
+        failure snapshots the pre-step caches first and the retry re-issues
+        from the snapshot — the semantics stay "results discarded
+        uncommitted, step re-run", bounded by the injected count."""
         while True:
             retry = self._collective_failures > 0
             if retry and self.donate:
-                safe = jax.tree.map(jnp.copy, args[cache_idx])
+                safe = jax.tree.map(jnp.copy, args[-1])
             out = self._call(exe, args)
             if not retry:
                 return out
             self._collective_failures -= 1
             self.stats["collective_retries"] += 1
             if self.donate:
-                args = args[:cache_idx] + (safe,) + args[cache_idx + 1:]
+                args = args[:-1] + (safe,)
 
     def _megastep_exe(self, k: int):
         """The fused K-step executable for the ACTIVE variant, lowered
-        lazily per (variant, K) and cached — the QoS budget only ever picks
-        K from {1, megastep_k}, so at most two executables per variant.
+        lazily per (variant, K) and cached — K is the QoS budget's pick
+        (1 or megastep_k) clipped to the longest remaining row budget.
         Cleared (and re-donated on rebuild) by ``_rehome``."""
         key = (self._active, k)
         exe = self._megasteps.get(key)
@@ -1565,7 +1326,7 @@ class ServeEngine:
             args = (self.params, cur, pos, alive, jnp.asarray(self._uids),
                     draws, budget, self.caches)
             toks, cur, pos, alive, draws, budget, counts, new_caches = \
-                self._call_decode(self._megastep_exe(k), args, 7)
+                self._call_decode(self._megastep_exe(k), args)
             self.caches = new_caches
             self._carry = (cur, pos, alive, draws, budget)
         self._inject_slots.clear()
@@ -1654,12 +1415,13 @@ class ServeEngine:
         self._carry = None
 
     def _megastep_round(self) -> None:
-        """One engine step in megastep mode — the async double-buffered
-        host pipeline: advance admissions, dispatch megastep N+1, THEN
-        drain megastep N (the device never idles waiting for the host to
-        process tokens), drain completed admissions, tick control. The ONE
-        explicit drain pair (``_drain_megastep`` + ``_drain_admissions``)
-        replaces the per-step path's scattered blocking calls.
+        """One engine step — the async double-buffered host pipeline:
+        advance admissions, dispatch megastep N+1, THEN drain megastep N
+        (the device never idles waiting for the host to process tokens),
+        drain completed admissions, tick control. Admission chunks are
+        dispatched before the megastep, so draining them never waits on
+        it: the ONE explicit drain pair is ``_drain_megastep`` +
+        ``_drain_admissions``.
         ``sync_timing`` drains each dispatch in its own round instead — no
         overlap, but per-token stamps measure compute, not enqueue."""
         prev, self._inflight = self._inflight, None
@@ -1676,16 +1438,10 @@ class ServeEngine:
         self._control_tick()
 
     def step(self) -> None:
-        """One engine step. Megastep (``megastep_k`` > 0): one async
-        double-buffered pipeline round (``_megastep_round``). Paged
-        per-step: run the continuous-batching admission phase (open
-        admissions on every free slot, advance them under the QoS chunk
-        budget), dispatch one decode for every active slot (admitting slots
-        ride along inactive, their writes masked), then drain admissions
-        and the decode at the step's single drain point — a long prompt
-        never stalls the decoders for more than the chunk budget. Dense:
-        legacy synchronous admission, then decode. All tick the Pliant
-        control loop at the step boundary."""
+        """One engine step: apply capacity events whose deadline arrived,
+        expire over-waited queued requests, then run one pipeline round
+        (``_megastep_round``), which ticks the Pliant control loop at the
+        step boundary."""
         sid = self.spans.on and self.spans.open(
             "engine.step", step=self.step_count + 1)
         try:
@@ -1698,111 +1454,7 @@ class ServeEngine:
         self.step_count += 1
         self._process_capacity()   # deadline-reached capacity events cut
         self._expire_pending()     # over first, at the step boundary
-        if self.paged and self.megastep_k > 0:
-            self._megastep_round()
-            return
-        if self.paged:
-            self._advance_admissions()
-        else:
-            self._admit()
-        # the decode row set is FIXED here: slots activated at this step's
-        # admission drain join the next step's decode
-        rows = [i for i, req in enumerate(self.slots) if req is not None]
-        if not rows:
-            if self.paged:
-                self._drain_admissions()  # no decode to overlap — drain now
-                self.pool.replenish()     # keep headroom between steps
-            self._control_tick()       # flush TTFT samples of 1-token admits
-            return
-        sid = self.spans.on and self.spans.open(
-            "decode.dispatch", k=1, rows=len(rows),
-            live_pages=self._live_pages(rows))
-        if self.paged:
-            # map each live slot's write page before the step scatters to it
-            # (live growth bypasses the reclaim limit — see serve.pages).
-            # Grouped admission already reserved these pages, so this is a
-            # no-op except for banded archs (which skip the reservation)
-            dirty = False
-            for i in rows:
-                dirty |= self.pool.ensure_decode_page(
-                    i, int(self.positions[i]))
-            if dirty:
-                self._push_blocks()
-        t0 = time.perf_counter()
-        with self._ctx():
-            toks = jnp.asarray(self.cur_tokens)[:, None]
-            pos = jnp.asarray(self.positions)
-            if self.paged:
-                act = jnp.asarray(
-                    np.array([s is not None for s in self.slots]))
-                args = (self.params, toks, pos, act, self.caches)
-                cidx = 4
-            else:
-                args = (self.params, toks, pos, self.caches)
-                cidx = 3
-            out, new_caches = self._call_decode(
-                self._decodes[self._active], args, cidx)
-            self.caches = new_caches
-            self.decode_dispatches += 1
-            if sid:
-                self.spans.close(sid)
-            if self.paged:
-                # the step's single drain point: admission chunks were
-                # dispatched BEFORE the decode, so draining them here never
-                # waits on the decode — their compute overlapped its
-                # dispatch (satellite of the megastep pipeline)
-                self._drain_admissions()
-            # fused greedy: ``out`` is (B,) sampled token ids — B*4 bytes
-            # off-device per step instead of the (B, V) logits matrix
-            sid = self.spans.on and self.spans.open("decode.drain")
-            tb = time.perf_counter()
-            out = np.asarray(out)
-            self.drain_block_s += time.perf_counter() - tb
-        dt = time.perf_counter() - t0
-        self.step_latencies.append(dt)
-        for entry in self._recovering:
-            # recovery = event application -> first COMPLETED decode step on
-            # the re-homed mesh (compile time of the cutover step included)
-            entry["recovery_steps"] = \
-                len(self.step_latencies) - entry["step_index"]
-            entry["recovery_s"] = time.perf_counter() - entry.pop("_t_rehome")
-        self._recovering.clear()
-        now = time.perf_counter()
-        if self._fused_sample:
-            nxt_tokens = out[rows]
-        else:
-            nxt_tokens = self._sample_rows(
-                out[rows], [self.slots[i] for i in rows])
-        freed = False
-        for i, nxt in zip(rows, nxt_tokens):
-            req = self.slots[i]
-            nxt = int(nxt)
-            self.positions[i] += 1
-            req.out.append(nxt)
-            req.token_times.append(now)
-            self.cur_tokens[i] = nxt
-            self.row_dispatches += 1
-            self.row_tokens += 1
-            if len(req.out) >= req.max_new or (
-                    self.eos_id >= 0 and nxt == self.eos_id):
-                req.done = True
-                self.slots[i] = None            # slot freed: continuous batch
-                self._rngs.pop(req.uid, None)
-                if self.paged:
-                    freed |= self._free_slot(i)
-            elif self._window_free:
-                # banded arch: pages that fell out of every layer's window
-                # are dead — return them so long decodes hold occupancy flat
-                freed |= self.pool.release_window_pages(
-                    i, int(self.positions[i]) - self._window_free)
-        if freed:
-            self._push_blocks()
-        if sid:
-            self.spans.close(sid, tokens=len(rows))
-        if self.paged:
-            self.pool.replenish()      # watermark top-up, off the admission
-        self._token_lat.extend([dt] * len(rows))   # path (between steps)
-        self._control_tick()
+        self._megastep_round()
 
     def _control_tick(self) -> None:
         """Monitor -> controller -> actuator at the step boundary. Variant

@@ -91,46 +91,6 @@ def _mamba_block_with_state(params, h, cfg, knobs):
     return h, cache
 
 
-def prefill_chunk(params, tokens, start, caches, cfg: ModelConfig,
-                  knobs: ApproxKnobs = PRECISE, *, mesh=None,
-                  use_kernel: Optional[bool] = None, interpret: bool = False):
-    """One prompt chunk against existing decode caches (chunked admission).
-
-    tokens: (B, C); start: scalar int32 absolute position of the chunk's
-    first token (traced — one executable serves every chunk of length C);
-    caches: ``lm.init_caches`` layout. Returns (last-token logits (B,V) fp32,
-    advanced caches). Iterating this over prompt chunks is the serving
-    admission path: 32k prompts stream through fixed-size executables instead
-    of one O(prompt) warmup per token or one giant full-sequence compile.
-
-    Under a ``mesh`` each chunk's attention goes ring-sequence-parallel when
-    ``dist.sharding.prefill_plan(cfg, mesh, C)`` applies (the same pure plan
-    the engine and the explorer's pricing derive), else the loud unsharded
-    fallback; ``use_kernel``/``interpret`` mirror the decode dispatch knobs.
-    """
-    from repro.models.blocks import block_prefill
-    h = params["embed"][tokens]
-    B, C, D = h.shape
-    positions = start + jnp.broadcast_to(jnp.arange(C), (B, C))
-    shared = params.get("shared")
-
-    def group_body(h, xs):
-        group_params, group_caches = xs
-        new_caches = []
-        for j, kind in enumerate(cfg.pattern):
-            p = shared if kind == SHARED_ATTN else group_params.get(f"pos{j}")
-            h, nc, _ = block_prefill(kind, p, h, positions, group_caches[j],
-                                     cfg, knobs, mesh=mesh,
-                                     use_kernel=use_kernel,
-                                     interpret=interpret)
-            new_caches.append(nc)
-        return h, tuple(new_caches)
-
-    h, new_caches = jax.lax.scan(group_body, h, (params["groups"], caches))
-    h = rms_norm(h, params["final_norm"], cfg.norm_eps)
-    return logits_fn(params, h[:, -1], cfg), new_caches
-
-
 PAD_TOKEN = -1      # pad entry of a paged admission chunk
 
 
@@ -151,10 +111,9 @@ def paged_prefill_chunk(params, tokens, start, caches, slot,
     """One prompt chunk for ONE slot of the paged engine caches.
 
     tokens: (1, C); start: traced scalar absolute position; slot: traced
-    scalar batch row. Unlike ``prefill_chunk`` (which fills a fresh
-    single-request cache that is then slot-scattered), this writes straight
-    into the batched page pool through the slot's block table — there is no
-    insert step, and prefix-shared pages are simply already mapped. Returns
+    scalar batch row. The chunk writes straight into the batched page pool
+    through the slot's block table — prefix-shared pages are simply already
+    mapped. Returns
     (last-valid-token logits (1,V) fp32, advanced caches).
 
     The chunk may end in pad entries (``PAD_TOKEN``) after n >= 1 valid

@@ -1,9 +1,7 @@
-"""Slot-scatter helpers for chunked-prefill admission.
+"""Cache re-encoding across a variant hot-swap.
 
-``insert_request`` splices a prefilled single-request cache into the engine's
-batched KV/Mamba caches; ``convert_caches`` re-encodes the KV rings when a
-variant hot-swap crosses the ``kv_quant`` boundary. Both are pure pytree
-functions (jit-friendly; ``slot`` may be traced).
+``convert_caches`` re-encodes the KV pages (or rings) when a variant
+hot-swap crosses the ``kv_quant`` boundary: a pure pytree function.
 """
 from __future__ import annotations
 
@@ -13,34 +11,6 @@ import jax.numpy as jnp
 from repro.models.attention import (KVCache, KV_SCALE, PagedKVCache,
                                     PagedLatentCache,
                                     dequantize_kv, quantize_kv)
-from repro.models.mamba2 import MambaCache
-
-
-def insert_request(batched, single, slot):
-    """Scatter a prefilled 1-request cache tree into batch row ``slot``.
-
-    Both trees are in ``lm.init_caches`` layout (leaves stacked over layer
-    groups, batch at axis 1). Attention rings are rotated so the request's
-    entries occupy exactly the slots a token-by-token warmup ending at the
-    engine's current cursor would have filled — subsequent decode writes land
-    after them and never clobber a live prompt entry until the ring genuinely
-    wraps. The batched cursor (global, shared by all slots) is kept.
-    """
-    def one(bc, sc):
-        if isinstance(bc, KVCache):
-            W = bc.k.shape[2]
-            shift = (bc.cursor[0] - sc.cursor[0]) % W
-            roll = lambda x: jnp.roll(x, shift, axis=2)
-            return KVCache(
-                k=bc.k.at[:, slot].set(roll(sc.k)[:, 0]),
-                v=bc.v.at[:, slot].set(roll(sc.v)[:, 0]),
-                pos=bc.pos.at[:, slot].set(roll(sc.pos)[:, 0]),
-                cursor=bc.cursor)
-        assert isinstance(bc, MambaCache), type(bc)
-        return MambaCache(*(b.at[:, slot].set(s[:, 0])
-                            for b, s in zip(bc, sc)))
-
-    return tuple(one(b, s) for b, s in zip(batched, single))
 
 
 def convert_caches(caches, kv_quant: bool, dtype=jnp.float32):

@@ -158,10 +158,9 @@ def make_paged_serve_step(cfg: ModelConfig, knobs: ApproxKnobs = PRECISE, *,
                           ep_axis: Optional[str] = None, mesh=None,
                           use_kernel: Optional[bool] = None,
                           dynamic_scatter: bool = False,
-                          sample_greedy: bool = False,
                           interpret: bool = False):
     """Returns decode_step(params, tokens, position, active, caches)
-    -> (logits_or_tokens, new_caches) — the paged engine's decode cell.
+    -> (logits, new_caches) — one decode step over the paged pool.
 
     ``active`` (B,) bool masks per-slot cache writes so decode steps can
     interleave with a background admission: the admitting slot's mapped
@@ -174,22 +173,15 @@ def make_paged_serve_step(cfg: ModelConfig, knobs: ApproxKnobs = PRECISE, *,
     ``dynamic_scatter`` selects the O(1)-per-entry dynamic cache write
     (single-device pools only — the sharded kernel path does its own
     dynamic write inside the shard; see
-    ``attention.paged_decode_attention``).
-    ``sample_greedy`` fuses argmax into the executable and returns (B,)
-    int32 tokens instead of (B, V) logits: the greedy engine then moves
-    B*4 bytes per step off-device instead of the full logits matrix."""
+    ``attention.paged_decode_attention``)."""
     decode = api.decode_fn(cfg)
     assert cfg.family != "encdec", "paged serving: decoder-only path"
 
     def decode_step(params, tokens, position, active, caches):
-        logits, caches = decode(params, tokens, position, caches, knobs=knobs,
-                                ep_axis=ep_axis, mesh=mesh, active=active,
-                                use_kernel=use_kernel,
-                                dyn_scatter=dynamic_scatter,
-                                interpret=interpret)
-        if sample_greedy:
-            return jnp.argmax(logits, axis=-1).astype(jnp.int32), caches
-        return logits, caches
+        return decode(params, tokens, position, caches, knobs=knobs,
+                      ep_axis=ep_axis, mesh=mesh, active=active,
+                      use_kernel=use_kernel, dyn_scatter=dynamic_scatter,
+                      interpret=interpret)
     return decode_step
 
 
@@ -221,28 +213,6 @@ def make_paged_megastep(cfg: ModelConfig, knobs: ApproxKnobs = PRECISE, *,
             ep_axis=ep_axis, mesh=mesh, use_kernel=use_kernel,
             dyn_scatter=dynamic_scatter, interpret=interpret)
     return decode_megastep
-
-
-def make_admission_step(cfg: ModelConfig, knobs: ApproxKnobs = PRECISE, *,
-                        mesh=None, use_kernel: Optional[bool] = None,
-                        interpret: bool = False):
-    """Returns prefill_chunk(params, tokens, start, caches)
-    -> (logits, caches).
-
-    One prompt chunk against existing decode caches — the serving engine's
-    chunked-prefill admission cell. ``start`` is traced, so ONE executable
-    per (variant, chunk length) serves every chunk of a streaming prompt.
-    Under a ``mesh`` the chunk attention dispatches on
-    ``dist.sharding.prefill_plan`` (ring sequence parallelism);
-    ``use_kernel``/``interpret`` mirror ``make_paged_serve_step``."""
-    from repro.serve import prefill as prefill_mod
-
-    def prefill_chunk(params, tokens, start, caches):
-        return prefill_mod.prefill_chunk(params, tokens, start, caches, cfg,
-                                         knobs=knobs, mesh=mesh,
-                                         use_kernel=use_kernel,
-                                         interpret=interpret)
-    return prefill_chunk
 
 
 def make_paged_admission_step(cfg: ModelConfig, knobs: ApproxKnobs = PRECISE,

@@ -1,9 +1,9 @@
 """Continuous batching: admissions opened mid-run interleave with live
-decoders yet the paged engine stays token-identical to the dense ring
-engine (greedy sampling and per-request PRNG streams make outputs
-scheduling-invariant); the per-step prefill-chunk spend never exceeds the
-QoS budget; and the batched sampler consumes exactly the same per-request
-random streams as a one-row-at-a-time loop."""
+decoders yet the paged engine stays token-identical to the model-level
+token-by-token reference (greedy sampling and per-request PRNG streams
+make outputs scheduling-invariant); the per-step prefill-chunk spend never
+exceeds the QoS budget; and the batched sampler consumes exactly the same
+per-request random streams as a one-row-at-a-time loop."""
 import functools
 from types import SimpleNamespace
 
@@ -18,6 +18,7 @@ from repro.configs import get_config
 from repro.core.monitor import LatencyMonitor
 from repro.models import api
 from repro.serve.engine import Request, ServeEngine
+from tests._reference import greedy_warmup_ref
 
 _PARAMS = {}
 
@@ -37,21 +38,13 @@ def test_midrun_admission_interleaves_and_matches_dense(name):
     """Requests submitted while earlier ones are mid-decode are admitted
     into freed slots chunk-by-chunk BETWEEN decode steps (no wave barrier),
     with several admissions in flight at once — and every request's token
-    stream equals the dense engine's."""
+    stream equals the token-by-token reference's."""
     cfg, params = setup(name)
     rng = np.random.default_rng(9)
     prompts = [list(rng.integers(1, cfg.vocab_size, 7)) for _ in range(5)]
 
-    dense_eng = ServeEngine(cfg, batch_slots=3, max_len=64, params=params,
-                            prefill_chunk=3, paged=False)
-    dense_reqs = [Request(i, prompt=list(p), max_new=6)
-                  for i, p in enumerate(prompts)]
-    for r in dense_reqs:
-        dense_eng.submit(r)
-    dense_eng.run()
-
     eng = ServeEngine(cfg, batch_slots=3, max_len=64, params=params,
-                      prefill_chunk=3, paged=True, page_size=4)
+                      prefill_chunk=3, page_size=4)
     reqs = [Request(i, prompt=list(p), max_new=6)
             for i, p in enumerate(prompts)]
     eng.submit(reqs[0])
@@ -74,7 +67,8 @@ def test_midrun_admission_interleaves_and_matches_dense(name):
     # no runtime): admissions MUST have overlapped each other and decode
     assert concurrent >= 2, concurrent
     assert interleaved
-    assert [r.out for r in reqs] == [r.out for r in dense_reqs]
+    assert [r.out for r in reqs] == [
+        greedy_warmup_ref(cfg, params, p, 6) for p in prompts]
     # property: no step spent more prefill chunks than its QoS budget, and
     # with live decoders and no monitor evidence the budget is exactly 1
     assert eng.step_admission_chunks

@@ -153,7 +153,7 @@ def test_collective_failure_retries_preserve_tokens():
 
     def run(faults):
         eng = ServeEngine(cfg, batch_slots=2, max_len=32, params=params,
-                          paged=True, page_size=4, prefill_chunk=4)
+                          page_size=4, prefill_chunk=4)
         reqs = [Request(i, prompt=p, max_new=5) for i, p in
                 enumerate(prompts)]
         for r in reqs:
@@ -178,7 +178,7 @@ def test_quota_cut_is_separate_from_reclaim_ledger():
     from repro.serve.engine import Request, ServeEngine
     cfg, params = _setup()
     eng = ServeEngine(cfg, batch_slots=2, max_len=32, params=params,
-                      paged=True, page_size=4, prefill_chunk=4)
+                      page_size=4, prefill_chunk=4)
     pool = eng.pool
     base_limit = pool.limit
     eng.inject(CapacityEvent(elastic.QUOTA_CUT, 0, quanta=1))
@@ -203,7 +203,7 @@ def test_revoke_without_mesh_is_pressure_only():
     from repro.serve.engine import Request, ServeEngine
     cfg, params = _setup()
     eng = ServeEngine(cfg, batch_slots=2, max_len=32, params=params,
-                      paged=True, page_size=4)
+                      page_size=4)
     eng.inject(CapacityEvent(elastic.REVOKE, 0, count=1))
     r = Request(0, prompt=[3, 1, 4], max_new=4)
     _serve(eng, [r])
@@ -243,12 +243,12 @@ def test_blocked_admission_backs_off_then_recovers():
     prompt = list(rng.integers(1, cfg.vocab_size, 6))
 
     ref_eng = ServeEngine(cfg, batch_slots=2, max_len=32, params=params,
-                          paged=True, page_size=4, prefill_chunk=4)
+                          page_size=4, prefill_chunk=4)
     ref = Request(0, prompt=list(prompt), max_new=5)
     _serve(ref_eng, [ref])
 
     eng = ServeEngine(cfg, batch_slots=2, max_len=32, params=params,
-                      paged=True, page_size=4, prefill_chunk=4)
+                      page_size=4, prefill_chunk=4)
     # external quota grab floors the budget to zero: admissions block
     eng.pool.set_capacity_cut(eng.pool.max_quanta + eng.pool.spec.usable)
     req = Request(0, prompt=list(prompt), max_new=5)
@@ -379,7 +379,7 @@ prompts = [list(rng.integers(1, cfg.vocab_size, 7)) for _ in range(8)]
 def run(script):
     mesh = make_mesh((4, 2), ("data", "model"))
     eng = ServeEngine(cfg, batch_slots=4, max_len=32, params=params,
-                      mesh=mesh, paged=True, page_size=4, prefill_chunk=3)
+                      mesh=mesh, page_size=4, prefill_chunk=3)
     reqs = [Request(i, prompt=list(p), max_new=6)
             for i, p in enumerate(prompts)]
     for r in reqs:

@@ -6,7 +6,7 @@ import pytest
 
 from repro.launch import compile_cache, serve as serve_mod
 
-ARGV = ["--arch", "phi4-mini-3.8b-smoke", "--paged", "--megastep", "4",
+ARGV = ["--arch", "phi4-mini-3.8b-smoke", "--megastep", "4",
         "--page-size", "4", "--slots", "2", "--requests", "3",
         "--max-len", "32", "--prompt-len", "8", "--prefill-chunk", "4",
         "--max-new", "5"]

@@ -1,7 +1,7 @@
 """Megastep decode: the fused K-token dispatch must be a pure perf knob.
 
 The contract is STREAM invariance, not host-numpy bit parity: greedy
-megastep output must equal the per-step paged engine token-for-token across
+megastep output must equal the model-level token-by-token reference across
 all four cache families, and temperature output must be invariant in K
 (the on-device sampler keys every draw by (seed, uid, draw_index), so the
 megastep width cannot change the stream). EOS inside a megastep must stop
@@ -17,6 +17,7 @@ import pytest
 from repro.configs import get_config
 from repro.models import api
 from repro.serve.engine import Request, ServeEngine
+from tests._reference import greedy_warmup_ref
 
 ARCHS = ["phi4-mini-3.8b",     # MHA
          "zamba2-2.7b",        # hybrid attn/SSM (+shared)
@@ -35,7 +36,7 @@ def setup(name):
 
 def drive(cfg, params, prompts, max_new=5, *, slots=2, chunk=3, **kw):
     eng = ServeEngine(cfg, batch_slots=slots, max_len=64, params=params,
-                      prefill_chunk=chunk, paged=True, page_size=4, **kw)
+                      prefill_chunk=chunk, page_size=4, **kw)
     reqs = [Request(i, prompt=list(p), max_new=max_new)
             for i, p in enumerate(prompts)]
     for r in reqs:
@@ -55,11 +56,11 @@ def prompts_for(cfg, n=5, length=7, seed=3):
 @pytest.mark.parametrize("name", ARCHS)
 @pytest.mark.parametrize("k", [1, 4, 16])
 def test_megastep_greedy_matches_per_step(name, k):
-    """Greedy megastep(K) == the per-step paged engine, token for token,
+    """Greedy megastep(K) == the token-by-token reference, token for token,
     through multiple admission waves (5 requests / 2 slots)."""
     cfg, params = setup(name)
     prompts = prompts_for(cfg)
-    base, _ = drive(cfg, params, prompts)
+    base = [greedy_warmup_ref(cfg, params, p, 5) for p in prompts]
     out, eng = drive(cfg, params, prompts, megastep_k=k)
     assert out == base, (name, k, out, base)
     assert eng.decode_dispatches > 0
@@ -111,7 +112,7 @@ def test_donation_active_in_compiled_megastep():
     from repro.train import step as step_mod
     cfg, params = setup("phi4-mini-3.8b")
     eng = ServeEngine(cfg, batch_slots=2, max_len=64, params=params,
-                      prefill_chunk=3, paged=True, page_size=4, megastep_k=4)
+                      prefill_chunk=3, page_size=4, megastep_k=4)
     step = step_mod.make_paged_megastep(cfg, k=4, dynamic_scatter=True)
     B = eng.batch_slots
     zi = jnp.zeros((B,), jnp.int32)
@@ -129,7 +130,7 @@ def test_donation_consumes_stale_cache_references():
     instead of double-buffering the KV pool."""
     cfg, params = setup("zamba2-2.7b")
     eng = ServeEngine(cfg, batch_slots=2, max_len=64, params=params,
-                      prefill_chunk=3, paged=True, page_size=4, megastep_k=4)
+                      prefill_chunk=3, page_size=4, megastep_k=4)
     req = Request(0, prompt=prompts_for(cfg)[0], max_new=6)
     eng.submit(req)
     while not req.out:          # admit until the slot decodes
@@ -147,7 +148,7 @@ def test_donation_off_keeps_buffers_alive():
     (tests/tools that hold cache references stay valid)."""
     cfg, params = setup("phi4-mini-3.8b")
     eng = ServeEngine(cfg, batch_slots=2, max_len=64, params=params,
-                      prefill_chunk=3, paged=True, page_size=4,
+                      prefill_chunk=3, page_size=4,
                       megastep_k=4, donate=False)
     req = Request(0, prompt=prompts_for(cfg)[0], max_new=5)
     eng.submit(req)
@@ -166,7 +167,7 @@ def test_per_uid_rng_streams_match_fresh_generators():
     advance or cross the streams."""
     cfg, params = setup("phi4-mini-3.8b")
     eng = ServeEngine(cfg, batch_slots=2, max_len=64, params=params,
-                      prefill_chunk=3, paged=True, page_size=4,
+                      prefill_chunk=3, page_size=4,
                       temperature=0.8, seed=5)
     draws = {}
     for uid in (3, 9, 3, 9, 3):
@@ -191,7 +192,7 @@ def test_megastep_pipeline_survives_variant_swap():
                           Variant(ApproxKnobs(kv_quant=True), 0.8, 0.01)])
     prompts = prompts_for(cfg, n=4, length=6)
     eng = ServeEngine(cfg, batch_slots=2, max_len=64, params=params,
-                      prefill_chunk=3, paged=True, page_size=4,
+                      prefill_chunk=3, page_size=4,
                       megastep_k=8, table=table)
     reqs = [Request(i, prompt=list(p), max_new=6)
             for i, p in enumerate(prompts)]
@@ -216,11 +217,7 @@ def test_megastep_pipeline_survives_variant_swap():
 def test_explain_megastep_banner():
     cfg, params = setup("phi4-mini-3.8b")
     eng = ServeEngine(cfg, batch_slots=2, max_len=64, params=params,
-                      paged=True, page_size=4, megastep_k=6)
+                      page_size=4, megastep_k=6)
     s = eng.explain_megastep()
     assert "6 tokens" in s and "donation ON" in s and "pipeline" in s
     assert "megastep scan" in eng.explain_dispatch()
-    off = ServeEngine(cfg, batch_slots=2, max_len=64, params=params,
-                      paged=True, page_size=4)
-    assert "off" in off.explain_megastep()
-    assert "megastep" not in off.explain_dispatch()

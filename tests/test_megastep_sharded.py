@@ -1,6 +1,6 @@
 """Megastep decode on an 8-simulated-device mesh (subprocess, like
 test_sharded_decode): the fused K-token dispatch must be token-identical to
-the single-device per-step engine across all four cache families, and the
+the single-device engine at K=1 across all four cache families, and the
 donation + async-pipeline machinery must survive an elastic revoke/restore
 mid-run with zero dropped requests — the drain point (`_drain_pipeline`)
 flushes the in-flight megastep before cache surgery, and the re-homed
@@ -36,14 +36,14 @@ for arch in %r:
     cfg = get_config(arch)
     params = api.init(cfg, jax.random.PRNGKey(0), jnp.float32)
     eng_m = ServeEngine(cfg, batch_slots=8, max_len=32, params=params,
-                        mesh=mesh, paged=True, page_size=4,
+                        mesh=mesh, page_size=4,
                         use_kernel=True, kernel_interpret=True,
                         megastep_k=4)
     assert "megastep scan" in eng_m.explain_dispatch()
     out_m = drive(eng_m, cfg)
     assert eng_m.row_dispatches / max(eng_m.row_tokens, 1) <= 1.0
     eng_1 = ServeEngine(cfg, batch_slots=8, max_len=32, params=params,
-                        paged=True, page_size=4, use_kernel=True,
+                        page_size=4, use_kernel=True,
                         kernel_interpret=True)
     out_1 = drive(eng_1, cfg)
     assert out_m == out_1, (arch, out_m, out_1)
@@ -80,7 +80,7 @@ prompts = [list(rng.integers(1, cfg.vocab_size, 7)) for _ in range(8)]
 def run(script):
     mesh = make_mesh((4, 2), ("data", "model"))
     eng = ServeEngine(cfg, batch_slots=4, max_len=32, params=params,
-                      mesh=mesh, paged=True, page_size=4, prefill_chunk=3,
+                      mesh=mesh, page_size=4, prefill_chunk=3,
                       megastep_k=4)
     assert eng.donate
     reqs = [Request(i, prompt=list(p), max_new=6)

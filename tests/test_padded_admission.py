@@ -118,7 +118,7 @@ def test_all_valid_block_is_the_plain_computation(length):
 def serve(name, lengths, pad=True, max_new=4):
     cfg, params = model(name)
     eng = ServeEngine(cfg, batch_slots=2, max_len=64, params=params,
-                      prefill_chunk=4, paged=True, page_size=4)
+                      prefill_chunk=4, page_size=4)
     if not pad:
         eng._pad_chunks = False           # the exact-length path
     asked = []

@@ -1,9 +1,9 @@
 """Paged cache subsystem: block-table engine equivalence, copy-on-write
 prefix reuse, and the Pliant-reclaimable page pool.
 
-The paged engine must reproduce the dense ring engine's greedy outputs
-EXACTLY across the attention / local+global / hybrid / pure-SSM cache
-families, including multi-wave slot reuse (stale-state hazards: reused
+The paged engine must reproduce the model-level token-by-token greedy
+reference EXACTLY across the attention / local+global / hybrid / pure-SSM
+cache families, including multi-wave slot reuse (stale-state hazards: reused
 pages' positions, reused slots' Mamba state). A shared-prefix workload must
 HIT the prefix index and skip the covered prefill chunks; a pool shrink /
 regrow round-trip — manual and controller-driven — must never corrupt a
@@ -23,6 +23,7 @@ from repro.core.variants import Variant, VariantTable
 from repro.launch.serve import serving_table
 from repro.models import api
 from repro.serve.engine import Request, ServeEngine
+from tests._reference import greedy_warmup_ref
 
 _PARAMS = {}
 
@@ -34,10 +35,10 @@ def setup(name):
     return cfg, _PARAMS[name]
 
 
-def drive(cfg, params, prompts, max_new=5, *, paged, page_size=4, n_pages=0,
+def drive(cfg, params, prompts, max_new=5, *, page_size=4, n_pages=0,
           slots=2, max_len=64, chunk=3, **kw):
     eng = ServeEngine(cfg, batch_slots=slots, max_len=max_len, params=params,
-                      prefill_chunk=chunk, paged=paged, page_size=page_size,
+                      prefill_chunk=chunk, page_size=page_size,
                       n_pages=n_pages, **kw)
     reqs = [Request(i, prompt=list(p), max_new=max_new)
             for i, p in enumerate(prompts)]
@@ -46,6 +47,11 @@ def drive(cfg, params, prompts, max_new=5, *, paged, page_size=4, n_pages=0,
     eng.run()
     assert all(r.done for r in reqs)
     return [r.out for r in reqs], eng
+
+
+def reference(cfg, params, prompts, max_new=5, max_len=64):
+    return [greedy_warmup_ref(cfg, params, list(p), max_new, max_len)
+            for p in prompts]
 
 
 @pytest.mark.parametrize("name", ["phi4-mini-3.8b",     # attention
@@ -58,9 +64,9 @@ def test_paged_matches_dense_engine(name):
     # 5 requests through 2 slots: multiple admission waves reuse slots AND
     # (with the tight 16-page pool) recycle freed physical pages
     prompts = [list(rng.integers(1, cfg.vocab_size, 7)) for _ in range(5)]
-    dense, _ = drive(cfg, params, prompts, paged=False)
-    paged, eng = drive(cfg, params, prompts, paged=True, n_pages=16)
-    assert paged == dense, (name, paged, dense)
+    want = reference(cfg, params, prompts)
+    paged, eng = drive(cfg, params, prompts, n_pages=16)
+    assert paged == want, (name, paged, want)
     assert eng.pool.stats["frees"] > 0          # pages actually cycled
     assert eng.pool.used == 0 or eng.pool.index  # only prefix pins remain
 
@@ -70,16 +76,16 @@ def test_prefix_reuse_skips_chunks(name):
     """Shared-prompt traffic: later requests map the registered prefix pages
     copy-on-write and skip those prefill chunks entirely (SSM state restored
     from the boundary snapshot for hybrid archs) — with outputs still equal
-    to the dense engine's token-by-token."""
+    to the token-by-token reference's."""
     cfg, params = setup(name)
     rng = np.random.default_rng(7)
     prefix = list(rng.integers(1, cfg.vocab_size, 8))
     prompts = [prefix + list(rng.integers(1, cfg.vocab_size, 4))
                for _ in range(4)]
     prompts.append(list(prompts[0]))            # exact duplicate prompt
-    dense, _ = drive(cfg, params, prompts, paged=False)
-    paged, eng = drive(cfg, params, prompts, paged=True)
-    assert paged == dense, (paged, dense)
+    want = reference(cfg, params, prompts)
+    paged, eng = drive(cfg, params, prompts)
+    assert paged == want, (paged, want)
     s = eng.pool.stats
     # requests 1-3 share the 8-token (2-page) prefix; request 4 additionally
     # matches request 0's full pages capped at len-1 -> still 8 tokens
@@ -96,7 +102,7 @@ def test_prefix_hit_runs_fewer_chunks():
     rng = np.random.default_rng(11)
     prompt = list(rng.integers(1, cfg.vocab_size, 9))
     eng = ServeEngine(cfg, batch_slots=1, max_len=64, params=params,
-                      prefill_chunk=2, paged=True, page_size=4)
+                      prefill_chunk=2, page_size=4)
     calls = []
     orig = eng._prefill_exe
 
@@ -118,13 +124,13 @@ def test_prefix_hit_runs_fewer_chunks():
 
 def test_pool_shrink_regrow_roundtrip():
     """A manual pool_pages shrink/regrow mid-decode never corrupts live
-    requests: outputs stay equal to the dense engine's."""
+    requests: outputs stay equal to the token-by-token reference's."""
     cfg, params = setup("zamba2-2.7b")
     rng = np.random.default_rng(5)
     prompts = [list(rng.integers(1, cfg.vocab_size, 7)) for _ in range(4)]
-    dense, _ = drive(cfg, params, prompts, max_new=10, paged=False)
+    want = reference(cfg, params, prompts, max_new=10)
     eng = ServeEngine(cfg, batch_slots=2, max_len=64, params=params,
-                      prefill_chunk=3, paged=True, page_size=4)
+                      prefill_chunk=3, page_size=4)
     reqs = [Request(i, prompt=list(p), max_new=10)
             for i, p in enumerate(prompts)]
     for r in reqs:
@@ -136,7 +142,7 @@ def test_pool_shrink_regrow_roundtrip():
         eng.step()                              # decode under shrunk budget
     eng.pool.set_reclaimed(0)
     eng.run()
-    assert [r.out for r in reqs] == dense
+    assert [r.out for r in reqs] == want
     assert eng.pool.stats["reclaim_events"] == 2
     log = eng.pool.stats["reclaim_log"]
     assert [e["action"] for e in log] == ["shrink", "grow"]
@@ -222,14 +228,14 @@ def test_controller_driven_pool_reclaim():
     most-approximate variant RECLAIMs pool quanta (prefix cache evicted
     first, live requests untouched); slack RETURNs them before stepping
     toward precise — and a request served after the regrow matches the
-    precise dense reference."""
+    precise token-by-token reference."""
     cfg, params = setup("gemma2-27b")
     table = serving_table(cfg, slots=4, max_len=64)
     monitor = LatencyMonitor(qos_target_s=1e-7, window=256, min_samples=4)
     runtime = PliantRuntime(table, monitor,
                             ControllerConfig(decision_interval_s=0.0))
     eng = ServeEngine(cfg, batch_slots=4, max_len=64, params=params,
-                      runtime=runtime, paged=True, page_size=8)
+                      runtime=runtime, page_size=8)
     assert runtime.cfg.max_reclaim == eng.pool.max_quanta > 0
     reqs = [Request(i, prompt=[3 + i, 11, 7], max_new=10) for i in range(8)]
     for r in reqs:
@@ -258,53 +264,46 @@ def test_controller_driven_pool_reclaim():
     late = Request(999, prompt=[9, 8, 7], max_new=6)
     eng.submit(late)
     eng.run()
-    ref, _ = drive(cfg, params, [late.prompt], max_new=6, paged=False,
-                   slots=1)
-    assert late.out == ref[0]
+    assert late.out == greedy_warmup_ref(cfg, params, late.prompt, 6)
 
 
 def test_stall_free_admission_bounds_decoder_gaps():
     """A 32-chunk prompt admitted mid-run must not stall concurrent
     decoders: the engine runs AT MOST ONE admission chunk between decode
     executions (the stall-free budget), and every request's outputs still
-    match the dense ring engine token-by-token."""
+    match the token-by-token reference."""
     cfg, params = setup("phi4-mini-3.8b")
     rng = np.random.default_rng(13)
     chunk = 2
     long_prompt = list(rng.integers(1, cfg.vocab_size, 32 * chunk))
     shorts = [list(rng.integers(1, cfg.vocab_size, 5)) for _ in range(2)]
 
-    def run(paged):
-        eng = ServeEngine(cfg, batch_slots=3, max_len=96, params=params,
-                          prefill_chunk=chunk, paged=paged, page_size=4)
-        events = []
-        if paged:
-            orig = eng._prefill_exe
+    eng = ServeEngine(cfg, batch_slots=3, max_len=96, params=params,
+                      prefill_chunk=chunk, page_size=4)
+    events = []
 
-            def counting(C):
-                fn = orig(C)
-                return lambda *a, **k: (events.append("chunk"), fn(*a, **k))[1]
+    def recording(make, event):
+        def made(*a):
+            fn = make(*a)
+            return lambda *args: (events.append(event), fn(*args))[1]
+        return made
 
-            eng._prefill_exe = counting
-            for vi, fn in list(eng._decodes.items()):
-                eng._decodes[vi] = (
-                    lambda f: lambda *a, **k:
-                        (events.append("decode"), f(*a, **k))[1])(fn)
-        reqs = [Request(i, prompt=list(p), max_new=50)
-                for i, p in enumerate(shorts)]
-        for r in reqs:
-            eng.submit(r)
-        for _ in range(6):
-            eng.step()                  # shorts admitted and mid-decode
-        big = Request(9, prompt=list(long_prompt), max_new=4)
-        eng.submit(big)
-        eng.run()
-        assert all(r.done for r in reqs + [big])
-        return [r.out for r in reqs + [big]], events
-
-    dense, _ = run(paged=False)
-    paged, events = run(paged=True)
-    assert paged == dense, (paged, dense)
+    eng._prefill_exe = recording(eng._prefill_exe, "chunk")
+    eng._megastep_exe = recording(eng._megastep_exe, "decode")
+    reqs = [Request(i, prompt=list(p), max_new=50)
+            for i, p in enumerate(shorts)]
+    for r in reqs:
+        eng.submit(r)
+    for _ in range(6):
+        eng.step()                      # shorts admitted and mid-decode
+    big = Request(9, prompt=list(long_prompt), max_new=4)
+    eng.submit(big)
+    eng.run()
+    assert all(r.done for r in reqs + [big])
+    paged = [r.out for r in reqs + [big]]
+    want = [greedy_warmup_ref(cfg, params, r.prompt, r.max_new, 96)
+            for r in reqs + [big]]
+    assert paged == want, (paged, want)
     # after the first decode, no two admission chunks back-to-back: a long
     # prompt costs active decoders at most one chunk per token
     tail = events[events.index("decode"):]
@@ -329,7 +328,7 @@ def test_window_pages_freed_keeps_occupancy_flat():
 
     def run(window_free):
         eng = ServeEngine(cfg, batch_slots=1, max_len=128, params=params,
-                          prefill_chunk=5, paged=True, page_size=4)
+                          prefill_chunk=5, page_size=4)
         assert eng._window_free == (cfg.window if window_free else 0) or \
             not window_free
         if not window_free:
@@ -361,7 +360,8 @@ def test_window_pages_freed_keeps_occupancy_flat():
 def test_prefill_exe_cache_knob_keyed_and_bounded():
     """Admission executables are keyed by knobs (table entries with equal
     admission knobs share one compiled chunk cell), LRU-bounded, and evicted
-    on variant retirement only when no live variant shares the knobs."""
+    on variant retirement only when no live variant shares the knobs; a
+    retired variant's megastep executables go at once."""
     cfg, params = setup("phi4-mini-3.8b")
     int8 = ApproxKnobs(matmul_precision="int8")
     table = VariantTable([Variant(PRECISE, 1.0, 0.0),
@@ -370,10 +370,13 @@ def test_prefill_exe_cache_knob_keyed_and_bounded():
     eng = ServeEngine(cfg, batch_slots=2, max_len=32, params=params,
                       table=table)
     eng._prefill_exe(4)
+    eng._megastep_exe(1)
     eng.set_variant(1)
     eng._prefill_exe(4)
+    eng._megastep_exe(1)
     eng.set_variant(2)
     eng._prefill_exe(4)                         # shares variant 1's cell
+    eng._megastep_exe(1)
     assert len(eng._prefills) == 2
     assert eng._prefill_exe(4) is eng._prefill_exe(4)
 
@@ -382,13 +385,14 @@ def test_prefill_exe_cache_knob_keyed_and_bounded():
     assert any(k[0] == int8 for k in eng._prefills)
     eng.retire_variant(1)                       # last int8 user retired
     assert not any(k[0] == int8 for k in eng._prefills)
-    assert 1 not in eng._decodes and 2 not in eng._decodes
+    assert not any(v in (1, 2) for v, _ in eng._megasteps), eng._megasteps
+    assert (0, 1) in eng._megasteps
 
     # the LRU bound, on a paged SSM hybrid, whose admission programs stay
     # keyed per chunk length (its state would absorb pad tokens)
     hcfg, hparams = setup("zamba2-2.7b")
     hyb = ServeEngine(hcfg, batch_slots=2, max_len=32, params=hparams,
-                      paged=True, page_size=4, max_prefill_exes=2)
+                      page_size=4, max_prefill_exes=2)
     for c in (1, 2, 3, 5):
         hyb._prefill_exe(c)
     assert hyb.stats["prefill_exe_misses"] == 4
@@ -418,7 +422,7 @@ prompts = [prefix + list(rng.integers(1, cfg.vocab_size, 3))
 
 def run(mesh):
     eng = ServeEngine(cfg, batch_slots=4, max_len=32, params=params,
-                      mesh=mesh, prefill_chunk=3, paged=True, page_size=8)
+                      mesh=mesh, prefill_chunk=3, page_size=8)
     reqs = [Request(i, prompt=list(p), max_new=5)
             for i, p in enumerate(prompts)]
     for r in reqs:

@@ -217,7 +217,7 @@ def test_full_pool_serves_every_request():
     eng = ServeEngine(cfg, batch_slots=4, max_len=256,
                       params=api.init(cfg, jax.random.PRNGKey(0),
                                       jnp.float32),
-                      prefill_chunk=32, paged=True, page_size=16,
+                      prefill_chunk=32, page_size=16,
                       n_pages=40, megastep_k=4)
     assert eng.pool.spec.usable == 39
     rng = np.random.default_rng(13)

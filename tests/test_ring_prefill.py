@@ -45,7 +45,7 @@ def drive(eng, cfg, n_req=6, prompt_len=10, max_new=5, shared=4):
     return [list(r.out) for r in reqs]
 
 mesh = make_mesh((2, 4), ("data", "model"))
-kw = dict(batch_slots=8, max_len=32, paged=True, page_size=4,
+kw = dict(batch_slots=8, max_len=32, page_size=4,
           prefill_chunk=8)
 for arch in %r:
     cfg = get_config(arch)
@@ -81,8 +81,9 @@ print("ALL_OK")
 
 
 def test_ring_dense_engine_token_parity(subproc):
-    """The dense (ring-buffer cache) engine's admission path dispatches the
-    ring too — the concat [cache; chunk] route, not the paged gather."""
+    """Four slots over the 2x4 mesh, unshared 10-token prompts on the
+    local+global and hybrid archs: admission dispatches the ring, and the
+    tokens equal the single-device engine's."""
     out = subproc("""
 import numpy as np, jax, jax.numpy as jnp
 from repro.configs import get_config
@@ -138,7 +139,7 @@ mesh = make_mesh((2, 4), ("data", "model"))
 attn_mod.DISPATCH_COUNTS.clear()
 # kernel explicitly off under a mesh -> unsharded admission + one-line warn
 eng = ServeEngine(cfg, batch_slots=8, max_len=32, params=params, mesh=mesh,
-                  paged=True, page_size=4, prefill_chunk=8, use_kernel=False)
+                  page_size=4, prefill_chunk=8, use_kernel=False)
 assert not eng.sharded_prefill
 assert "unsharded" in eng.explain_prefill_dispatch(), \\
     eng.explain_prefill_dispatch()

@@ -7,28 +7,16 @@ import pytest
 
 from repro.approx.knobs import ApproxKnobs
 from repro.configs import get_config
-from repro.models import api, lm
+from repro.models import api
 from repro.serve.engine import Request, ServeEngine
+from tests._reference import greedy_warmup_ref
 
 CFG = get_config("gemma2-27b-smoke")
 PARAMS = api.init(CFG, jax.random.PRNGKey(0), jnp.float32)
 
 
-def greedy_ref(prompt, n, max_len=64):
-    caches = lm.init_caches(CFG, 1, max_len, dtype=jnp.float32)
-    step = jax.jit(lambda p, t, po, c: lm.decode_step(p, t, po, c, CFG))
-    out, cursor, cur, pos = [], 0, prompt[0], 0
-    while len(out) < n:
-        logits, caches = step(PARAMS, jnp.asarray([[cur]]),
-                              jnp.asarray([pos]), caches)
-        pos += 1
-        if cursor + 1 < len(prompt):
-            cursor += 1
-            cur = prompt[cursor]
-            continue
-        cur = int(jnp.argmax(logits[0]))
-        out.append(cur)
-    return out
+def greedy_ref(prompt, n):
+    return greedy_warmup_ref(CFG, PARAMS, prompt, n)
 
 
 def test_continuous_batching_matches_greedy():
@@ -93,3 +81,13 @@ def test_int8_kv_quant_variant_close():
     agree = np.mean([a == b for ra, rb in zip(outs["p"], outs["a"])
                      for a, b in zip(ra, rb)])
     assert agree >= 0.5, (agree, outs)    # bounded quality loss, not garbage
+
+
+@pytest.mark.parametrize("kw", [dict(paged=False), dict(megastep_k=0)],
+                         ids=["paged_false", "megastep_0"])
+def test_engine_has_one_decode_loop(kw):
+    """The engine serves only the paged pool through megasteps of at least
+    one token: asking for ring caches or for K = 0 is an error, not a
+    silent fallback."""
+    with pytest.raises(ValueError):
+        ServeEngine(CFG, batch_slots=2, max_len=64, params=PARAMS, **kw)

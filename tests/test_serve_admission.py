@@ -1,10 +1,11 @@
 """Chunked-prefill admission and Pliant-controlled serving.
 
-Engine-level equivalence: admission via ``prefill_chunk`` + slot scatter must
-reproduce the seed token-by-token warmup outputs EXACTLY (greedy) for the
-attention, hybrid, and Mamba cache families. Control: a forced QoS violation
-must make ``PliantRuntime`` hot-swap the serving variant mid-run — crossing
-the ``kv_quant`` boundary both ways — with decode continuing across the swap.
+Engine-level equivalence: admission via chunked prefill into the page pool
+must reproduce the seed token-by-token warmup outputs EXACTLY (greedy) for
+the attention, hybrid, and Mamba cache families. Control: a forced QoS
+violation must make ``PliantRuntime`` hot-swap the serving variant mid-run —
+crossing the ``kv_quant`` boundary both ways — with decode continuing
+across the swap.
 """
 import jax
 import jax.numpy as jnp
@@ -16,9 +17,10 @@ from repro.core.controller import ControllerConfig
 from repro.core.monitor import LatencyMonitor
 from repro.core.runtime import PliantRuntime
 from repro.launch.serve import serving_table
-from repro.models import api, lm
-from repro.models.attention import KVCache
+from repro.models import api
+from repro.models.attention import PagedKVCache
 from repro.serve.engine import Request, ServeEngine
+from tests._reference import greedy_warmup_ref
 
 _PARAMS = {}
 
@@ -30,27 +32,6 @@ def setup(name):
     return cfg, _PARAMS[name]
 
 
-def greedy_warmup_ref(cfg, params, prompt, n, max_len=64, knobs=None):
-    """The seed engine's admission: prompt fed through decode steps."""
-    from repro.approx.knobs import PRECISE
-    kn = knobs or PRECISE
-    caches = lm.init_caches(cfg, 1, max_len, dtype=jnp.float32,
-                            quantized=kn.kv_quant)
-    step = jax.jit(lambda p, t, po, c: lm.decode_step(p, t, po, c, cfg, kn))
-    out, cursor, cur, pos = [], 0, prompt[0], 0
-    while len(out) < n:
-        logits, caches = step(params, jnp.asarray([[cur]]),
-                              jnp.asarray([pos]), caches)
-        pos += 1
-        if cursor + 1 < len(prompt):
-            cursor += 1
-            cur = prompt[cursor]
-            continue
-        cur = int(jnp.argmax(logits[0]))
-        out.append(cur)
-    return out
-
-
 @pytest.mark.parametrize("name", ["phi4-mini-3.8b",     # attention
                                   "zamba2-2.7b",        # hybrid (+shared)
                                   "mamba2-780m",        # pure SSM
@@ -59,7 +40,7 @@ def test_admission_matches_tokenwise_warmup(name):
     cfg, params = setup(name)
     rng = np.random.default_rng(3)
     # prompt (7) > prefill_chunk (3): exercises multi-chunk admission with a
-    # ragged tail; 4 requests through 2 slots: staggered ring offsets
+    # ragged tail; 4 requests through 2 slots: reused slots and pages
     eng = ServeEngine(cfg, batch_slots=2, max_len=64, params=params,
                       prefill_chunk=3)
     reqs = [Request(uid, prompt=list(rng.integers(1, cfg.vocab_size, 7)),
@@ -120,9 +101,9 @@ def test_forced_qos_swap_crosses_kvq_boundary():
         "swap must happen mid-run, not after drain"
     assert all(r.done and len(r.out) == 10 for r in reqs), \
         "decode must continue across the swap"
-    kv = [c for c in eng.caches if isinstance(c, KVCache)]
-    assert kv and all(c.k.dtype == jnp.int8 for c in kv), \
-        "crossing into kv_quant must convert the KV rings to int8"
+    kv = [c for c in eng.caches if isinstance(c, PagedKVCache)]
+    assert kv and all(c.kp.dtype == jnp.int8 for c in kv), \
+        "crossing into kv_quant must convert the KV pages to int8"
     assert any(h["action"] == "set_most_approx" for h in runtime.history)
 
     # relax the target -> controller steps back toward precise one variant
@@ -138,9 +119,9 @@ def test_forced_qos_swap_crosses_kvq_boundary():
         assert all(r.done for r in more)
         guard += 1
     assert eng.active_variant == 0, runtime.history
-    kv = [c for c in eng.caches if isinstance(c, KVCache)]
-    assert all(c.k.dtype == jnp.float32 for c in kv), \
-        "leaving kv_quant must convert the KV rings back"
+    kv = [c for c in eng.caches if isinstance(c, PagedKVCache)]
+    assert all(c.kp.dtype == jnp.float32 for c in kv), \
+        "leaving kv_quant must convert the KV pages back"
     assert any(h["action"] == "step_toward_precise" for h in runtime.history)
 
     # a request served entirely under the restored precise variant matches

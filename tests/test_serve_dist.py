@@ -1,7 +1,8 @@
 """Multi-device serving on an 8-device host mesh (subprocess, like
 test_dist): engine with params sharded via ``dist.param_shardings`` and
-caches via ``dist.cache_shardings`` produces the same greedy outputs as the
-single-device engine, including across a kv_quant variant hot-swap."""
+the page pool via ``dist.cache_shardings`` produces the same greedy outputs
+as the single-device engine, including across a kv_quant variant
+hot-swap."""
 import pytest
 
 
@@ -13,7 +14,7 @@ from repro.configs import get_config
 from repro.launch.mesh import make_mesh
 from repro.launch.serve import serving_table
 from repro.models import api
-from repro.models.attention import KVCache
+from repro.models.attention import PagedKVCache
 from repro.serve.engine import Request, ServeEngine
 
 cfg = get_config("phi4-mini-3.8b-smoke")
@@ -43,12 +44,14 @@ eng_ref, ref = run(None)
 mesh = make_mesh((2, 4), ("data", "model"))
 eng_sh, got = run(mesh)
 assert got == ref, (got, ref)
-kv = [c for c in eng_sh.caches if isinstance(c, KVCache)]
+kv = [c for c in eng_sh.caches if isinstance(c, PagedKVCache)]
 assert kv
 for c in kv:
-    assert c.k.dtype == jnp.int8                       # converted under mesh
-    assert c.k.sharding.spec == P(None, "data", "model", None, None), \\
-        c.k.sharding                                    # dist.cache_shardings
+    assert c.kp.dtype == jnp.int8                      # converted under mesh
+    # slot-affinity pool (dist.cache_shardings): pages over the batch axis;
+    # the smoke kv_heads do not divide the model axis, so they replicate
+    assert c.kp.sharding.spec == P(None, "data", None, None, None), \\
+        c.kp.sharding
 ps = jax.tree.leaves(eng_sh.params)
 assert any("model" in (s.sharding.spec or ()) for s in ps), \\
     "params must be TP-sharded"

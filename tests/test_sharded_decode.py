@@ -40,7 +40,7 @@ for arch in %r:
     params = api.init(cfg, jax.random.PRNGKey(0), jnp.float32)
     attn_mod.DISPATCH_COUNTS.clear()
     eng_s = ServeEngine(cfg, batch_slots=8, max_len=32, params=params,
-                        mesh=mesh, paged=True, page_size=4,
+                        mesh=mesh, page_size=4,
                         use_kernel=True, kernel_interpret=True)
     assert eng_s.sharded_kernel, arch
     assert "shard_map'd" in eng_s.explain_dispatch(), \\
@@ -53,11 +53,11 @@ for arch in %r:
         assert counts.get("kernel_sharded", 0) > 0, (arch, counts)
     assert counts.get("gather_mesh", 0) == 0, (arch, counts)
     eng_1 = ServeEngine(cfg, batch_slots=8, max_len=32, params=params,
-                        paged=True, page_size=4, use_kernel=True,
+                        page_size=4, use_kernel=True,
                         kernel_interpret=True)
     out_1 = drive(eng_1, cfg)
     eng_g = ServeEngine(cfg, batch_slots=8, max_len=32, params=params,
-                        paged=True, page_size=4, use_kernel=False)
+                        page_size=4, use_kernel=False)
     out_g = drive(eng_g, cfg)
     assert out_s == out_1 == out_g, (arch, out_s, out_1, out_g)
     assert all(len(t) == 5 for t in out_s), out_s
@@ -87,7 +87,7 @@ mesh = make_mesh((2, 4), ("data", "model"))
 attn_mod.DISPATCH_COUNTS.clear()
 # kernel explicitly off under a mesh -> gather path + one-line warning
 eng = ServeEngine(cfg, batch_slots=8, max_len=32, params=params, mesh=mesh,
-                  paged=True, page_size=4, use_kernel=False)
+                  page_size=4, use_kernel=False)
 assert not eng.sharded_kernel
 assert "gather" in eng.explain_dispatch(), eng.explain_dispatch()
 r = Request(0, prompt=list(np.arange(1, 9)), max_new=3)

@@ -28,7 +28,7 @@ def model():
 def engine(megastep_k=4):
     cfg, params = model()
     return ServeEngine(cfg, batch_slots=2, max_len=64, params=params,
-                       prefill_chunk=4, paged=True, page_size=4,
+                       prefill_chunk=4, page_size=4,
                        megastep_k=megastep_k)
 
 
@@ -60,7 +60,7 @@ def test_spans_off_record_nothing():
     assert eng.stats["programs_obtained"] > 0     # counters count regardless
 
 
-@pytest.mark.parametrize("megastep_k", [0, 4])
+@pytest.mark.parametrize("megastep_k", [1, 4])
 def test_spans_nest_inside_their_parents(megastep_k):
     eng, _, spans = traced(megastep_k)
     by_id = {s.sid: s for s in spans}
@@ -74,13 +74,12 @@ def test_spans_nest_inside_their_parents(megastep_k):
         assert p.t0 <= s.t0 and s.t1 <= p.t1, (s, p)
     roots = [s for s in spans if s.name == "engine.step"]
     assert len(roots) == eng.step_count
-    # one decode token per drained row per step on the per-step path; the
-    # megastep drains up to K per row
+    # the megastep drains up to K tokens per row
     drained = sum(s.attrs["tokens"] for s in spans if s.name == "decode.drain")
     assert drained == eng.row_tokens
 
 
-@pytest.mark.parametrize("megastep_k", [0, 4])
+@pytest.mark.parametrize("megastep_k", [1, 4])
 def test_prefill_chunks_carry_their_request(megastep_k):
     eng, reqs, spans = traced(megastep_k)
     chunks = [s for s in spans if s.name == "prefill.chunk"]
@@ -95,7 +94,7 @@ def test_prefill_chunks_carry_their_request(megastep_k):
     assert sorted(admitted) == [r.uid for r in reqs]
 
 
-@pytest.mark.parametrize("megastep_k", [0, 4])
+@pytest.mark.parametrize("megastep_k", [1, 4])
 def test_decode_dispatch_counts_live_pages(megastep_k):
     """Each ``decode.dispatch`` carries ``live_pages``: ceil(length /
     page) summed over the decoding rows, where a row's length is its prompt
@@ -118,14 +117,14 @@ def test_decode_dispatch_counts_live_pages(megastep_k):
     assert any(s.attrs["live_pages"] > s.attrs["rows"] for s in spans)
 
 
-@pytest.mark.parametrize("megastep_k", [0, 4])
+@pytest.mark.parametrize("megastep_k", [1, 4])
 def test_program_obtain_spans_match_the_counter(megastep_k):
     eng, _, spans = traced(megastep_k)
     got = [s for s in spans if s.name == "program.obtain"]
     assert len(got) == eng.stats["programs_obtained"]
     progs = {s.attrs["program"] for s in got}
     assert "prefill_chunk" in progs
-    assert ("decode_megastep" if megastep_k else "decode_step") in progs
+    assert "decode_megastep" in progs
     # prompts of 5, 8 and 11 tokens in chunks of 4: tails 1 and 3 pad to
     # the full chunk, so one admission executable serves all three
     assert eng.stats["prefill_exe_misses"] == 1
