@@ -88,7 +88,8 @@ def main(rows: Rows):
 def _paged_case(live_per_slot: int, *, B=4, G=2, R=2, hd=32, P=8, M=8,
                 n_pages=40, quantized=False, seed=0):
     """Random paged pool with ``live_per_slot`` mapped pages per slot (the
-    last one partial); returns the fused-kernel argument tuple."""
+    last one partial); returns the fused-kernel argument tuple, the pools
+    stacked over one layer (read at layer 0)."""
     rng = np.random.default_rng(seed)
     if quantized:
         kp = rng.integers(-127, 128, (n_pages, P, G, hd)).astype(np.int8)
@@ -106,7 +107,8 @@ def _paged_case(live_per_slot: int, *, B=4, G=2, R=2, hd=32, P=8, M=8,
             pid += 1
     position = np.full((B,), live_per_slot * P - P // 2 - 1, np.int32)
     q = (rng.normal(size=(B, G, R, hd)) * 0.3).astype(np.float32)
-    return tuple(jnp.asarray(a) for a in (q, kp, vp, ppos, block, position))
+    return tuple(jnp.asarray(a) for a in (q, kp[None], vp[None], ppos[None],
+                                          block, position))
 
 
 def _gather_path(q, kp, vp, ppos, block, position, *, window=0, kv_scale=0.0):
@@ -116,7 +118,7 @@ def _gather_path(q, kp, vp, ppos, block, position, *, window=0, kv_scale=0.0):
     from repro.models.attention import PagedKVCache, _gather_pages, _sdpa
     B, G, R, hd = q.shape
     cache = PagedKVCache(kp, vp, ppos, block)
-    kk, vv, _, valid = _gather_pages(cache, block, position[:, None],
+    kk, vv, _, valid = _gather_pages(cache, 0, block, position[:, None],
                                      window=window)
     dq = (lambda a: a.astype(q.dtype) * kv_scale) if kv_scale else \
         (lambda a: a.astype(q.dtype))
@@ -149,7 +151,7 @@ def paged_decode_rows(rows: Rows):
         t_ref, o_ref = timed(lambda: jax.block_until_ready(
             _gather_path(q, kp, vp, ppos, block, position, **kw)))
         t_k, o_k = timed(lambda: jax.block_until_ready(
-            paged_attention(q, kp, vp, ppos, block, position,
+            paged_attention(q, kp, vp, ppos, block, position, 0,
                             interpret=True, **kw)))
         err = float(jnp.max(jnp.abs(o_k - o_ref)))
         rows.add(f"kernel.paged_decode.{name}.gather", t_ref * 1e6,
@@ -194,8 +196,9 @@ _SHARD_PAGES = 80                       # 4 shards x 20 (null + 16 live + slack)
 def _sharded_paged_case(live_per_slot: int, *, quantized=False, seed=0):
     """Slot-affinity layout: slot b's pages all come from the contiguous
     page range of shard ``b * n_shards // B``; each shard's first page is
-    its local null sentinel (never mapped). Also returns the step's new K/V
-    entries so the write+attend region can be benched as one unit."""
+    its local null sentinel (never mapped). The pools are stacked over one
+    layer (read at layer 0). Also returns the step's new K/V entries so the
+    write+attend region can be benched as one unit."""
     B, G, hd = _SHARD_B, _SHARD_G, _SHARD_HD
     Pg, nsh, n_pages = _SHARD_P, _SHARD_NSH, _SHARD_PAGES
     chunk = n_pages // nsh
@@ -222,7 +225,7 @@ def _sharded_paged_case(live_per_slot: int, *, quantized=False, seed=0):
             ppos[pid] = np.arange(lp * Pg, (lp + 1) * Pg)
     position = np.full((B,), live_per_slot * Pg - Pg // 2 - 1, np.int32)
     q = (rng.normal(size=(B, G, _SHARD_R, hd)) * 0.3).astype(np.float32)
-    return q, kp, vp, ppos, block, position, knew, vnew
+    return q, kp[None], vp[None], ppos[None], block, position, knew, vnew
 
 
 def _sharded_child():
@@ -250,12 +253,13 @@ def _sharded_child():
 
     def gather_jit(window, kv_scale):
         sh = lambda *s: NamedSharding(mesh, Psp(*s))
-        pool = sh("data", None, "model", None)
+        pool = sh(None, "data", None, "model", None)
         return jax.jit(
             functools.partial(_gather_path, window=window,
                               kv_scale=kv_scale),
             in_shardings=(sh("data", "model"), pool, pool,
-                          sh("data", None), sh("data", None), sh("data")))
+                          sh(None, "data", None), sh("data", None),
+                          sh("data")))
 
     def written_pool(case):
         # the gather comparator attends a pre-written pool: emulate the
@@ -264,7 +268,7 @@ def _sharded_child():
         kp2, vp2 = kp.copy(), vp.copy()
         for b in range(B):
             phys, off = block[b, position[b] // Pg], position[b] % Pg
-            kp2[phys, off], vp2[phys, off] = knew[b], vnew[b]
+            kp2[0, phys, off], vp2[0, phys, off] = knew[b], vnew[b]
         return kp2, vp2
 
     variants = [("fp32", dict(window=0, kv_scale=0.0), dict()),
@@ -275,11 +279,12 @@ def _sharded_child():
         case = _sharded_paged_case(4, **mk)
         q, kp, vp, ppos, block, position, knew, vnew = case
         cache = attn_mod.PagedKVCache(*map(jnp.asarray,
-                                           (kp, vp, ppos, block)))
+                                           (kp, vp, ppos, block[None])))
         ff = fused_fn(**kw)
         args = tuple(map(jnp.asarray, (q, knew, vnew, position)))
+        layer = jnp.asarray(0, jnp.int32)
         t_f, (o_f, _) = timed(lambda: jax.block_until_ready(
-            ff(*args, active, cache)))
+            ff(*args, active, cache, layer)))
         kp2, vp2 = written_pool(case)
         gf = gather_jit(**kw)
         gargs = tuple(map(jnp.asarray, (q, kp2, vp2, ppos, block, position)))

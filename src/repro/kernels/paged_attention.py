@@ -8,7 +8,10 @@ index map streams the *physical* page HBM -> VMEM directly, and an
 online-softmax accumulator in VMEM scratch folds pages as they arrive. The
 dense ``(B, S_max, G, hd)`` gather buffer of the reference path never
 exists, so per-step decode HBM traffic scales with LIVE pages instead of
-slots x max_len.
+slots x max_len. The pools come stacked over layers, as the layer scan
+carries them, and the layer index is scalar-prefetched beside the tables:
+the index maps pick the layer's pages out of the stack, so no layer's pool
+is sliced out for the kernel.
 
 Dead traffic is skipped at two levels:
 
@@ -50,8 +53,8 @@ from jax.experimental.pallas import tpu as pltpu
 NEG_INF = -1e30
 
 
-def _kernel(block_ref, pos_ref, q_ref, k_ref, v_ref, ppos_ref, o_ref,
-            m_ref, l_ref, acc_ref, *, page: int, n_m: int, n_kv: int,
+def _kernel(layer_ref, block_ref, pos_ref, q_ref, k_ref, v_ref, ppos_ref,
+            o_ref, m_ref, l_ref, acc_ref, *, page: int, n_m: int, n_kv: int,
             window: int, kv_scale: float, cap: float, scale: float):
     b = pl.program_id(0)
     m = pl.program_id(1)          # logical page (sequential)
@@ -106,21 +109,28 @@ def _kernel(block_ref, pos_ref, q_ref, k_ref, v_ref, ppos_ref, o_ref,
         o_ref[0] = (acc_ref[...] / l).astype(o_ref.dtype)
 
 
-def paged_attention_impl(q, kp, vp, ppos, block, position, *, window: int = 0,
-                         kv_scale: float = 0.0, cap: float = 0.0,
-                         interpret: bool = False):
+def paged_attention_impl(q, kp, vp, ppos, block, position, layer, *,
+                         window: int = 0, kv_scale: float = 0.0,
+                         cap: float = 0.0, interpret: bool = False):
     """Fused paged decode attention (unjitted body).
 
     q: (B, G, R, hd) — current token's queries, grouped by KV head;
-    kp/vp: (n_pages, P, G, hd) physical page pools (int8 when ``kv_scale``);
-    ppos: (n_pages, P) absolute positions (-1 empty); block: (B, M) int32
-    physical page ids (0 = unmapped); position: (B,) absolute query position.
-    Returns (B, G, R, hd) in q.dtype.
+    kp/vp: (L, n_pages, P, G, hd) physical page pools stacked over layers
+    (int8 when ``kv_scale``), of which ``layer`` (a traced scalar) is read
+    — the layer scan hands the kernel the whole stack, so no layer's pool
+    is copied for it; ppos: (L, n_pages, P) absolute positions (-1 empty);
+    block: (B, M) int32 the layer's physical page ids (0 = unmapped);
+    position: (B,) absolute query position. Returns (B, G, R, hd) in
+    q.dtype.
 
     One grid step streams a whole page, every KV head at once: the TPU
     compiler tiles the last two block dims, which must be (8, 128)-aligned
-    or span the array, so a page block is (1, P, G, hd) and the ``ppos``
-    row travels as a (1, 1, P) block of a (n_pages, 1, P) view.
+    or span the array, so a page block is (P, G, hd) of layer ``layer``
+    (the layer dim squeezed) and the ``ppos`` row travels as a (1, 1, P)
+    block of the layer's (n_pages, 1, P) view. That view is a copy on the
+    TPU (the row-per-page tiling differs from the pool's): it is taken of
+    the one layer's positions, n_pages x P int32, since a view of the
+    whole stack would copy every layer's at each layer.
 
     Use ``paged_attention`` (the jitted wrapper) from op-level code; this
     raw body exists so ``models.attention`` can call the kernel INSIDE a
@@ -128,12 +138,12 @@ def paged_attention_impl(q, kp, vp, ppos, block, position, *, window: int = 0,
     jit there would re-trace per shard for nothing.
     """
     B, G, R, hd = q.shape
-    n_pages, P = ppos.shape
+    n_pages, P = ppos.shape[1:]
     M = block.shape[1]
     block = block.astype(jnp.int32)
     position = position.astype(jnp.int32)
 
-    def _qo_map(b, m, block_ref, pos_ref):
+    def _qo_map(b, m, layer_ref, block_ref, pos_ref):
         return (b, 0, 0, 0)
 
     def _page_map(b, m, block_ref, pos_ref):
@@ -149,19 +159,19 @@ def paged_attention_impl(q, kp, vp, ppos, block, position, *, window: int = 0,
             dead |= (m + 1) * P - 1 <= pos_ref[b] - window
         return jnp.where(dead, 0, pid)
 
-    def _kv_map(b, m, block_ref, pos_ref):
-        return (_page_map(b, m, block_ref, pos_ref), 0, 0, 0)
+    def _kv_map(b, m, layer_ref, block_ref, pos_ref):
+        return (layer_ref[0], _page_map(b, m, block_ref, pos_ref), 0, 0, 0)
 
-    def _ppos_map(b, m, block_ref, pos_ref):
+    def _ppos_map(b, m, layer_ref, block_ref, pos_ref):
         return (_page_map(b, m, block_ref, pos_ref), 0, 0)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
+        num_scalar_prefetch=3,
         grid=(B, M),
         in_specs=[
             pl.BlockSpec((1, G, R, hd), _qo_map),
-            pl.BlockSpec((1, P, G, hd), _kv_map),
-            pl.BlockSpec((1, P, G, hd), _kv_map),
+            pl.BlockSpec((None, 1, P, G, hd), _kv_map),
+            pl.BlockSpec((None, 1, P, G, hd), _kv_map),
             pl.BlockSpec((1, 1, P), _ppos_map),
         ],
         out_specs=pl.BlockSpec((1, G, R, hd), _qo_map),
@@ -182,7 +192,9 @@ def paged_attention_impl(q, kp, vp, ppos, block, position, *, window: int = 0,
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
         name="paged_attention_decode",
-    )(block, position, q, kp, vp, ppos.reshape(n_pages, 1, P))
+    )(jnp.reshape(layer, (1,)).astype(jnp.int32), block, position, q, kp, vp,
+      jax.lax.dynamic_index_in_dim(ppos, layer, 0, keepdims=False)
+      .reshape(n_pages, 1, P))
 
 
 paged_attention = functools.partial(jax.jit, static_argnames=(

@@ -248,7 +248,11 @@ class PagedKVCache(NamedTuple):
     null/trash page: unmapped block entries point at it and are masked out
     of attention, and inactive decode rows scatter into it harmlessly.
     Allocation is host-side (``serve.pages.PagePool``); the jitted paths
-    below only gather/scatter through the tables."""
+    below only gather/scatter through the tables. They take the cache
+    stacked over layers (leading axis) with the layer's index, as the
+    latent functions take ``PagedLatentCache``: the layer scan carries the
+    whole stack, so entries are written in place and the kernel reads its
+    layer without a copy."""
     kp: jax.Array         # (n_pages, page_size, G, hd) physical page pool
     vp: jax.Array
     ppos: jax.Array       # (n_pages, page_size) absolute positions, -1 empty
@@ -267,35 +271,40 @@ def init_paged_cache(cfg: ModelConfig, batch: int, n_pages: int,
         block=jnp.zeros((batch, max_pages), jnp.int32))
 
 
-def _page_scatter(sel, write, buf, new):
-    """Scatter ``new`` rows into the page pool through a one-hot selection —
-    NOT a dynamic-index scatter: indices stay on the unsharded (page, offset)
-    dims as an elementwise one-hot, so a pool sharded over pages or heads
-    partitions cleanly (same GSPMD hazard class as the dense ring write).
+def _page_scatter(sel, write, buf, layer, new):
+    """Scatter ``new`` rows into layer ``layer`` of the stacked page pool
+    through a one-hot selection — NOT a dynamic-index scatter: indices stay
+    on the unsharded (page, offset) dims as an elementwise one-hot, so a
+    pool sharded over pages or heads partitions cleanly (same GSPMD hazard
+    class as the dense ring write); only the unsharded layer dim is indexed.
 
     sel: (R, n_pages, P) one-hot; write: (n_pages, P) = sel.any(0);
-    buf: (n_pages, P, ...); new: (R, ...). Colliding rows (inactive decode
-    slots all aimed at the trash page) sum to garbage that is never read.
+    buf: (L, n_pages, P, ...); new: (R, ...). Colliding rows (inactive
+    decode slots all aimed at the trash page) sum to garbage that is never
+    read.
     """
     scat = jnp.einsum("rnp,r...->np...", sel.astype(jnp.float32),
                       new.astype(jnp.float32))
-    expand = (None,) * (buf.ndim - 2)
-    return jnp.where(write[(slice(None), slice(None)) + expand],
-                     scat.astype(buf.dtype), buf)
+    expand = (None,) * (buf.ndim - 3)
+    return buf.at[layer].set(
+        jnp.where(write[(slice(None), slice(None)) + expand],
+                  scat.astype(buf.dtype), buf[layer]))
 
 
-def _gather_pages(cache: PagedKVCache, block, q_positions, *, window: int):
-    """Gather a block table's pages into contiguous K/V + validity mask.
+def _gather_pages(cache: PagedKVCache, layer, block, q_positions, *,
+                  window: int):
+    """Gather a block table's pages of stacked layer ``layer`` into
+    contiguous K/V + validity mask.
 
     block: (B, M); q_positions: (B, C) absolute query positions. Returns
     (k (B, M*P, G, hd), v, valid (B, C, M*P)). Unmapped entries (physical
     page 0) are masked regardless of the trash page's contents.
     """
-    n_pages, P = cache.ppos.shape
+    P = cache.ppos.shape[-1]
     B, M = block.shape
-    gk = jnp.take(cache.kp, block, axis=0).reshape(B, M * P, *cache.kp.shape[2:])
-    gv = jnp.take(cache.vp, block, axis=0).reshape(B, M * P, *cache.vp.shape[2:])
-    gpos = jnp.take(cache.ppos, block, axis=0).reshape(B, M * P)
+    gk = cache.kp[layer, block].reshape(B, M * P, *cache.kp.shape[3:])
+    gv = cache.vp[layer, block].reshape(B, M * P, *cache.vp.shape[3:])
+    gpos = cache.ppos[layer, block].reshape(B, M * P)
     mapped = jnp.repeat(block != 0, P, axis=1)            # (B, M*P)
     valid = mapped[:, None, :] & (gpos[:, None, :] >= 0)
     valid &= gpos[:, None, :] <= q_positions[:, :, None]
@@ -435,8 +444,9 @@ def _flat_axis_index(mesh, axes):
 
 
 def _sharded_write_attend(q, k_store, v_store, position, active,
-                          cache: PagedKVCache, mesh, plan, *, window: int,
-                          kv_scale: float, cap: float, interpret: bool):
+                          cache: PagedKVCache, layer, mesh, plan, *,
+                          window: int, kv_scale: float, cap: float,
+                          interpret: bool):
     """ONE shard_map region: slot-affinity dynamic cache write + the fused
     Pallas kernel, zero collectives.
 
@@ -449,49 +459,55 @@ def _sharded_write_attend(q, k_store, v_store, position, active,
     sharded page dim — and the scalar-prefetch kernel grid become plain
     single-device programs per shard. Inactive rows write into the local
     null page (never read). q: (B, G, R, hd); k_store/v_store: (B, G, hd)
-    at cache dtype. Returns (o (B, G, R, hd), new PagedKVCache).
+    at cache dtype; the pools stacked over layers, written and read at
+    ``layer`` (the layer dim is unsharded). Returns (o (B, G, R, hd), new
+    PagedKVCache).
     """
     from jax.sharding import PartitionSpec as P
     from repro.kernels.paged_attention import paged_attention_impl
     b, g = plan.batch_axes, plan.kv_head_axis
-    n_pages, Pg = cache.ppos.shape
+    _, n_pages, Pg = cache.ppos.shape
     chunk = n_pages // plan.n_shards
 
-    def inner(q_l, k_l, v_l, pos_l, act_l, kp_l, vp_l, ppos_l, block_l):
+    def inner(q_l, k_l, v_l, pos_l, act_l, kp_l, vp_l, ppos_l, block_l,
+              layer_l):
         base = _flat_axis_index(mesh, b) * chunk
         lblock = jnp.where(block_l == 0, 0, block_l - base)
         phys = jnp.take_along_axis(lblock, (pos_l // Pg)[:, None],
                                    axis=1)[:, 0]
         tgt = jnp.where(act_l, phys, 0)
         off = pos_l % Pg
-        nkp = kp_l.at[tgt, off].set(k_l)
-        nvp = vp_l.at[tgt, off].set(v_l)
-        nppos = ppos_l.at[tgt, off].set(pos_l)
+        nkp = kp_l.at[layer_l, tgt, off].set(k_l)
+        nvp = vp_l.at[layer_l, tgt, off].set(v_l)
+        nppos = ppos_l.at[layer_l, tgt, off].set(pos_l)
         o = paged_attention_impl(q_l, nkp, nvp, nppos, lblock, pos_l,
-                                 window=window, kv_scale=kv_scale, cap=cap,
-                                 interpret=interpret)
+                                 layer_l, window=window, kv_scale=kv_scale,
+                                 cap=cap, interpret=interpret)
         return o, nkp, nvp, nppos
 
     q_spec = P(b, g, None, None)
-    kv_spec = P(b, None, g, None)
+    kv_spec = P(None, b, None, g, None)
+    ppos_spec = P(None, b, None)
     fn = jax.shard_map(
         inner, mesh=mesh,
         in_specs=(q_spec, P(b, g, None), P(b, g, None), P(b), P(b),
-                  kv_spec, kv_spec, P(b, None), P(b, None)),
-        out_specs=(q_spec, kv_spec, kv_spec, P(b, None)),
+                  kv_spec, kv_spec, ppos_spec, P(b, None), P()),
+        out_specs=(q_spec, kv_spec, kv_spec, ppos_spec),
         check_vma=False)
     o, nkp, nvp, nppos = fn(q, k_store, v_store, position, active,
-                            cache.kp, cache.vp, cache.ppos, cache.block)
+                            cache.kp, cache.vp, cache.ppos,
+                            cache.block[layer], layer)
     return o, PagedKVCache(nkp, nvp, nppos, cache.block)
 
 
 def paged_decode_attention(params, x, position, cache: PagedKVCache,
-                           cfg: ModelConfig, *, window: int = 0,
+                           cfg: ModelConfig, *, layer, window: int = 0,
                            kv_scale: float = 0.0, active=None,
                            use_kernel: Optional[bool] = None,
                            interpret: bool = False,
                            dyn_scatter: bool = False, mesh=None):
-    """One-token decode against the paged pool. x: (B,1,D); position: (B,).
+    """One-token decode against the stacked paged pool at ``layer``.
+    x: (B,1,D); position: (B,).
 
     The new K/V entry scatters into the slot's private tail page (host-side
     allocation guarantees it is mapped and unshared before the step runs);
@@ -541,7 +557,8 @@ def paged_decode_attention(params, x, position, cache: PagedKVCache,
     else:
         k_store = k.astype(cache.kp.dtype)
         v_store = v.astype(cache.vp.dtype)
-    n_pages, P = cache.ppos.shape
+    _, n_pages, P = cache.ppos.shape
+    block = cache.block[layer]
     if use_kernel is None:
         use_kernel = kops._on_tpu()
     if mesh is not None:
@@ -556,7 +573,7 @@ def paged_decode_attention(params, x, position, cache: PagedKVCache,
                    else jnp.ones((B,), jnp.bool_))
             o, new_cache = _sharded_write_attend(
                 q[:, 0].reshape(B, G, R, hd), k_store[:, 0], v_store[:, 0],
-                position, act, cache, mesh, plan, window=window,
+                position, act, cache, layer, mesh, plan, window=window,
                 kv_scale=kv_scale, cap=cfg.attn_softcap, interpret=interpret)
             return o.reshape(B, 1, cfg.q_dim) @ params["wo"], new_cache
         DISPATCH_COUNTS["gather_mesh"] += 1
@@ -565,14 +582,14 @@ def paged_decode_attention(params, x, position, cache: PagedKVCache,
     else:
         DISPATCH_COUNTS["kernel_single" if use_kernel
                         else "gather_single"] += 1
-    phys = jnp.take_along_axis(cache.block, (position // P)[:, None],
+    phys = jnp.take_along_axis(block, (position // P)[:, None],
                                axis=1)[:, 0]              # (B,)
     if dyn_scatter:
         tgt = phys if active is None else jnp.where(active, phys, 0)
         off = position % P
-        nkp = cache.kp.at[tgt, off].set(k_store[:, 0])
-        nvp = cache.vp.at[tgt, off].set(v_store[:, 0])
-        nppos = cache.ppos.at[tgt, off].set(position)
+        nkp = cache.kp.at[layer, tgt, off].set(k_store[:, 0])
+        nvp = cache.vp.at[layer, tgt, off].set(v_store[:, 0])
+        nppos = cache.ppos.at[layer, tgt, off].set(position)
     else:
         sel = ((jnp.arange(n_pages)[None, :, None] == phys[:, None, None])
                & (jnp.arange(P)[None, None, :]
@@ -580,20 +597,20 @@ def paged_decode_attention(params, x, position, cache: PagedKVCache,
         if active is not None:
             sel &= active[:, None, None]
         write = sel.any(axis=0)
-        nkp = _page_scatter(sel, write, cache.kp, k_store[:, 0])
-        nvp = _page_scatter(sel, write, cache.vp, v_store[:, 0])
-        nppos = _page_scatter(sel, write, cache.ppos, position)
+        nkp = _page_scatter(sel, write, cache.kp, layer, k_store[:, 0])
+        nvp = _page_scatter(sel, write, cache.vp, layer, v_store[:, 0])
+        nppos = _page_scatter(sel, write, cache.ppos, layer, position)
     new_cache = PagedKVCache(nkp, nvp, nppos, cache.block)
 
     if use_kernel:
         qk = q[:, 0].reshape(B, G, R, hd)
-        o = paged_attention(qk, nkp, nvp, nppos, cache.block, position,
+        o = paged_attention(qk, nkp, nvp, nppos, block, position, layer,
                             window=window, kv_scale=kv_scale,
                             cap=cfg.attn_softcap, interpret=interpret)
         return o.reshape(B, 1, cfg.q_dim) @ params["wo"], new_cache
 
-    kk, vv, _, valid = _gather_pages(new_cache, cache.block, position[:, None],
-                                     window=window)
+    kk, vv, _, valid = _gather_pages(new_cache, layer, block,
+                                     position[:, None], window=window)
     dq = (lambda a: dequantize_kv(a, q.dtype, kv_scale)) if kv_scale else \
         (lambda a: a.astype(q.dtype))
     qg = q.reshape(B, 1, G, R, hd)
@@ -603,14 +620,15 @@ def paged_decode_attention(params, x, position, cache: PagedKVCache,
 
 
 def paged_chunk_attention(params, x, positions, cache: PagedKVCache,
-                          cfg: ModelConfig, slot, *, window: int = 0,
+                          cfg: ModelConfig, slot, *, layer, window: int = 0,
                           kv_scale: float = 0.0, valid=None,
                           dyn_scatter: bool = False,
                           mesh=None, use_kernel: Optional[bool] = None,
                           interpret: bool = False):
-    """C-token prompt-chunk step for ONE slot of the paged pool (chunked
-    admission). x: (1,C,D); positions: (1,C); ``slot`` is a traced scalar —
-    one executable of chunk length C serves every slot and every chunk.
+    """C-token prompt-chunk step for ONE slot of the stacked paged pool at
+    ``layer`` (chunked admission). x: (1,C,D); positions: (1,C); ``slot``
+    is a traced scalar — one executable of chunk length C serves every slot
+    and every chunk.
 
     Scatters the chunk's K/V into the slot's (pre-allocated, private) pages,
     then attends over every mapped page — the chunk's own entries included,
@@ -650,8 +668,8 @@ def paged_chunk_attention(params, x, positions, cache: PagedKVCache,
     else:
         k_store = k.astype(cache.kp.dtype)
         v_store = v.astype(cache.vp.dtype)
-    n_pages, P = cache.ppos.shape
-    brow = jnp.take(cache.block, slot, axis=0)            # (M,)
+    _, n_pages, P = cache.ppos.shape
+    brow = cache.block[layer, slot]                       # (M,)
     pos_c = positions[0]                                  # (C,)
     phys = jnp.take(brow, pos_c // P)                     # (C,)
     if dyn_scatter:
@@ -660,9 +678,9 @@ def paged_chunk_attention(params, x, positions, cache: PagedKVCache,
         # their targets never collide; pad rows collide on the null page
         tgt = phys if valid is None else jnp.where(valid, phys, 0)
         off = pos_c % P
-        nkp = cache.kp.at[tgt, off].set(k_store[0])
-        nvp = cache.vp.at[tgt, off].set(v_store[0])
-        nppos = cache.ppos.at[tgt, off].set(pos_c)
+        nkp = cache.kp.at[layer, tgt, off].set(k_store[0])
+        nvp = cache.vp.at[layer, tgt, off].set(v_store[0])
+        nppos = cache.ppos.at[layer, tgt, off].set(pos_c)
     else:
         sel = ((jnp.arange(n_pages)[None, :, None] == phys[:, None, None])
                & (jnp.arange(P)[None, None, :]
@@ -670,18 +688,18 @@ def paged_chunk_attention(params, x, positions, cache: PagedKVCache,
         if valid is not None:
             sel &= valid[:, None, None]
         write = sel.any(axis=0)
-        nkp = _page_scatter(sel, write, cache.kp, k_store[0])
-        nvp = _page_scatter(sel, write, cache.vp, v_store[0])
-        nppos = _page_scatter(sel, write, cache.ppos, pos_c)
+        nkp = _page_scatter(sel, write, cache.kp, layer, k_store[0])
+        nvp = _page_scatter(sel, write, cache.vp, layer, v_store[0])
+        nppos = _page_scatter(sel, write, cache.ppos, layer, pos_c)
     new_cache = PagedKVCache(nkp, nvp, nppos, cache.block)
 
     plan, _ = _prefill_ring_plan(cfg, mesh, C, use_kernel)
     if plan is not None:
         from repro.kernels.ring_attention import ring_chunk_attention
         M = brow.shape[0]
-        gk = jnp.take(nkp, brow[None], axis=0).reshape(B, M * P, G, hd)
-        gv = jnp.take(nvp, brow[None], axis=0).reshape(B, M * P, G, hd)
-        gpos = jnp.take(nppos, brow[None], axis=0).reshape(B, M * P)
+        gk = nkp[layer, brow[None]].reshape(B, M * P, G, hd)
+        gv = nvp[layer, brow[None]].reshape(B, M * P, G, hd)
+        gpos = nppos[layer, brow[None]].reshape(B, M * P)
         mapped = jnp.repeat(brow[None] != 0, P, axis=1)
         kv_pos = jnp.where(mapped, gpos, -1)
         o = ring_chunk_attention(q.reshape(B, C, G, R, hd), gk, gv,
@@ -690,7 +708,7 @@ def paged_chunk_attention(params, x, positions, cache: PagedKVCache,
                                  kv_scale=kv_scale, interpret=interpret)
         return _merge(o, B, C, cfg.q_dim) @ params["wo"], new_cache
 
-    kk, vv, _, valid = _gather_pages(new_cache, brow[None], positions,
+    kk, vv, _, valid = _gather_pages(new_cache, layer, brow[None], positions,
                                      window=window)
     dq = (lambda a: dequantize_kv(a, q.dtype, kv_scale)) if kv_scale else \
         (lambda a: a.astype(q.dtype))

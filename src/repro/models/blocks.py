@@ -112,7 +112,7 @@ def block_prefill_paged(kind: str, params, h, positions, cache,
     ``slot`` traced.
     ``valid`` (C,) bool marks the rows whose K/V reach the slot's pages
     (``attention.paged_chunk_attention``) and that take expert rows; a
-    Mamba layer takes every row. ``layer``: latent caches and expert
+    Mamba layer takes every row. ``layer``: paged pools and expert
     matrices come stacked over layers (``lm.scan_layers``), this one's
     index. Returns (h, new_cache, counts) — counts as ``_serve_ffn``.
     """
@@ -141,9 +141,10 @@ def block_prefill_paged(kind: str, params, h, positions, cache,
             kv_scale=kv_scale, valid=valid, mesh=mesh)
     else:
         y, new_cache = attn_mod.paged_chunk_attention(
-            params["attn"], hn, positions, cache, cfg, slot, window=window,
-            kv_scale=kv_scale, valid=valid, dyn_scatter=dyn_scatter,
-            mesh=mesh, use_kernel=use_kernel, interpret=interpret)
+            params["attn"], hn, positions, cache, cfg, slot, layer=layer,
+            window=window, kv_scale=kv_scale, valid=valid,
+            dyn_scatter=dyn_scatter, mesh=mesh, use_kernel=use_kernel,
+            interpret=interpret)
     h = h + y
     y, counts = _serve_ffn(params, rms_norm(h, params["norm_mlp"],
                                             cfg.norm_eps), cfg, knobs,
@@ -195,9 +196,10 @@ def block_decode(kind: str, params, h, position, cache, cfg: ModelConfig,
             mesh=mesh, interpret=interpret)
     elif isinstance(cache, attn_mod.PagedKVCache):
         y, new_cache = attn_mod.paged_decode_attention(
-            params["attn"], hn, position, cache, cfg, window=window,
-            kv_scale=kv_scale, active=active, use_kernel=use_kernel,
-            dyn_scatter=dyn_scatter, mesh=mesh, interpret=interpret)
+            params["attn"], hn, position, cache, cfg, layer=layer,
+            window=window, kv_scale=kv_scale, active=active,
+            use_kernel=use_kernel, dyn_scatter=dyn_scatter, mesh=mesh,
+            interpret=interpret)
     else:
         y, new_cache = attn_mod.decode_attention(
             params["attn"], hn, position, cache, cfg, window=window,

@@ -332,19 +332,21 @@ def scan_layers(params, caches, cfg: ModelConfig, h, layer_fn, *,
     ``init_caches`` / ``init_paged_caches`` layout). Returns (h,
     new_caches, counts summed over layers).
 
-    Latent caches and the expert matrices stay stacked over the layers:
-    the scan carries the former (written in place) and closes over the
-    latter, and ``layer_fn`` gets them whole with the layer index ``i``,
-    so a kernel reads its layer without the per-layer copy a scanned
-    slice would cost. Every other cache and parameter is sliced per
-    group."""
-    from repro.models.attention import PagedLatentCache
+    Paged pools (``PagedKVCache``, ``PagedLatentCache``) and the expert
+    matrices stay stacked over the layers: the scan carries the former
+    (written in place) and closes over the latter, and ``layer_fn`` gets
+    them whole with the layer index ``i``, so a kernel reads its layer
+    without the per-layer copy a scanned slice would cost, and no layer's
+    pool is written back into a second stack. Every other cache (per-slot
+    Mamba state, ring ``KVCache``) and parameter is sliced per group."""
+    from repro.models.attention import PagedKVCache, PagedLatentCache
     n_pat = len(cfg.pattern)
     shared = params.get("shared")
     counts = jnp.zeros((2,), jnp.int32)
 
     def run(h, counts, stack, stack_caches, kinds, n):
-        lat = [isinstance(c, PagedLatentCache) for c in stack_caches]
+        pooled = [isinstance(c, (PagedKVCache, PagedLatentCache))
+                  for c in stack_caches]
         xs_params, mats = {}, {}
         for key, p in stack.items():
             if "moe" in p:
@@ -363,20 +365,21 @@ def scan_layers(params, caches, cfg: ModelConfig, h, layer_fn, *,
                 if key in mats:
                     p = dict(p, moe=dict(p["moe"], **mats[key]))
                 h, nc, c = layer_fn(kind, p, h,
-                                    carried[j] if lat[j] else gc[j], i)
+                                    carried[j] if pooled[j] else gc[j], i)
                 counts = counts + c
-                if lat[j]:
+                if pooled[j]:
                     carried[j], nc = nc, None
                 ys.append(nc)
             return (h, counts, tuple(carried)), tuple(ys)
 
-        carried = tuple(c if l else None for c, l in zip(stack_caches, lat))
-        xs_c = tuple(None if l else c for c, l in zip(stack_caches, lat))
+        carried = tuple(c if l else None
+                        for c, l in zip(stack_caches, pooled))
+        xs_c = tuple(None if l else c for c, l in zip(stack_caches, pooled))
         (h, counts, carried), ys = jax.lax.scan(
             body, (h, counts, carried), (xs_params, xs_c, jnp.arange(n)),
             unroll=unroll)
         return h, counts, tuple(c if l else y
-                                for c, y, l in zip(carried, ys, lat))
+                                for c, y, l in zip(carried, ys, pooled))
 
     lead = ()
     if cfg.n_dense_layers:

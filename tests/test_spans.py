@@ -152,14 +152,15 @@ def test_paged_attention_lowers_under_its_name():
     B, G, R, hd, P, M = 2, 2, 2, 8, 4, 3
     n_pages = B * M + 1
 
-    def decode_megastep(q, kp, vp, ppos, block, pos):
-        return paged_attention_impl(q, kp, vp, ppos, block, pos,
+    def decode_megastep(q, kp, vp, ppos, block, pos, layer):
+        return paged_attention_impl(q, kp, vp, ppos, block, pos, layer,
                                     interpret=True)
 
-    args = (jnp.zeros((B, G, R, hd)), jnp.zeros((n_pages, P, G, hd)),
-            jnp.zeros((n_pages, P, G, hd)),
-            jnp.zeros((n_pages, P), jnp.int32),
-            jnp.zeros((B, M), jnp.int32), jnp.zeros((B,), jnp.int32))
+    args = (jnp.zeros((B, G, R, hd)), jnp.zeros((2, n_pages, P, G, hd)),
+            jnp.zeros((2, n_pages, P, G, hd)),
+            jnp.zeros((2, n_pages, P), jnp.int32),
+            jnp.zeros((B, M), jnp.int32), jnp.zeros((B,), jnp.int32),
+            jnp.asarray(1, jnp.int32))
     text = jax.jit(decode_megastep).lower(*args).as_text(debug_info=True)
     assert "paged_attention_decode" in text
 

@@ -48,25 +48,69 @@ PHI4 = dict(B=8, G=8, R=3, hd=128, P=16, max_len=2048)
                                                (jnp.float32, 0.0)])
 def test_paged_attention_compiles(one_chip, kv_dtype, kv_scale):
     """bf16 and int8 pages serve phi4 on one chip; float32 pages are the
-    four-chip parity check's."""
+    four-chip parity check's. The pools are stacked over layers, as the
+    layer scan carries them, and the kernel reads one by index."""
     from repro.kernels.paged_attention import paged_attention_impl
     B, G, R, hd, P = (PHI4[k] for k in ("B", "G", "R", "hd", "P"))
     M = PHI4["max_len"] // P
-    n_pages = B * M + 1
+    n_pages, L = B * M + 1, 4
 
-    def fn(q, kp, vp, ppos, block, pos):
-        return paged_attention_impl(q, kp, vp, ppos, block, pos,
+    def fn(q, kp, vp, ppos, block, pos, layer):
+        return paged_attention_impl(q, kp, vp, ppos, block, pos, layer,
                                     kv_scale=kv_scale)
 
     q_dtype = jnp.float32 if kv_dtype == jnp.float32 else jnp.bfloat16
     hlo = _compile(fn, one_chip,
                    ((B, G, R, hd), q_dtype),
-                   ((n_pages, P, G, hd), kv_dtype),
-                   ((n_pages, P, G, hd), kv_dtype),
-                   ((n_pages, P), jnp.int32),
+                   ((L, n_pages, P, G, hd), kv_dtype),
+                   ((L, n_pages, P, G, hd), kv_dtype),
+                   ((L, n_pages, P), jnp.int32),
                    ((B, M), jnp.int32),
-                   ((B,), jnp.int32))
+                   ((B,), jnp.int32), ((), jnp.int32))
     assert "tpu_custom_call" in hlo
+
+
+def test_phi4_megastep_carries_the_paged_pool(one_chip):
+    """The megastep at phi4's published widths and serving sizes (16 slots,
+    1,792 pages of 16, K=8, caches donated), cut to 2 layers: the layer
+    scan carries the stacked K/V pool and the kernel reads its layer by
+    index, so no layer's pool is sliced out, written back into the stack,
+    or copied whole, and no second pool's worth of temporaries exists."""
+    import dataclasses
+    import re
+    from types import SimpleNamespace
+    from repro.configs import get_config
+    from repro.models import api
+    from repro.train.step import make_paged_megastep
+    L, B, P, n_pages, max_len = 2, 16, 16, 1792, 4096
+    cfg = dataclasses.replace(get_config("phi4-mini-3.8b"), n_layers=L)
+    spec = SimpleNamespace(n_pages=n_pages, page_size=P,
+                           max_pages=max_len // P)
+    place = lambda tree: jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip),
+        tree)
+    params = place(api.abstract(cfg))
+    caches = place(api.abstract_caches(cfg, B, max_len, paged=spec))
+    row = jax.ShapeDtypeStruct((B,), jnp.int32, sharding=one_chip)
+    alive = jax.ShapeDtypeStruct((B,), jnp.bool_, sharding=one_chip)
+    step = make_paged_megastep(cfg, k=8, use_kernel=True,
+                               dynamic_scatter=True)
+    compiled = jax.jit(step, donate_argnums=(7,)).lower(
+        params, row, row, alive, row, row, row, caches).compile()
+
+    page = f"{n_pages},{P},{cfg.n_kv_heads},{cfg.resolved_head_dim}]"
+    pools = (f"bf16[{L},{page}", f"bf16[1,{page}", f"bf16[{page}")
+    moved = []
+    for line in compiled.as_text().splitlines():
+        m = re.search(r"= (.*?) ([\w-]+)\(", line)
+        if (m and m.group(2) in ("dynamic-slice", "dynamic-update-slice",
+                                 "copy", "copy-start")
+                and any(p in m.group(1) for p in pools)):
+            moved.append(line.strip()[:160])
+    assert not moved, moved
+    kv_bytes = sum(c.kp.size * c.kp.dtype.itemsize * 2 for c in caches)
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    assert temp < kv_bytes, (temp, kv_bytes)
 
 
 @pytest.mark.parametrize("rows", [8, 200])
